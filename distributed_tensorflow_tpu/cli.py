@@ -33,7 +33,8 @@ import argparse
 import json
 import sys
 
-from distributed_tensorflow_tpu.utils.harness import ExperimentConfig, run
+from distributed_tensorflow_tpu.utils.harness import (
+    ExperimentConfig, resolve_compile_cache, run)
 
 
 def parse_model_args(pairs: list[str]) -> dict:
@@ -490,11 +491,6 @@ def build_parser() -> argparse.ArgumentParser:
                         "exposed-vs-hidden collective split "
                         "(grad_collective_exposed_s); pipeline modes "
                         "reject the flag")
-    p.add_argument("--compile-cache", default=None, metavar="DIR",
-                   help="persistent XLA compilation cache directory "
-                        "(jax_compilation_cache_dir): repeat runs and "
-                        "bench warmups skip recompiles of unchanged "
-                        "programs")
     p.add_argument("--steps-per-call", type=int, default=None,
                    help="steady-state drain: training steps rolled into one "
                         "jitted lax.scan per host dispatch (README "
@@ -658,27 +654,14 @@ def select_engine(args: argparse.Namespace) -> str:
     return "sync"  # tpu_pod
 
 
-def _honor_platform_env() -> None:
-    """Re-assert the user's JAX platform choice over preloaded plugins.
-
-    The package __init__ already runs this at import time (see
-    distributed_tensorflow_tpu._honor_platform_env — the single
-    definition); main() re-asserts for belt-and-braces in embedding
-    scenarios where the host process imported jax (but initialized no
-    backend) before setting the env vars and importing us."""
-    from distributed_tensorflow_tpu import _honor_platform_env as _honor
-
-    _honor()
-
-
 def main(argv: list[str] | None = None, *, model_fn=None,
          dataset_fn=None) -> dict:
     """CLI entry.  ``model_fn``/``dataset_fn`` are the reference's user
     plug-in contract (reference README.md:12: "edit model_fn/dataset_fn in
     initializer.py"): when provided they override --model/--dataset."""
-    _honor_platform_env()
     parser = build_parser()
     args = parser.parse_args(argv)
+    resolve_compile_cache()
 
     try:
         model_args = parse_model_args(args.model_arg)
@@ -722,7 +705,6 @@ def main(argv: list[str] | None = None, *, model_fn=None,
         grad_compression=args.grad_compression,
         precision=args.precision,
         grad_bucket_mb=args.grad_bucket_mb,
-        compile_cache=args.compile_cache,
         weight_decay=args.weight_decay,
         clip_norm=args.clip_norm,
         sync_every=args.sync_every,

@@ -43,6 +43,9 @@ class Dataset:
     # logical dataset (multi-host input sharding): the Trainer then treats
     # batches as process-local rows of a global batch (engines/allreduce.py)
     process_shard: tuple[int, int] | None = None
+    # which producer the last ``batches()`` call chose: "native" (the C++
+    # prefetcher) or "python"; None before the first epoch
+    input_path: str | None = None
 
     def __len__(self) -> int:
         return len(self.x)
@@ -126,11 +129,14 @@ class Dataset:
         if native is not False:
             try:
                 nb = self._native_batcher(bs)
-                return nb.epoch(shuffle=shuffle, seed=seed, epoch=epoch,
-                                drop_remainder=drop_remainder)
+                it = nb.epoch(shuffle=shuffle, seed=seed, epoch=epoch,
+                              drop_remainder=drop_remainder)
+                self.input_path = "native"
+                return it
             except RuntimeError:
                 if native:
                     raise
+        self.input_path = "python"
         return iter_batches(
             self.x, self.y, bs, shuffle=shuffle, seed=seed, epoch=epoch,
             drop_remainder=drop_remainder, start_batch=start_batch,

@@ -15,8 +15,8 @@ Attention is pluggable (``attention_impl``):
                  'seq' (see engines.seq_parallel).  K/V rotate via ppermute.
   'ring_flash' — ring schedule with the Pallas flash kernel as the local
                  block math (parallel.ring_attention.ring_flash_attention):
-                 long-context memory scaling AND the kernel's on-chip wins
-                 (BASELINE.md §attention).  Same contract as 'ring'.
+                 long-context memory scaling with the kernel's VMEM-resident
+                 score tiles.  Same contract as 'ring'.
   'ulysses'    — all-to-all head-parallel attention over 'seq'; same
                  contract, plus num_heads % seq_axis_size == 0.
   'ulysses_flash' — Ulysses reshard with the Pallas flash kernel as the

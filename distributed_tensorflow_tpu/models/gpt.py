@@ -38,6 +38,7 @@ device (the Megatron vocab-parallel-loss layout, for free from GSPMD).
 from __future__ import annotations
 
 import functools
+from typing import Any
 
 import flax.linen as nn
 import jax.numpy as jnp
@@ -108,7 +109,7 @@ class CausalSelfAttention(nn.Module):
                                # read — the stored table is what shrinks
     paged_blocks: int = 0      # >0: paged KV layout (decode_slots only).
                                # The cache becomes ONE physical pool of
-                               # this many (paged_block, kvh, head_dim)
+                               # this many (kvh, paged_block, head_dim)
                                # blocks shared by every slot; the caller
                                # passes per-slot int32 block tables and
                                # owns allocation/aliasing/CoW
@@ -121,6 +122,10 @@ class CausalSelfAttention(nn.Module):
                                # bitwise the monolithic math (prefill /
                                # oracle); the fused path is the decode
                                # hot op (tolerance parity)
+    paged_mesh: Any = None     # the serving mesh when the slot table
+                               # shards over its 'data' axis: the fused
+                               # kernel then runs under shard_map (Mosaic
+                               # kernels cannot be GSPMD-partitioned)
 
     @nn.compact
     def __call__(self, x, pos=None, block_tables=None):
@@ -400,12 +405,11 @@ class CausalSelfAttention(nn.Module):
 
         Cache variables are the shared physical pools; per-slot state is
         the caller's block table.  Writes scatter each (row, position)
-        K/V vector into ``pool[bt[row, pos // blk], pos % blk]``; reads
+        K/V vector into ``pool[bt[row, pos // blk], :, pos % blk]``; reads
         go fused (Pallas kernel) or unfused (gather + dense — bitwise
         the monolithic token-block branch's math over the gathered
         table, which is what keeps paged prefill exactly equal to
         monolithic prefill)."""
-        b = x.shape[0]
         blk = self.paged_block
         if self.max_len % blk:
             raise ValueError(
@@ -414,17 +418,17 @@ class CausalSelfAttention(nn.Module):
         store = jnp.int8 if self.kv_quant else self.dtype
         kp = self.variable(
             "cache", "key_pool", jnp.zeros,
-            (self.paged_blocks, blk, kvh, head_dim), store)
+            (self.paged_blocks, kvh, blk, head_dim), store)
         vp = self.variable(
             "cache", "value_pool", jnp.zeros,
-            (self.paged_blocks, blk, kvh, head_dim), store)
+            (self.paged_blocks, kvh, blk, head_dim), store)
         if self.kv_quant:
             ksp = self.variable(
                 "cache", "key_scale_pool", jnp.zeros,
-                (self.paged_blocks, blk, kvh), jnp.float32)
+                (self.paged_blocks, kvh, blk), jnp.float32)
             vsp = self.variable(
                 "cache", "value_scale_pool", jnp.zeros,
-                (self.paged_blocks, blk, kvh), jnp.float32)
+                (self.paged_blocks, kvh, blk), jnp.float32)
         if not ready:
             # .init(): create the pools, write nothing (the same
             # init-time guard as the monolithic cache)
@@ -445,42 +449,43 @@ class CausalSelfAttention(nn.Module):
         blk_ids = jnp.take_along_axis(
             block_tables, jnp.minimum(j, block_tables.shape[1] - 1), axis=1)
         off = jnp.where(oob, blk, idx % blk)
+        # the pool keeps the kv head ahead of the token axis (the Pallas
+        # kernel's window is then the pool's full last two dims); the two
+        # advanced indices around the head slice index (B, L) first, so
+        # the update keeps k/v's own (B, L, kvh[, D]) shape
         if self.kv_quant:
             qk, sk = compression.int8_channel_encode(k)
             qv, sv = compression.int8_channel_encode(v)
-            kp.value = kp.value.at[blk_ids, off].set(qk)
-            vp.value = vp.value.at[blk_ids, off].set(qv)
-            ksp.value = ksp.value.at[blk_ids, off].set(sk)
-            vsp.value = vsp.value.at[blk_ids, off].set(sv)
+            kp.value = kp.value.at[blk_ids, :, off].set(qk)
+            vp.value = vp.value.at[blk_ids, :, off].set(qv)
+            ksp.value = ksp.value.at[blk_ids, :, off].set(sk)
+            vsp.value = vsp.value.at[blk_ids, :, off].set(sv)
         else:
-            kp.value = kp.value.at[blk_ids, off].set(
+            kp.value = kp.value.at[blk_ids, :, off].set(
                 k.astype(kp.value.dtype))
-            vp.value = vp.value.at[blk_ids, off].set(
+            vp.value = vp.value.at[blk_ids, :, off].set(
                 v.astype(vp.value.dtype))
+        from distributed_tensorflow_tpu.ops.paged_attention import (
+            gather_pool, paged_attention)
         if self.paged_fused:
-            from distributed_tensorflow_tpu.ops.paged_attention import (
-                paged_attention)
             return paged_attention(
                 q, kp.value, vp.value, block_tables, idx[:, 0],
                 k_scale=ksp.value if self.kv_quant else None,
                 v_scale=vsp.value if self.kv_quant else None,
+                mesh=self.paged_mesh,
             ).astype(self.dtype)
         # unfused: gather the logical table back through the block table
         # and run the SAME masked dense attention as the monolithic
         # token-block branch — garbage rows from unmapped entries sit
         # past the validity mask
         t = self.max_len
-        keys = jnp.take(kp.value, block_tables, axis=0).reshape(
-            b, t, kvh, head_dim)
-        vals = jnp.take(vp.value, block_tables, axis=0).reshape(
-            b, t, kvh, head_dim)
+        keys = gather_pool(kp.value, block_tables)
+        vals = gather_pool(vp.value, block_tables)
         if self.kv_quant:
-            kscale = jnp.take(ksp.value, block_tables, axis=0).reshape(
-                b, t, kvh)
-            vscale = jnp.take(vsp.value, block_tables, axis=0).reshape(
-                b, t, kvh)
-            keys = compression.int8_channel_decode(keys, kscale, self.dtype)
-            vals = compression.int8_channel_decode(vals, vscale, self.dtype)
+            keys = compression.int8_channel_decode(
+                keys, gather_pool(ksp.value, block_tables), self.dtype)
+            vals = compression.int8_channel_decode(
+                vals, gather_pool(vsp.value, block_tables), self.dtype)
         valid = (jnp.arange(t)[None, None, :]
                  <= idx[:, :, None]).astype(self.dtype)
         return dense_attention(q, widen(keys), widen(vals),
@@ -519,6 +524,7 @@ class GPTBlock(nn.Module):
     paged_blocks: int = 0        # paged KV pool size (see attention)
     paged_block: int = 16        # tokens per physical block
     paged_fused: bool = False    # fused Pallas paged read (see attention)
+    paged_mesh: Any = None       # serving mesh for the fused read
 
     @nn.compact
     def __call__(self, x, train: bool = False, pos=None, block_tables=None):
@@ -530,7 +536,8 @@ class GPTBlock(nn.Module):
                                 kv_quant=self.kv_quant,
                                 paged_blocks=self.paged_blocks,
                                 paged_block=self.paged_block,
-                                paged_fused=self.paged_fused)(
+                                paged_fused=self.paged_fused,
+                                paged_mesh=self.paged_mesh)(
                                     nn.LayerNorm(dtype=self.dtype)(x), pos,
                                     block_tables)
         y = nn.Dropout(self.dropout_rate, deterministic=not train)(y)
@@ -625,6 +632,8 @@ class GPTLM(nn.Module):
                                  # max_len)
     paged_fused: bool = False    # fused Pallas paged-attention read
                                  # (ops/paged_attention.py)
+    paged_mesh: Any = None       # serving mesh for the fused read (its
+                                 # 'data' axis shards the slots)
 
     causal_lm = True  # read by engines/harness to select the LM data layout
 
@@ -750,6 +759,7 @@ class GPTLM(nn.Module):
                           paged_blocks=self.paged_blocks,
                           paged_block=self.paged_block,
                           paged_fused=self.paged_fused,
+                          paged_mesh=self.paged_mesh,
                           name=f"GPTBlock_{i}")(
                               x, train,
                               pos if (rope or self.decode_slots) else None,

@@ -19,48 +19,63 @@ Python paths take over.  Set ``DTF_TPU_NO_NATIVE=1`` to force Python paths.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import tempfile
 from pathlib import Path
 
 _SRC_DIR = Path(__file__).parent / "src"
-_LIB_NAME = "libdtf_native.so"
+_BUILD_DIR = Path(__file__).parent / "_build"
 _lib: ctypes.CDLL | None = None
 _load_failed = False
 
 
-def _lib_path() -> Path:
-    return Path(__file__).parent / "_build" / _LIB_NAME
-
-
-def build(force: bool = False) -> Path | None:
-    """Compile src/*.cc into the package-local _build/ dir; None on failure."""
-    out = _lib_path()
-    sources = [s for s in sorted(_SRC_DIR.glob("*.cc"))
-               if not s.stem.endswith("_test")]
-    if not sources:
-        return None
+def _build_keyed(stem: str, suffix: str, sources: list[Path],
+                 flags: list[str], timeout: int,
+                 force: bool = False) -> Path | None:
+    """g++ ``sources`` into ``_build/<stem>-<digest><suffix>``, the digest
+    taken over the source bytes and the compile flags.  ``_build/`` is not
+    tracked and a copy of the tree carries whatever sits in it with
+    whatever mtimes the copy gave, so a binary is reused only when its
+    name says it was built from exactly these sources — a stale one can
+    never be loaded.  The build is atomic (compile to a temp name, rename
+    over — parallel pytest safe) and drops the binaries of other source
+    contents; None when the toolchain or the build fails."""
+    h = hashlib.sha256(" ".join(flags).encode())
+    for src in sources:
+        h.update(src.name.encode() + b"\0" + src.read_bytes() + b"\0")
+    out = _BUILD_DIR / f"{stem}-{h.hexdigest()[:16]}{suffix}"
     if out.exists() and not force:
-        newest = max(s.stat().st_mtime for s in sources)
-        if out.stat().st_mtime >= newest:
-            return out
+        return out
     out.parent.mkdir(parents=True, exist_ok=True)
-    # atomic build: compile to a temp name, rename over (parallel pytest safe)
     with tempfile.NamedTemporaryFile(
-            dir=out.parent, suffix=".so", delete=False) as tmp:
+            dir=out.parent, suffix=suffix, delete=False) as tmp:
         tmp_path = Path(tmp.name)
-    cmd = [
-        os.environ.get("CXX", "g++"), "-O3", "-std=c++17", "-shared", "-fPIC",
-        "-pthread", "-Wall", *map(str, sources), "-o", str(tmp_path),
-    ]
+    cmd = [os.environ.get("CXX", "g++"), *flags, *map(str, sources),
+           "-o", str(tmp_path)]
     try:
-        subprocess.run(cmd, check=True, capture_output=True, timeout=120)
+        subprocess.run(cmd, check=True, capture_output=True, timeout=timeout)
     except (OSError, subprocess.SubprocessError):
         tmp_path.unlink(missing_ok=True)
         return None
     tmp_path.replace(out)
+    for old in out.parent.glob(f"{stem}-*{suffix}"):
+        if old != out:
+            old.unlink(missing_ok=True)
     return out
+
+
+def build(force: bool = False) -> Path | None:
+    """Compile src/*.cc into the package-local _build/ dir; None on failure."""
+    sources = [s for s in sorted(_SRC_DIR.glob("*.cc"))
+               if not s.stem.endswith("_test")]
+    if not sources:
+        return None
+    return _build_keyed(
+        "libdtf_native", ".so", sources,
+        ["-O3", "-std=c++17", "-shared", "-fPIC", "-pthread", "-Wall"],
+        timeout=120, force=force)
 
 
 def build_race_test() -> Path | None:
@@ -70,23 +85,13 @@ def build_race_test() -> Path | None:
     libtsan is unavailable.  Run it; any 'WARNING: ThreadSanitizer' output
     (exit code 66 under default TSAN options) is a detected race.
     """
-    out = Path(__file__).parent / "_build" / "pipeline_tsan_test"
     sources = [_SRC_DIR / "pipeline.cc", _SRC_DIR / "pipeline_tsan_test.cc"]
     if not all(s.exists() for s in sources):
         return None
-    if out.exists() and out.stat().st_mtime >= max(
-            s.stat().st_mtime for s in sources):
-        return out
-    out.parent.mkdir(parents=True, exist_ok=True)
-    cmd = [
-        os.environ.get("CXX", "g++"), "-O1", "-g", "-std=c++17", "-pthread",
-        "-fsanitize=thread", *map(str, sources), "-o", str(out),
-    ]
-    try:
-        subprocess.run(cmd, check=True, capture_output=True, timeout=180)
-    except (OSError, subprocess.SubprocessError):
-        return None
-    return out
+    return _build_keyed(
+        "pipeline_tsan_test", ".bin", sources,
+        ["-O1", "-g", "-std=c++17", "-pthread", "-fsanitize=thread"],
+        timeout=180)
 
 
 def load() -> ctypes.CDLL | None:

@@ -63,7 +63,7 @@ to ``steps_per_call=1`` (see Trainer.resolve_steps_per_call).
 from distributed_tensorflow_tpu.observability.metrics import (
     LogHistogram, MetricsRegistry, exact_percentile)
 from distributed_tensorflow_tpu.observability.report import (
-    build_run_report, runtime_environment, serve_section)
+    build_run_report, device_memory, runtime_environment, serve_section)
 from distributed_tensorflow_tpu.observability.sink import (
     SCHEMA_VERSION, AsyncJsonlSink)
 from distributed_tensorflow_tpu.observability.roofline import (
@@ -94,6 +94,7 @@ __all__ = [
     "Timeline",
     "Tracer",
     "build_run_report",
+    "device_memory",
     "device_peaks",
     "diff_manifests",
     "program_attribution",

@@ -25,44 +25,52 @@ from typing import Any
 from distributed_tensorflow_tpu.observability.sink import SCHEMA_VERSION
 
 
-def runtime_environment() -> dict[str, Any]:
+def runtime_environment(devices=None) -> dict[str, Any]:
     """The execution-environment facts that make perf numbers attributable
-    across containers (the r03–r05 lesson: a bench trajectory without
-    them cannot be compared): jax version, device kind, and the effective
-    XLA flag carriers (``XLA_FLAGS`` / ``LIBTPU_INIT_ARGS`` — the overlap
-    flags ``utils/harness.enable_overlap_flags`` sets ride the latter).
-    The jax fields degrade to None rather than force a backend where none
-    was initialized by the caller's run."""
-    env: dict[str, Any] = {
-        "jax_version": None,
-        "device_kind": None,
+    across machines: jax version, the platform, device kind and count of
+    ``devices`` — the devices of the mesh the run built, so the section
+    says where the work ran, not what a backend peek found — and the
+    effective XLA flag carriers (``XLA_FLAGS`` / ``LIBTPU_INIT_ARGS`` — the
+    overlap flags ``utils/harness.enable_overlap_flags`` sets ride the
+    latter).  Without ``devices`` the device fields are None: this function
+    never initializes a backend itself, which would lock in whatever flags
+    are set NOW, before a caller's ``enable_overlap_flags`` could act."""
+    import jax
+
+    devices = list(devices) if devices is not None else []
+    first = devices[0] if devices else None
+    return {
+        "jax_version": jax.__version__,
+        "platform": first.platform if first else None,
+        "device_kind": first.device_kind if first else None,
+        "device_count": len(devices) or None,
         "xla_flags": os.environ.get("XLA_FLAGS"),
         "libtpu_init_args": os.environ.get("LIBTPU_INIT_ARGS"),
     }
-    try:
-        import jax
 
-        env["jax_version"] = jax.__version__
-        # device_kind only when a backend ALREADY exists: jax.local_devices()
-        # would otherwise initialize one as a side effect, locking in
-        # whatever LIBTPU_INIT_ARGS/XLA_FLAGS are set NOW and silently
-        # ignoring flags the caller (e.g. enable_overlap_flags) meant to
-        # apply before its own init — the exact misattribution this
-        # section exists to prevent
-        from jax._src import xla_bridge
 
-        if getattr(xla_bridge, "_backends", None):
-            env["device_kind"] = jax.local_devices()[0].device_kind
-    except Exception:
-        pass
-    return env
+def device_memory(devices) -> list[dict[str, Any]]:
+    """What each device the run used holds, as its backend reports it
+    (``memory_stats()``): ``bytes_in_use`` now and ``peak_bytes_in_use``
+    since the process started — one row per device, so state that sits on
+    device 0 alone shows as rows of zeros beside it.  Both are None where
+    the backend keeps no such count (XLA:CPU)."""
+    rows = []
+    for d in devices:
+        stats = d.memory_stats() or {}
+        rows.append({"id": d.id,
+                     "bytes_in_use": stats.get("bytes_in_use"),
+                     "peak_bytes_in_use": stats.get("peak_bytes_in_use")})
+    return rows
 
 
 def serve_section(summary: dict[str, Any] | None,
                   n_devices: int = 1, tracer=None) -> dict[str, Any] | None:
     """Normalize a ContinuousBatcher summary into the run-report/bench
-    ``serve`` section: the per-request result objects are dropped (the
-    section must stay JSON), and the per-chip rates — requests/sec (the
+    ``serve`` section: the per-request result objects are reduced to
+    their token streams (``generated_tokens``, one list per request in rid
+    order — the section must stay JSON, and a greedy window is compared
+    with another by what it generated), and the per-chip rates — requests/sec (the
     round-7 headline) and goodput-under-SLO (the round-13 one, mirroring
     examples_per_sec_per_device) — are derived here so every surface
     divides by the same device count.  ``tracer`` (when enabled) adds the
@@ -72,6 +80,8 @@ def serve_section(summary: dict[str, Any] | None,
     if summary is None:
         return None
     sec = {k: v for k, v in summary.items() if k != "results"}
+    sec["generated_tokens"] = [[int(t) for t in r.tokens]
+                               for r in summary.get("results", [])]
     for key in ("serve_requests_per_sec", "serve_goodput_under_slo"):
         v = sec.get(key)
         sec[f"{key}_per_chip"] = (
@@ -89,14 +99,16 @@ def build_run_report(fit_result: dict[str, Any], *,
                      watchdog=None, metrics_logger=None, tracer=None,
                      serve: dict[str, Any] | None = None,
                      timeline=None, ledger=None, roofline=None,
-                     ) -> dict[str, Any]:
+                     devices=None) -> dict[str, Any]:
     """Assemble the run report from the Trainer's fit result and the live
     telemetry objects.  Every argument except ``fit_result`` is optional —
     absent subsystems report as None, so readers can distinguish
     "disabled" from "zero".  ``serve`` is a post-training serving window's
     section (``serve_section``) — serving gets the same trajectory and
     regression gating training has (`analyze diff` flattens the nested
-    serve_* keys)."""
+    serve_* keys).  ``devices`` are the run's mesh devices: named in the
+    ``environment`` section, and read for ``device_memory`` while the
+    training state is still alive."""
     st = fit_result.get("step_time") or {}
     elapsed = float(fit_result.get("elapsed") or 0.0)
 
@@ -297,10 +309,12 @@ def build_run_report(fit_result: dict[str, Any], *,
         # keys flatten from the serve section's serve_* prefix already)
         report["train_mfu"] = fit_result.get("train_mfu")
 
-    # execution environment (jax version, device kind, effective XLA
-    # flags): bench/report trajectories stay attributable across
-    # containers — the r03–r05 measurement-blackout lesson
-    report["environment"] = runtime_environment()
+    # execution environment (jax version, platform, device kind and
+    # count, effective XLA flags): trajectories stay attributable across
+    # machines
+    report["environment"] = runtime_environment(devices)
+    report["device_memory"] = (device_memory(devices)
+                               if devices is not None else None)
 
     # the telemetry's own measured cost, against the run's wall clock —
     # this is the number the 5%-overhead acceptance bound reads

@@ -54,9 +54,10 @@ from dataclasses import dataclass
 PEAK_TABLE_REVISION = 1
 
 # Public per-chip figures: (device_kind substring, peak bf16 matmul
-# flops/s, HBM bytes/s).  First match wins, so specific v5/v6 entries
-# precede the bare "v5" fallback (some libtpu builds report v5p as just
-# "TPU v5").  f32 is listed at half the bf16 rate and int8 at double —
+# flops/s, HBM bytes/s).  First match wins.  The chip tool's v5e reports
+# "TPU v5 lite" (chip run, PR 21); there is no bare "v5" row, so a v5
+# string this table does not know stays unknown instead of being priced
+# as a v5p.  f32 is listed at half the bf16 rate and int8 at double —
 # the MXU convention, part of what REVISION pins.
 _DEVICE_PEAKS = (
     ("v6 lite", 918e12, 1640e9),
@@ -64,7 +65,6 @@ _DEVICE_PEAKS = (
     ("v5 lite", 197e12, 819e9),
     ("v5e", 197e12, 819e9),
     ("v5p", 459e12, 2765e9),
-    ("v5", 459e12, 2765e9),
     ("v4", 275e12, 1228e9),
     ("v3", 123e12, 900e9),
     ("v2", 45e12, 700e9),

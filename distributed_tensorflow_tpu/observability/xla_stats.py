@@ -1,7 +1,6 @@
 """Per-compiled-program XLA memory/compile ledger.
 
-Hardware has been blind since BENCH_r02, yet XLA reports HBM footprint
-and compile cost for free on every backend: ``compiled.memory_analysis()``
+XLA reports HBM footprint and compile cost for free on every backend: ``compiled.memory_analysis()``
 carries argument/output/temp/generated-code bytes per executable (the
 tests already read it on CPU), and compile wall-time is one
 ``perf_counter`` pair around ``lower().compile()``.  :class:`ProgramLedger`

@@ -34,20 +34,20 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # TPU-specific bits are unavailable in some CPU-only wheels
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+_VMEM = pltpu.VMEM
 
 NEG_INF = -1e30  # matches parallel.ring_attention.NEG_INF: keeps exp()
                  # NaN-free when an entire row is masked
 _TINY = 1e-30
-_VMEM_BYTES = 128 * 2**20  # v4/v5e/v5p VMEM ≈ 128 MiB; the budget below
-                           # validates block sizes BEFORE launching Mosaic
+_VMEM_BYTES = 16 * 2**20  # the scoped VMEM limit Mosaic enforces per kernel
+                          # on the v5e, of 128 MiB physical (chip run, PR 21:
+                          # 2048×2048 tiles at head 64 were refused with
+                          # "Scoped allocation with size 20.99M and limit
+                          # 16.00M exceeded scoped vmem limit"; 1024×2048 and
+                          # the 512×1024 defaults compile).  The budget below
+                          # validates block sizes BEFORE launching Mosaic
 
 
 def _check_vmem_budget(bq: int, bk: int, d: int) -> None:
@@ -57,10 +57,12 @@ def _check_vmem_budget(bq: int, bk: int, d: int) -> None:
     q/k/v blocks (bq·d + 2·bk·d) plus the f32 accumulators (~bq·d), with
     Pallas double-buffering the HBM-windowed operands.  An oversized
     choice otherwise surfaces as an opaque Mosaic allocation error deep in
-    compilation.  The check is deliberately a conservative estimate (×2
-    for double buffering, f32 everywhere) against a ~128 MiB budget —
-    kernels near the line may still fail in Mosaic, but the common
-    mistake (block_q/block_k sized like sequence lengths) is caught here."""
+    compilation.  The estimate (×2 for double buffering, f32 everywhere)
+    is held against the scoped limit the compiler reported on the chip; it
+    came within 5% of Mosaic's own figure where that was refused (20.1 MiB
+    estimated for 2048×2048 at head 64, 20.99M reported).  Kernels near the
+    line may still fail in Mosaic, but the common mistake (block_q/block_k
+    sized like sequence lengths) is caught here."""
     tile = bq * bk * 4                       # score/prob tile, f32
     operands = 2 * (bq * d + 2 * bk * d) * 4  # q + k/v, double-buffered
     acc = 2 * bq * d * 4 + 2 * bq * 4        # out accumulator + m/l rows
@@ -74,23 +76,32 @@ def _check_vmem_budget(bq: int, bk: int, d: int) -> None:
             f"smaller blocks (defaults 512/1024)")
 
 
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
+def resolve_interpret(interpret: bool | None) -> bool:
+    """Interpret mode (and the jnp twins behind it) is the CPU test lane.
+    On a TPU backend the kernels always go through Mosaic: asking for the
+    interpreter there is refused rather than obeyed, so no caller can end
+    up off the compiled kernel on the chip."""
+    on_tpu = jax.default_backend() == "tpu"
+    if interpret and on_tpu:
+        raise ValueError(
+            "interpret=True on a TPU backend: the Pallas kernels compile "
+            "with Mosaic there; the interpreter and its jnp twins are the "
+            "CPU test lane")
+    return not on_tpu if interpret is None else interpret
 
 
-def _join_vma(*xs) -> frozenset:
+def join_vma(*xs) -> frozenset:
     """Union of the operands' varying-axes sets — pallas_call outputs must
     declare their vma explicitly when running inside `jax.shard_map`
     (check_vma); outside shard_map this is the empty set."""
     vma = frozenset()
     for x in xs:
-        vma |= jax.typeof(x).vma
+        if x is not None:
+            vma |= jax.typeof(x).vma
     return vma
 
 
 def _block_spec(shape, index_map):
-    if _VMEM is None:
-        return pl.BlockSpec(shape, index_map)
     return pl.BlockSpec(shape, index_map, memory_space=_VMEM)
 
 
@@ -182,14 +193,14 @@ def _fwd(q, k, v, mask, scale, causal, bq, bk, interpret):
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, lq, d), q.dtype,
-                                 vma=_join_vma(q, k, v, mask)),
+                                 vma=join_vma(q, k, v, mask)),
             jax.ShapeDtypeStruct((bh, 1, lq), jnp.float32,
-                                 vma=_join_vma(q, k, v, mask)),
+                                 vma=join_vma(q, k, v, mask)),
         ],
         scratch_shapes=[
-            _VMEM((bq, 1), jnp.float32) if _VMEM else None,
-            _VMEM((bq, 1), jnp.float32) if _VMEM else None,
-            _VMEM((bq, d), jnp.float32) if _VMEM else None,
+            _VMEM((bq, 1), jnp.float32),
+            _VMEM((bq, 1), jnp.float32),
+            _VMEM((bq, d), jnp.float32),
         ],
         interpret=interpret,
     )(q, k, v, mask)
@@ -287,8 +298,8 @@ def _bwd(q, k, v, mask, lse, delta, do, scale, causal, bq, bk, interpret):
                   qspec, rowspec, rowspec],
         out_specs=[qspec],
         out_shape=[jax.ShapeDtypeStruct(
-            q.shape, q.dtype, vma=_join_vma(q, k, v, mask, do, lse, delta))],
-        scratch_shapes=[_VMEM((bq, d), jnp.float32) if _VMEM else None],
+            q.shape, q.dtype, vma=join_vma(q, k, v, mask, do, lse, delta))],
+        scratch_shapes=[_VMEM((bq, d), jnp.float32)],
         interpret=interpret,
     )(q, k, v, mask, do, lse, delta)[0]
 
@@ -306,12 +317,12 @@ def _bwd(q, k, v, mask, lse, delta, do, scale, causal, bq, bk, interpret):
         out_specs=[kspec, kspec],
         out_shape=[jax.ShapeDtypeStruct(
                        k.shape, k.dtype,
-                       vma=_join_vma(q, k, v, mask, do, lse, delta)),
+                       vma=join_vma(q, k, v, mask, do, lse, delta)),
                    jax.ShapeDtypeStruct(
                        v.shape, v.dtype,
-                       vma=_join_vma(q, k, v, mask, do, lse, delta))],
-        scratch_shapes=[_VMEM((bk, d), jnp.float32) if _VMEM else None,
-                        _VMEM((bk, d), jnp.float32) if _VMEM else None],
+                       vma=join_vma(q, k, v, mask, do, lse, delta))],
+        scratch_shapes=[_VMEM((bk, d), jnp.float32),
+                        _VMEM((bk, d), jnp.float32)],
         interpret=interpret,
     )(q, k, v, mask, do, lse, delta)
     return dq, dk, dv
@@ -362,18 +373,14 @@ def flash_attention(q, k, v, *, causal: bool = False,
         is expected when True).
       kv_mask: optional (B, Lk) key-validity mask (>0 == valid).
       block_q / block_k: VMEM tile sizes; clamped to the (padded) sequence
-        lengths.  At the defaults (512, 1024), `bench.py --attention`
-        measured fwd+bwd vs XLA dense attention on TPU v5e (B=4 H=8 D=128
-        f32 causal): 3.1× faster at L=1024, 4.1× at L=4096 — recorded in
-        BASELINE.md §attention.  The (bq × bk) f32 score tile must fit VMEM
-        alongside the q/k/v blocks (2 MB at default).
+        lengths.  The (bq × bk) f32 score tile must fit VMEM alongside the
+        q/k/v blocks (2 MB at the defaults).
       interpret: force Pallas interpret mode; default = auto (True off-TPU).
 
     Returns (B, Lq, H, D).  Rows with no valid key return 0 (same guard as
     ring_attention).
     """
-    if interpret is None:
-        interpret = _interpret_default()
+    interpret = resolve_interpret(interpret)
     b, lq, h, d = q.shape
     lk = k.shape[1]
     scale = scale if scale is not None else d ** -0.5
@@ -381,7 +388,7 @@ def flash_attention(q, k, v, *, causal: bool = False,
     mask = kv_mask if kv_mask is not None else jnp.ones((b, lk), jnp.float32)
     mask = mask.astype(jnp.float32)
 
-    if interpret and _join_vma(q, k, v, mask):
+    if interpret and join_vma(q, k, v, mask):
         # inside shard_map on a non-TPU backend: Pallas's HLO interpreter
         # cannot currently lower under vma checking, so run the pure-jnp
         # kernel twin (identical math incl. NEG_INF/_TINY guards, and
@@ -500,8 +507,7 @@ def flash_fwd_block(q, k, v, kv_mask, *, scale, causal=False,
     means the pair sits on the ring's diagonal (identical global offsets);
     off-diagonal causal blocks are entirely-past (causal=False) or
     entirely-future (skipped by the caller)."""
-    if interpret is None:
-        interpret = _interpret_default()
+    interpret = resolve_interpret(interpret)
     if interpret:
         return _fwd_block_ref(q, k, v, kv_mask, scale, causal)
     b, lq, h, d = q.shape
@@ -531,8 +537,7 @@ def flash_bwd_block(q, k, v, kv_mask, do, lse, delta, *, scale, causal=False,
     the FULL output (flash's backward recovers this block's probabilities
     as exp(s − lse)).  Returns (dq, dk, dv) in f32, each the contribution
     of this (q-block, k-block) pair alone."""
-    if interpret is None:
-        interpret = _interpret_default()
+    interpret = resolve_interpret(interpret)
     if interpret:
         return _bwd_block_ref(q, k, v, kv_mask, do, lse, delta, scale,
                               causal)

@@ -79,17 +79,6 @@ def _numel(shape) -> int:
 _RNG_TAG = 0x636F6D70
 
 
-def _axis_size(axis: str) -> int:
-    """Static mesh-axis size inside a mapped function.  ``lax.axis_size``
-    where it exists; ``psum(1, axis)`` (constant-folded to the static
-    size) on older jax — this module must import-and-run on containers
-    whose jax predates the engine layer's floor, because the codec math
-    itself is exercised there via ``vmap`` axis emulation."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis)
-    return lax.psum(1, axis_name=axis)
-
-
 def codec_rng(rng: jax.Array) -> jax.Array:
     """The codec's rounding key for a step, derived from the engine's step
     rng.  Engines pass a per-DEVICE rng when each device quantizes its own
@@ -288,7 +277,7 @@ class Int8Codec(GradCodec):
         return size * jnp.dtype(dtype).itemsize
 
     def _reduce(self, tree, axis, rng, mean: bool):
-        n = _axis_size(axis)
+        n = lax.axis_size(axis)
 
         def leaf(x, key):
             if not self._compressible(x.dtype):
@@ -328,7 +317,7 @@ class Int8Codec(GradCodec):
     def neighbor_mean(self, tree, axis, degree=1, *, rng=None):
         if degree <= 0:
             return tree
-        n = _axis_size(axis)
+        n = lax.axis_size(axis)
         if 2 * degree + 1 >= n:
             # whole-ring neighborhood — same degenerate case as the
             # uncompressed mix (collectives.neighbor_mean)

@@ -1370,7 +1370,7 @@ class PagedSlotKVCache(SlotKVCache):
 
     What changes vs the monolithic table:
 
-    * DEVICE: cache leaves are pools ``(num_blocks+1, block, kv_heads,
+    * DEVICE: cache leaves are pools ``(num_blocks+1, kv_heads, block,
       head_dim)`` (+1 is a scratch block — see below) instead of
       ``(slots, max_len, ...)`` rows; the model's paged decode mode
       (models/gpt.py ``paged_blocks``) scatters each write through the
@@ -1483,7 +1483,8 @@ class PagedSlotKVCache(SlotKVCache):
                               kv_quant=self.quantized,
                               paged_blocks=self.num_blocks + 1,
                               paged_block=block,
-                              paged_fused=self.paged_fused)
+                              paged_fused=self.paged_fused,
+                              paged_mesh=mesh)
         self.dm_gather = self.dm.clone(paged_fused=False)
         self._rng = rng if rng is not None else jax.random.key(0)
 

@@ -16,6 +16,7 @@ import dataclasses
 import os
 import sys
 import time
+from pathlib import Path
 from typing import Any, Callable
 
 import jax
@@ -84,9 +85,6 @@ class ExperimentConfig:
                                     # pre-overlap programs.  ~4 is the
                                     # recommended size; pipeline modes
                                     # reject it like grad_compression
-    compile_cache: str | None = None  # persistent XLA compilation cache
-                                    # dir (jax_compilation_cache_dir):
-                                    # repeat runs skip recompiles
     weight_decay: float = 0.0       # >0: AdamW decoupled weight decay
     clip_norm: float = 0.0          # >0: clip gradients to this global norm
                                     # before the optimizer update
@@ -435,28 +433,36 @@ class ExperimentConfig:
                                            # round 19
 
 
-def enable_compile_cache(directory: str | os.PathLike) -> str:
-    """Point XLA's persistent compilation cache at ``directory``
-    (``--compile-cache``): repeat runs — and bench warmups — reuse the
-    compiled executables of unchanged programs instead of re-tracing and
-    re-compiling them.  Creates the directory, drops jax's minimum-compile-
-    time/entry-size gates so even fast CPU-test compiles persist (the gates
-    exist to avoid caching trivia; a user who passed a cache dir wants
-    hits), and returns the resolved path.  Safe to call before or after
-    backend initialization — the cache dir is read per compile."""
-    import pathlib
+def resolve_compile_cache() -> str | None:
+    """Place XLA's persistent compilation cache and return its directory.
 
-    import jax
+    The entry points (``cli.main``, ``bench.main``, ``chip_smoke.py``, the
+    examples) call this before their first compile; package import and
+    ``run()`` do not, so a library caller gets a cache only if the
+    environment asks for one.  Where ``JAX_COMPILATION_CACHE_DIR`` is set,
+    JAX reads it itself and no directory is set in code.  Otherwise the
+    cache lives in one fixed directory of the checkout, ``.jax_cache``
+    beside the package: the directory is part of the cache key, so one
+    that moved between runs would never hit.  jax's minimum-compile-time
+    and entry-size gates are dropped either way, so every program of a run
+    is a hit on the next one.
 
-    path = pathlib.Path(directory)
-    path.mkdir(parents=True, exist_ok=True)
+    A process held to the CPU (``jax_platforms == "cpu"``, the setting of
+    the tests and of the development recipe) gets no directory of its own
+    and returns None: nothing is measured there, and XLA:CPU's loader logs
+    a multi-kilobyte feature-mismatch error for every entry it reads back.
+    The backend is not touched to find this out — ``cli.main`` may still
+    have ``jax.distributed.initialize`` ahead of it."""
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed:
+        return placed
+    if jax.config.jax_platforms == "cpu":
+        return None
+    path = Path(__file__).resolve().parents[2] / ".jax_cache"
+    path.mkdir(exist_ok=True)
     jax.config.update("jax_compilation_cache_dir", str(path))
-    for knob, value in (("jax_persistent_cache_min_compile_time_secs", 0.0),
-                        ("jax_persistent_cache_min_entry_size_bytes", -1)):
-        try:
-            jax.config.update(knob, value)
-        except Exception:  # knob not present on this jax — cache still on
-            pass
     return str(path)
 
 
@@ -687,6 +693,20 @@ def _setup(config: ExperimentConfig) -> _Experiment:
         return _setup_expert_parallel(config)
     mesh = meshlib.create_mesh(config.n_devices)
     n = mesh.shape[meshlib.DATA_AXIS]
+    if (config.engine == "fsdp" and n > 1 and config.attention_impl in (
+            "flash", "ring_flash", "ulysses_flash")):
+        # the fsdp engine is one GSPMD program over the mesh, and Mosaic
+        # refuses to be partitioned by it ("Mosaic kernels cannot be
+        # automatically partitioned. Please wrap the call in a shard_map",
+        # four-chip run, PR 21); the sync/allreduce engines run the model
+        # inside shard_map, where the kernel compiles
+        raise ValueError(
+            f"--attention {config.attention_impl} runs the Pallas flash "
+            f"kernel, which the fsdp engine cannot hold on more than one "
+            f"device: its step is one GSPMD-partitioned program and a "
+            f"Mosaic kernel cannot be partitioned automatically.  Train "
+            f"with the sync/allreduce engine (the model runs inside "
+            f"shard_map there), or drop --attention under -ds fsdp")
 
     train_ds, test_ds = _load_data(config)
     if config.model in _LM_MODELS and config.model_fn is None:
@@ -1598,10 +1618,6 @@ def run(config: ExperimentConfig) -> dict[str, Any]:
         raise ValueError(f"--timeline-interval must be >= 0 seconds "
                          f"(0 = sample at every boundary), got "
                          f"{config.timeline_interval}")
-    if config.compile_cache:
-        # before any compile: the whole run's programs become cache hits
-        # on the next invocation with the same cache dir
-        enable_compile_cache(config.compile_cache)
     if config.grad_bucket_mb:
         # before backend init: the latency-hiding/async-collective flags
         # only take effect at compile time (recorded in the run report's
@@ -1918,11 +1934,19 @@ def run(config: ExperimentConfig) -> dict[str, Any]:
                          * config.pipeline_parallel * config.expert_parallel)
         model_name = config.model if config.model_fn is None else getattr(
             config.model_fn, "__name__", "custom_model_fn")
+        mesh_devices = list(ex.mesh.devices.flat)
         summary = {
             "engine": engine_name,
             "model": model_name,
+            # where the run executed, read from the mesh it built: a CPU
+            # fallback shows here, at top level, not in a nested report
+            "platform": mesh_devices[0].platform,
+            "device_kind": mesh_devices[0].device_kind,
             "dataset": train_ds.name,
             "synthetic_data": train_ds.synthetic,
+            # which producer fed the last epoch: the C++ prefetcher
+            # ("native", needs g++ on the host) or the Python gather
+            "input_pipeline": train_ds.input_path,
             "n_devices": total_devices,
             "data_parallel": n,
             "seq_parallel": config.seq_parallel,
@@ -2020,7 +2044,7 @@ def run(config: ExperimentConfig) -> dict[str, Any]:
                                   metrics_logger=metrics_logger,
                                   tracer=tracer, serve=serve_sec,
                                   timeline=timeline, ledger=ledger,
-                                  roofline=roofline)
+                                  roofline=roofline, devices=mesh_devices)
         summary["run_report"] = report
         sink.emit("run_report", **report)
         sink.emit("summary", **summary)
@@ -2455,9 +2479,17 @@ def _serve_from_state(config: ExperimentConfig, ex: _Experiment, state,
     hook: a SIGTERM'd serve window stops admitting, finishes in-flight
     requests, and its partial section still flushes into the report."""
     from distributed_tensorflow_tpu.observability import (
-        SLOMonitor, serve_section)
+        SLOMonitor, device_memory, serve_section)
     from distributed_tensorflow_tpu.serving import (
         ContinuousBatcher, Request, SlotKVCache)
+
+    def section(summary):
+        """The serve section, with each mesh device's memory read while
+        the slot table is still alive: a table that shards over 'data'
+        shows as bytes on every device, not on device 0 alone."""
+        sec = serve_section(summary, total_devices, tracer=tracer)
+        sec["device_memory"] = device_memory(ex.mesh.devices.flat)
+        return sec
 
     get_params = getattr(ex.engine, "eval_params", None)
     params = get_params(state) if get_params is not None else state.params
@@ -2637,7 +2669,7 @@ def _serve_from_state(config: ExperimentConfig, ex: _Experiment, state,
                                           should_stop=should_stop)
             finally:
                 replica_set.close()
-        return serve_section(summary, total_devices, tracer=tracer)
+        return section(summary)
     batcher_kwargs: dict[str, Any] = {}
     if config.serve_multi_step is not None:
         # conditional-kwarg pattern: the round-19 batcher construction
@@ -2654,7 +2686,7 @@ def _serve_from_state(config: ExperimentConfig, ex: _Experiment, state,
             draft_kv=draft_kv, draft_k=config.serve_draft_k,
             timeline=timeline,
             roofline=serve_roofline, **batcher_kwargs).run(requests)
-    return serve_section(summary, total_devices, tracer=tracer)
+    return section(summary)
 
 
 def steps_to_accuracy(

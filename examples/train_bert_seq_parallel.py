@@ -6,8 +6,7 @@ slice of every sequence, and attention runs as a blockwise ppermute ring
 (parallel/ring_attention.py) so the full sequence never materializes on one
 device.  No reference counterpart (SURVEY.md §2.2: no attention anywhere).
 
-  JAX_PLATFORM_NAME=cpu JAX_PLATFORMS="" \
-  XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+  JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
   python examples/train_bert_seq_parallel.py
 """
 
@@ -22,6 +21,7 @@ from distributed_tensorflow_tpu.data.loaders import load_text_dataset
 from distributed_tensorflow_tpu.engines import SeqParallelEngine
 from distributed_tensorflow_tpu.models import create_model
 from distributed_tensorflow_tpu.parallel import mesh as meshlib
+from distributed_tensorflow_tpu.utils.harness import resolve_compile_cache
 
 
 def main(seq_parallel: int = 4) -> None:
@@ -51,4 +51,5 @@ def main(seq_parallel: int = 4) -> None:
 
 
 if __name__ == "__main__":
+    resolve_compile_cache()
     main()

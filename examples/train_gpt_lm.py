@@ -7,8 +7,7 @@ flash kernel, and long-context ring-attention sequence parallelism (pass
 ``--seq-parallel 4``).  No reference counterpart (SURVEY.md §2.2: no
 language models anywhere).
 
-  JAX_PLATFORM_NAME=cpu JAX_PLATFORMS="" \
-  XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+  JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
   python examples/train_gpt_lm.py [--seq-parallel 4]
 """
 
@@ -25,6 +24,7 @@ from distributed_tensorflow_tpu.engines import (
     SeqParallelEngine, SyncEngine, Trainer)
 from distributed_tensorflow_tpu.models import create_model
 from distributed_tensorflow_tpu.parallel import mesh as meshlib
+from distributed_tensorflow_tpu.utils.harness import resolve_compile_cache
 
 
 def main(seq_parallel: int = 1) -> None:
@@ -47,6 +47,7 @@ def main(seq_parallel: int = 1) -> None:
         # Pallas is orders of magnitude slower than XLA there — right for
         # correctness tests, wrong for a demo)
         impl = "flash" if jax.default_backend() == "tpu" else "dense"
+        print(f"attention: {impl} (backend {jax.default_backend()})")
         model = create_model("gpt", num_classes=train.num_classes,
                              hidden=64, layers=2, heads=4, ffn=128,
                              max_len=64, attention_impl=impl)
@@ -71,6 +72,7 @@ def main(seq_parallel: int = 1) -> None:
 
 
 if __name__ == "__main__":
+    resolve_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--seq-parallel", type=int, default=1)
     main(p.parse_args().seq_parallel)

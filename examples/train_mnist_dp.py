@@ -5,8 +5,7 @@ The library rendering of the reference's default workload (reference
 initializer.py:12-21 MLP + MNIST): sync DP over every local device, full
 test-set eval.  Runs on real TPUs or the fake CPU mesh:
 
-  JAX_PLATFORM_NAME=cpu JAX_PLATFORMS="" \
-  XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+  JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
   python examples/train_mnist_dp.py
 """
 
@@ -19,6 +18,7 @@ from distributed_tensorflow_tpu.data.loaders import load_dataset
 from distributed_tensorflow_tpu.engines import Trainer
 from distributed_tensorflow_tpu.models import create_model
 from distributed_tensorflow_tpu.parallel import mesh as meshlib
+from distributed_tensorflow_tpu.utils.harness import resolve_compile_cache
 
 
 def main() -> None:
@@ -38,4 +38,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    resolve_compile_cache()
     main()

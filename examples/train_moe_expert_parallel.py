@@ -5,8 +5,7 @@ Experts shard over an 'expert' mesh axis (GShard/Switch dense-dispatch,
 models/moe.py); XLA lowers the dispatch einsums to all-to-alls over ICI.
 No reference counterpart (SURVEY.md §2.2: no MoE anywhere).
 
-  JAX_PLATFORM_NAME=cpu JAX_PLATFORMS="" \
-  XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+  JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
   python examples/train_moe_expert_parallel.py
 """
 
@@ -21,6 +20,7 @@ from distributed_tensorflow_tpu.data.loaders import load_dataset
 from distributed_tensorflow_tpu.engines import ExpertParallelEngine
 from distributed_tensorflow_tpu.models import create_model
 from distributed_tensorflow_tpu.parallel import mesh as meshlib
+from distributed_tensorflow_tpu.utils.harness import resolve_compile_cache
 
 
 def main(expert_parallel: int = 4, num_experts: int = 8) -> None:
@@ -50,4 +50,5 @@ def main(expert_parallel: int = 4, num_experts: int = 8) -> None:
 
 
 if __name__ == "__main__":
+    resolve_compile_cache()
     main()

@@ -8,8 +8,7 @@ ring stays manual, so stage activations ride ICI between stages AND
 expert dispatch rides ICI within them.  No reference counterpart
 (SURVEY.md §2.2: no pipeline, no MoE anywhere).
 
-  JAX_PLATFORM_NAME=cpu JAX_PLATFORMS="" \
-  XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+  JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
   python examples/train_moe_pipeline.py
 
 CLI spelling of the same run:
@@ -29,6 +28,7 @@ from distributed_tensorflow_tpu.data.loaders import load_lm_dataset
 from distributed_tensorflow_tpu.engines.pipeline import PipelineEngine
 from distributed_tensorflow_tpu.models.gpt import gpt_pipeline_stages
 from distributed_tensorflow_tpu.parallel import mesh as meshlib
+from distributed_tensorflow_tpu.utils.harness import resolve_compile_cache
 
 
 def main(pipeline_parallel: int = 2, expert_parallel: int = 2,
@@ -66,4 +66,5 @@ def main(pipeline_parallel: int = 2, expert_parallel: int = 2,
 
 
 if __name__ == "__main__":
+    resolve_compile_cache()
     main()

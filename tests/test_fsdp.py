@@ -99,6 +99,16 @@ def test_fsdp_converges_and_cli_selects(mesh8, data):
     args = build_parser().parse_args(["-m", "d", "-ds", "fsdp"])
     assert select_engine(args) == "fsdp"
 
+    # the Pallas flash kernel cannot ride the engine's GSPMD program on
+    # more than one device (Mosaic refuses automatic partitioning on the
+    # chip): rejected by name before any data is loaded, on every platform
+    from distributed_tensorflow_tpu.utils.harness import (
+        ExperimentConfig, run)
+
+    with pytest.raises(ValueError, match="fsdp engine cannot hold"):
+        run(ExperimentConfig(engine="fsdp", model="gpt", dataset="lm_synth",
+                             n_devices=8, attention_impl="flash"))
+
     train, test = data
     eng = create_engine("fsdp", tiny_model(), mesh=mesh8, learning_rate=5e-3)
     tr = Trainer(None, engine=eng, seed=0)

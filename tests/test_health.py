@@ -37,11 +37,6 @@ from distributed_tensorflow_tpu.utils.failure import (  # noqa: E402
     AnomalyDetected, TrainingDiverged)
 from distributed_tensorflow_tpu.utils.metrics import MetricsLogger  # noqa: E402
 
-needs_shard_map = pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="shard_map engine layer needs a newer jax than this container")
-
-
 # ------------------------------------------------------------ capture units
 
 def test_global_norm_and_nonfinite_count():
@@ -330,7 +325,6 @@ def test_run_report_carries_health_section():
 
 # ---------------------------------------------- shard_map engine smoke
 
-@needs_shard_map
 def test_sync_engine_health_smoke(mesh8):
     """The shared base hook covers the real engine layer: one SyncEngine
     step on the 8-device mesh carries finite health stats."""

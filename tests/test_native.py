@@ -26,6 +26,32 @@ def test_build_is_cached():
     assert p1 == p2 and p1.exists()
 
 
+def test_build_is_keyed_on_source_content(tmp_path, monkeypatch):
+    """A source byte changes while the mtimes say nothing did (a copied
+    tree, a checkout): the library is rebuilt under a new name and the old
+    binary is gone — a stale one can never be loaded."""
+    import os
+    import shutil
+
+    src = tmp_path / "src"
+    shutil.copytree(native._SRC_DIR, src)
+    monkeypatch.setattr(native, "_SRC_DIR", src)
+    monkeypatch.setattr(native, "_BUILD_DIR", tmp_path / "_build")
+    first = native.build()
+    assert first is not None and first.exists()
+    assert native.build() == first                    # same content: reused
+
+    wire = src / "wire.cc"
+    stamp = wire.stat()
+    wire.write_bytes(wire.read_bytes() + b"// one more byte\n")
+    os.utime(wire, ns=(stamp.st_atime_ns, stamp.st_mtime_ns))
+    assert wire.stat().st_mtime_ns == stamp.st_mtime_ns
+    assert first.stat().st_mtime_ns >= stamp.st_mtime_ns  # "up to date"
+    second = native.build()
+    assert second is not None and second.exists() and second != first
+    assert not first.exists()
+
+
 # ------------------------------------------------------------------- wire
 def _blocking_socketpair():
     a, b = socket.socketpair()

@@ -4,10 +4,8 @@ plumbing.
 
 Layout mirrors tests/test_compression.py's shard_map split: the bucketer
 math (vmap axis emulation), the GSPMD engines (FSDP is pure jit), the
-probe accounting (host-level fakes) and the harness/report plumbing run
-on ANY jax; the sync-engine variants whose bucketed collectives need a
-real shard_map are ``needs_shard_map``-guarded like the rest of the
-engine layer.
+probe accounting (host-level fakes), the harness/report plumbing, and the
+sync-engine variants whose bucketed collectives ride a real shard_map.
 """
 
 import json
@@ -27,11 +25,6 @@ from distributed_tensorflow_tpu.engines.base import TrainState
 from distributed_tensorflow_tpu.engines.fsdp import FSDPEngine
 from distributed_tensorflow_tpu.models import create_model
 from distributed_tensorflow_tpu.parallel import compression, overlap
-
-needs_shard_map = pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="shard_map engine layer needs a newer jax than this container")
-
 
 def _leaves(seed=0):
     """A mixed tree: odd sizes (padding tails), a large splittable leaf,
@@ -450,7 +443,8 @@ def test_run_report_surfaces_overlap_split_and_environment():
     split = overlap.overlap_split(1.2, 1.0, 0.5)
     report = build_run_report({"steps": 2, "elapsed": 1.0,
                                "grad_bucket_mb": 4.0,
-                               "collective_overlap": split})
+                               "collective_overlap": split},
+                              devices=jax.devices())
     assert report["grad_bucket_mb"] == 4.0
     assert report["grad_collective_exposed_s"] == pytest.approx(0.2)
     assert report["grad_collective_hidden_s"] == pytest.approx(0.3)
@@ -458,7 +452,9 @@ def test_run_report_surfaces_overlap_split_and_environment():
         pytest.approx(1.5)
     env = report["environment"]
     assert env["jax_version"] == jax.__version__
-    assert env["device_kind"]
+    assert env["device_kind"] == jax.devices()[0].device_kind
+    assert env["platform"] == "cpu"
+    assert env["device_count"] == len(jax.devices())
     # overlap off: keys present but None — "off" ≠ "measured 0"
     off = build_run_report({"steps": 2, "elapsed": 1.0})
     assert off["grad_collective_exposed_s"] is None
@@ -527,24 +523,26 @@ def test_run_rejects_bad_bucket_config_without_mutating_env(monkeypatch):
 
 
 def test_runtime_environment_does_not_initialize_backend():
-    """report.runtime_environment() must be initialization-free: probing
-    device_kind via jax.local_devices() in an uninitialized process would
-    lock in the backend BEFORE enable_overlap_flags() could act, while
-    the section still showed the flags as effective — the exact
-    misattribution the environment section exists to prevent.  Probed in
-    a subprocess (this test process already has a backend)."""
+    """report.runtime_environment() must be initialization-free: a backend
+    brought up as a side effect would lock in LIBTPU_INIT_ARGS BEFORE
+    enable_overlap_flags() could act, while the section still showed the
+    flags as effective.  The device facts come only from the devices the
+    caller hands it (the run's mesh).  Probed in a subprocess (this test
+    process already has a backend)."""
     code = (
         "from distributed_tensorflow_tpu.observability.report import "
         "runtime_environment\n"
         "env = runtime_environment()\n"
         "assert env['jax_version'], env\n"
         "assert env['device_kind'] is None, env\n"
+        "assert env['platform'] is None, env\n"
         "from jax._src import xla_bridge\n"
         "assert not xla_bridge._backends, 'backend was initialized'\n"
         "import jax\n"
-        "jax.devices()\n"
-        "env2 = runtime_environment()\n"
-        "assert env2['device_kind'], env2\n")
+        "env2 = runtime_environment(jax.devices())\n"
+        "assert env2['device_kind'] == 'cpu', env2\n"
+        "assert env2['platform'] == 'cpu', env2\n"
+        "assert env2['device_count'] == len(jax.devices()), env2\n")
     proc = subprocess.run(
         [sys.executable, "-c", code], capture_output=True, text=True,
         env={**os.environ, "JAX_PLATFORMS": "cpu"}, timeout=120)
@@ -598,9 +596,8 @@ def test_analyze_diff_gates_exposed_seconds(tmp_path):
     assert load_report(p)["grad_collective_exposed_s"] == 0.10
 
 
-# ------------------------------ sync engine variants (need shard_map)
+# ------------------------------------ sync engine variants (shard_map)
 
-@needs_shard_map
 def test_sync_bucketed_none_matches_exact(mesh8):
     """The bucketed explicit-psum step reproduces the exact path's
     trajectory (per-bucket psums are the same elementwise sums)."""
@@ -617,7 +614,6 @@ def test_sync_bucketed_none_matches_exact(mesh8):
     np.testing.assert_allclose(le, lb, rtol=1e-5, atol=1e-6)
 
 
-@needs_shard_map
 def test_sync_overlap_accum_reduce_in_scan_close_to_exact(mesh8):
     """Overlap restructure (grad_accum with per-microbatch reduces inside
     the scan): Σᵢ psum(gᵢ) matches psum(Σᵢ gᵢ) within fp accumulation
@@ -635,7 +631,6 @@ def test_sync_overlap_accum_reduce_in_scan_close_to_exact(mesh8):
     np.testing.assert_allclose(le, lo, rtol=1e-4, atol=1e-5)
 
 
-@needs_shard_map
 def test_sync_probe_reports_real_split(mesh8):
     """The real probe on the sync engine: three programs compile, the
     split is internally consistent, and the caller's state survives."""

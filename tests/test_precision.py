@@ -1,10 +1,8 @@
 """End-to-end mixed precision (ISSUE 8): the --precision policy layer.
 
-Layout mirrors the suite's shard_map split (tests/test_compression.py):
-the policy/wrapper math, the GSPMD engines (FSDP is pure jit), the
-Trainer/report/harness plumbing and the checkpoint adoption path run on
-EVERY container; only the sync-engine variants (explicit shard_map
-collectives) are ``needs_shard_map``-guarded.
+Covers the policy/wrapper math, the GSPMD engines (FSDP is pure jit), the
+Trainer/report/harness plumbing, the checkpoint adoption path and the
+sync-engine variants (explicit shard_map collectives).
 
 The two acceptance claims pinned here:
 
@@ -36,11 +34,6 @@ from distributed_tensorflow_tpu.observability import (
     Tracer, build_run_report, health as hl)
 from distributed_tensorflow_tpu.parallel import precision as pl
 from distributed_tensorflow_tpu.utils.checkpoint import CheckpointManager
-
-needs_shard_map = pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="shard_map engine layer needs a newer jax than this container")
-
 
 def _tiny_ds(n=512, split="train"):
     x, y = synthetic_classification((8, 8), 4, n, seed=3, split=split)
@@ -280,7 +273,6 @@ def test_mnist_mlp_bf16_vs_f32_same_method_accuracy(mesh8):
     assert abs(accs["bf16-f32master"] - accs["f32"]) < 0.05
 
 
-@needs_shard_map
 def test_sync_mnist_mlp_bf16_policy_converges(mesh8):
     """The sync-engine rendering of the same-method claim (explicit
     shard_map collectives; the grad psum itself moves bf16)."""

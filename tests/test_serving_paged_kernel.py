@@ -7,6 +7,8 @@ mesh variant.  Everything runs in Pallas interpret mode on this
 container's CPU devices (the kernel's off-TPU default).
 """
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -28,18 +30,18 @@ def _case(seed, *, s=4, l_q=1, h=4, kvh=None, d=8, blk=4, mb=4,
     q = jnp.asarray(rng.standard_normal((s, l_q, h, d)), jnp.float32)
     if int8:
         k_pool = jnp.asarray(
-            rng.integers(-127, 128, (n, blk, kvh, d)), jnp.int8)
+            rng.integers(-127, 128, (n, kvh, blk, d)), jnp.int8)
         v_pool = jnp.asarray(
-            rng.integers(-127, 128, (n, blk, kvh, d)), jnp.int8)
+            rng.integers(-127, 128, (n, kvh, blk, d)), jnp.int8)
         k_scale = jnp.asarray(
-            rng.uniform(0.5, 1.5, (n, blk, kvh)) / 127.0, jnp.float32)
+            rng.uniform(0.5, 1.5, (n, kvh, blk)) / 127.0, jnp.float32)
         v_scale = jnp.asarray(
-            rng.uniform(0.5, 1.5, (n, blk, kvh)) / 127.0, jnp.float32)
+            rng.uniform(0.5, 1.5, (n, kvh, blk)) / 127.0, jnp.float32)
     else:
         k_pool = jnp.asarray(
-            rng.standard_normal((n, blk, kvh, d)), jnp.float32)
+            rng.standard_normal((n, kvh, blk, d)), jnp.float32)
         v_pool = jnp.asarray(
-            rng.standard_normal((n, blk, kvh, d)), jnp.float32)
+            rng.standard_normal((n, kvh, blk, d)), jnp.float32)
         k_scale = v_scale = None
     bt = jnp.asarray(
         rng.permutation(n)[:s * mb].reshape(s, mb), jnp.int32)
@@ -132,10 +134,14 @@ def test_kernel_masks_tail_and_unmapped_blocks():
     np.testing.assert_array_equal(base, out)
 
 
-def test_kernel_under_gspmd_mesh(mesh8):
+@pytest.mark.parametrize("pass_mesh", [False, True])
+def test_kernel_under_gspmd_mesh(mesh8, pass_mesh):
     """The serving layout under jit: queries/tables/positions sharded
     over slots on the 8-way data axis, pools replicated (any slot reads
-    any block) — the partitioned program still matches the oracle."""
+    any block) — the partitioned program still matches the oracle.  With
+    the mesh passed, the call runs under shard_map over 'data' (what the
+    paged cache does: Mosaic kernels cannot be GSPMD-partitioned on the
+    chip); without it, the CPU interpreter rides GSPMD."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from distributed_tensorflow_tpu.parallel import mesh as meshlib
@@ -149,7 +155,9 @@ def test_kernel_under_gspmd_mesh(mesh8):
                                                     None)))
     posd = jax.device_put(pos, row)
     kd, vd = jax.device_put(k, repl), jax.device_put(v, repl)
-    out = np.asarray(jax.jit(paged_attention)(qd, kd, vd, btd, posd))
+    fn = functools.partial(paged_attention,
+                           mesh=mesh8 if pass_mesh else None)
+    out = np.asarray(jax.jit(fn)(qd, kd, vd, btd, posd))
     ref = np.asarray(paged_attention_reference(q, k, v, bt, pos))
     np.testing.assert_allclose(out, ref, rtol=1e-5, atol=2e-5)
 

@@ -782,15 +782,12 @@ def test_bench_serve_smoke_int8_and_draft():
                BENCH_SERVE_KV_DTYPE="int8",
                BENCH_SERVE_DRAFT="self", BENCH_SERVE_DRAFT_K="2")
     proc = subprocess.run(
-        [sys.executable, str(repo / "bench.py"), "--serve", "--no-probe"],
+        [sys.executable, str(repo / "bench.py"), "--serve"],
         capture_output=True, text=True, timeout=540, env=env,
         cwd=str(repo))
     assert proc.returncode == 0, proc.stderr[-2000:]
     payload = json.loads(proc.stdout.strip().splitlines()[-1])
     assert payload["metric"] == "gpt_serve_requests_per_sec_per_chip"
-    if payload.get("skipped"):
-        assert payload["value"] is None and payload["error"]
-        return
     assert payload["serve_kv_dtype"] == "int8"
     assert payload["serve_kv_bytes_per_slot"] > 0
     assert payload["config"]["kv_dtype"] == "int8"

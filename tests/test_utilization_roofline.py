@@ -185,8 +185,10 @@ def test_peak_table_entries_and_revision():
     assert p.flops_per_s["f32"] == 197e12 / 2
     assert p.flops_per_s["int8"] == 2 * 197e12
     assert p.hbm_bytes_per_s == 819e9
-    # substring, first match wins: libtpu spells v5e "TPU v5 lite" too
+    # "TPU v5 lite" is what the chip tool's v5e reports (PR 21 chip run)
     assert device_peaks("TPU v5 lite").flops_per_s["bf16"] == 197e12
+    # no catch-all: an unlisted v5 string is unknown, not priced as a v5p
+    assert device_peaks("TPU v5") is None
     assert device_peaks("TPU v4").flops_per_s["bf16"] == 275e12
 
 
