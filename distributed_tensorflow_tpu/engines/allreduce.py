@@ -149,7 +149,9 @@ class Trainer:
         ``tracer``: an observability.Tracer — spans ``compile`` /
         ``chunk_dispatch`` / ``materialize`` / ``checkpoint`` / ``eval``
         plus prefetch queue-depth gauges at chunk boundaries; defaults to
-        the inert NULL_TRACER.  An async checkpoint manager
+        the process-wide ``recorder()`` (records in memory, no file; pass
+        NULL_TRACER to record nothing).  Only ``span``, ``event`` and
+        ``gauge`` are called on it.  An async checkpoint manager
         (utils/checkpoint.AsyncCheckpointManager) replaces the blocking
         ``checkpoint`` span with ``ckpt_snapshot`` (training-thread
         blocked time: previous-write backpressure + device snapshot) and
@@ -215,11 +217,11 @@ class Trainer:
         the same seed.
         """
         from distributed_tensorflow_tpu.observability import health as healthlib
-        from distributed_tensorflow_tpu.observability.trace import NULL_TRACER
+        from distributed_tensorflow_tpu.observability.trace import recorder
         from distributed_tensorflow_tpu.utils.failure import (
             AnomalyDetected, check_finite)
         if tracer is None:
-            tracer = NULL_TRACER
+            tracer = recorder()
         if on_anomaly not in ("warn", "halt"):
             raise ValueError(
                 f"on_anomaly must be 'warn' or 'halt', got '{on_anomaly}'")

@@ -11,9 +11,11 @@ fast path instead of disabling it:
              record is one queue put.
   trace    — structured span/event timeline (monotonic clock, run/host/
              process ids) shared with XProf via
-             ``jax.profiler.TraceAnnotation``, plus cheap in-memory span
-             aggregates for the run report even when no file sink is
-             configured.
+             ``jax.profiler.TraceAnnotation``; every span's record
+             (start, end, id, parent) is kept in a bounded ring in memory
+             and aggregated for the run report even when no file sink is
+             configured.  ``recorder()`` is the process-wide tracer the
+             serve loop and ``Trainer.fit`` use when none is passed.
   report   — the end-of-run structured summary: steady-state step-time
              percentiles split from compile, chunk shapes actually used,
              watchdog heartbeat/stall counts, prefetch starvation totals,
@@ -73,7 +75,7 @@ from distributed_tensorflow_tpu.observability.slo import SLOMonitor
 from distributed_tensorflow_tpu.observability.timeline import (
     GaugeSeries, Timeline, sparkline)
 from distributed_tensorflow_tpu.observability.trace import (
-    NULL_TRACER, Tracer)
+    NULL_TRACER, Tracer, recorder)
 from distributed_tensorflow_tpu.observability.xla_stats import (
     ProgramLedger, diff_manifests)
 
@@ -98,6 +100,7 @@ __all__ = [
     "device_peaks",
     "diff_manifests",
     "program_attribution",
+    "recorder",
     "runtime_environment",
     "serve_section",
     "sparkline",

@@ -65,7 +65,7 @@ import numpy as np
 from distributed_tensorflow_tpu.elastic.lease import LeaseManager
 from distributed_tensorflow_tpu.observability.metrics import (
     MetricsRegistry, exact_percentile)
-from distributed_tensorflow_tpu.observability.trace import NULL_TRACER
+from distributed_tensorflow_tpu.observability.trace import recorder
 from distributed_tensorflow_tpu.serving.kv_cache import SlotKVCache
 from distributed_tensorflow_tpu.serving.scheduler import (
     ContinuousBatcher, Request, RequestQueue, RequestResult, VirtualClock,
@@ -824,7 +824,7 @@ class ReplicaSet:
       Fleet elapsed time is then the max over lanes.
     """
 
-    def __init__(self, kvs: list[SlotKVCache], *, tracer=NULL_TRACER,
+    def __init__(self, kvs: list[SlotKVCache], *, tracer=None,
                  clock=None, threaded: bool | None = None,
                  prefill_chunk: int = 0, queue_cap: int = 0, slo=None,
                  draft_kvs: list[SlotKVCache] | None = None,
@@ -884,7 +884,9 @@ class ReplicaSet:
                     f"must fit in the {len(kvs)}-replica set")
         if handoff_s < 0:
             raise ValueError(f"handoff_s must be >= 0, got {handoff_s}")
-        self.tracer = tracer
+        # as in ContinuousBatcher: none passed = the process-wide recorder
+        self.tracer = tracer = (tracer if tracer is not None
+                                else recorder())
         base_clock = clock if clock is not None else WallClock()
         self.clock = _SharedClock(base_clock)
         if threaded is None:

@@ -69,6 +69,7 @@ from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from distributed_tensorflow_tpu.models.gpt import GPTLM
+from distributed_tensorflow_tpu.observability.trace import recorder
 from distributed_tensorflow_tpu.parallel import mesh as meshlib
 
 
@@ -242,6 +243,9 @@ class SlotKVCache:
         # scheduler reads deltas of this for the prefill/decode token
         # split and the VirtualClock interference model
         self.prefill_tokens_computed = 0
+        # scan steps the prefill programs ran for them: each call's
+        # bucket, pad steps included (the ``prefill`` span's padded_len)
+        self.prefill_tokens_padded = 0
 
         # host-observed seconds inside the compiled programs, per phase
         # (cumulative; the scheduler reads deltas per run) — the device
@@ -270,7 +274,10 @@ class SlotKVCache:
         (-1 = no EOS, 0 = unlimited budget — the draft table's mode);
         ``halted`` mirrors slots the device stopped advancing that the
         scheduler has not yet evicted (occupancy ``active`` is separate);
-        ``dispatch_count`` counts every compiled-program host call."""
+        ``dispatch_count`` counts every compiled-program host call;
+        ``tracer`` takes the ``program_build`` spans (the batcher that
+        drives this table hands it its own)."""
+        self.tracer = recorder()
         self.eos_tok = np.full(self.slots, -1, np.int32)
         self.budget = np.zeros(self.slots, np.int32)
         self.halted = np.zeros(self.slots, np.bool_)
@@ -339,15 +346,24 @@ class SlotKVCache:
         is fewer of exactly these).  With no ledger the builtin runs
         underneath, so the flag-off compiled-program set is byte-
         identical (the parity pin — the counting closure is host Python,
-        it compiles nothing)."""
+        it compiles nothing).  A program's first call traces, lowers and
+        compiles it (or loads it from the compile cache): that call runs
+        under a ``program_build`` span, which is how set-up time is split
+        by program."""
         if self._ledger is None:
             compiled = jax.jit(fn, **jit_kwargs)
         else:
             compiled = self._ledger.jit(fn, name=name, **jit_kwargs)
+        built = False
 
         def dispatch(*args, **kwargs):
+            nonlocal built
             self.dispatch_count += 1
-            return compiled(*args, **kwargs)
+            if built:
+                return compiled(*args, **kwargs)
+            built = True
+            with self.tracer.span("program_build", program=name):
+                return compiled(*args, **kwargs)
 
         return dispatch
 
@@ -694,11 +710,15 @@ class SlotKVCache:
         self.cache, first = fn(
             self.params, self.cache, jnp.int32(slot),
             self._put_repl(padded), jnp.int32(lp), self._next_rng())
+        # the host reads the token here, inside the timed region:
+        # prefill_s covers the program, not its enqueue
+        first = int(first)
         self._phase_s["prefill_s"] += time.perf_counter() - t0
         self.prefill_tokens_computed += lp
+        self.prefill_tokens_padded += lpad
         self.active[slot] = True
         self.lengths[slot] = lp
-        self.tokens[slot] = first = int(first)
+        self.tokens[slot] = first
         return slot, first
 
     # ------------------------------------------- chunked (resumable) prefill
@@ -752,16 +772,21 @@ class SlotKVCache:
             self.params, self.cache, jnp.int32(slot),
             self._put_repl(padded), jnp.int32(filled), jnp.int32(n),
             self._next_rng())
+        if final:
+            # materialize the token BEFORE flipping host state: a deferred
+            # device error surfaces here while the slot is still pending,
+            # so the caller's abort path sees a consistent table — and
+            # inside the timed region, so that the final chunk's prefill_s
+            # covers the program (a chunk that is not final is read by
+            # nobody and stays timed by its enqueue)
+            first = int(first)
         self._phase_s["prefill_s"] += time.perf_counter() - t0
         pend["filled"] = filled + n
         self.lengths[slot] = filled + n
         self.prefill_tokens_computed += n
+        self.prefill_tokens_padded += lpad
         if not final:
             return None
-        # materialize the token BEFORE flipping host state: a deferred
-        # device error surfaces here while the slot is still pending, so
-        # the caller's abort path sees a consistent table
-        first = int(first)
         del self._pending[slot]
         self.reserved[slot] = False
         self.active[slot] = True
@@ -1551,6 +1576,7 @@ class PagedSlotKVCache(SlotKVCache):
                              "tokens_reused": 0, "inserted_blocks": 0}
 
         self.prefill_tokens_computed = 0
+        self.prefill_tokens_padded = 0
         self._phase_s = {"prefill_s": 0.0, "decode_s": 0.0}
 
         self._step = self._build_step()
@@ -1773,13 +1799,15 @@ class PagedSlotKVCache(SlotKVCache):
             self.params, self.cache, bt_row,
             self._put_repl(padded), jnp.int32(filled), jnp.int32(n),
             self._next_rng())
+        if final:
+            first = int(first)   # inside the timed region, as above
         self._phase_s["prefill_s"] += time.perf_counter() - t0
         pend["filled"] = filled + n
         self.lengths[slot] = filled + n
         self.prefill_tokens_computed += n
+        self.prefill_tokens_padded += lpad
         if not final:
             return None
-        first = int(first)
         del self._pending[slot]
         self.reserved[slot] = False
         self.active[slot] = True

@@ -91,7 +91,7 @@ import numpy as np
 
 from distributed_tensorflow_tpu.observability.metrics import (
     MetricsRegistry, exact_percentile)
-from distributed_tensorflow_tpu.observability.trace import NULL_TRACER
+from distributed_tensorflow_tpu.observability.trace import recorder
 from distributed_tensorflow_tpu.serving.kv_cache import (
     SlotKVCache, SlotOverflow)
 
@@ -316,12 +316,11 @@ class _Live:
     """Host bookkeeping for one in-flight slot."""
 
     def __init__(self, req: Request, result: RequestResult,
-                 req_span, dec_span, last_t: float, req_attrs=None):
+                 req_span, dec_span, last_t: float):
         self.req = req
         self.result = result
-        self.req_span = req_span     # entered context managers, exited on
-        self.dec_span = dec_span     # finish (per-request span contract)
-        self.req_attrs = req_attrs if req_attrs is not None else {}
+        self.req_span = req_span     # detached spans (tracer.begin), ended
+        self.dec_span = dec_span     # on finish (per-request span contract)
         self.last_t = last_t
 
 
@@ -342,7 +341,7 @@ class ContinuousBatcher:
     counters so the comparison is apples-to-apples.
     """
 
-    def __init__(self, kv: SlotKVCache, *, tracer=NULL_TRACER,
+    def __init__(self, kv: SlotKVCache, *, tracer=None,
                  clock=None, mode: str = "continuous",
                  prefill_chunk: int = 0, metrics=None, slo=None,
                  queue_cap: int = 0, should_stop=None,
@@ -410,7 +409,14 @@ class ContinuousBatcher:
         self.draft_kv = draft_kv
         self.draft_k = int(draft_k)
         self.kv = kv
-        self.tracer = tracer
+        # no tracer passed = the process-wide recorder (records kept in
+        # memory, no file); NULL_TRACER is what a caller passes to have
+        # nothing recorded.  The tables build their programs under the
+        # same tracer (``program_build``).
+        self.tracer = tracer if tracer is not None else recorder()
+        kv.tracer = self.tracer
+        if draft_kv is not None:
+            draft_kv.tracer = self.tracer
         self.clock = clock if clock is not None else WallClock()
         self.mode = mode
         # per-iteration prompt-token budget (Sarathi-Serve chunked
@@ -484,9 +490,8 @@ class ContinuousBatcher:
         kv, tracer = self.kv, self.tracer
         lp = self._check_capacity(req)
         t_claim = self.clock.now()
-        req_span = tracer.span("request", rid=req.rid, prompt_len=lp,
-                               max_new_tokens=req.max_new_tokens)
-        req_attrs = req_span.__enter__() or {}
+        req_span = tracer.begin("request", rid=req.rid, prompt_len=lp,
+                                max_new_tokens=req.max_new_tokens)
         if req.handoff is not None:
             # disaggregated decode-side admission: the prompt KV arrives
             # serialized from a prefill replica — restore it instead of
@@ -505,8 +510,13 @@ class ContinuousBatcher:
             self._handoffs_in += 1
         else:
             before = kv.prefill_tokens_computed
-            with tracer.span("prefill", rid=req.rid, prompt_len=lp):
+            padded = kv.prefill_tokens_padded
+            # the span ends after the host holds the first token (insert
+            # returns it as an int): its duration is the prefill, not the
+            # enqueue — the stall and queue-wait metrics rest on that
+            with tracer.span("prefill", rid=req.rid, prompt_len=lp) as sp:
                 slot, first = kv.insert(req.prompt)
+                sp["padded_len"] = kv.prefill_tokens_padded - padded
             self.clock.on_prefill(kv.prefill_tokens_computed - before)
             if self._rf_cost is not None:
                 # credit only positions actually computed: a prefix-cache
@@ -526,7 +536,7 @@ class ContinuousBatcher:
             # replica — no local decode, no local token delivery (the
             # decode replica emits the payload's first token, so TTFT is
             # still charged arrival→first-token INCLUDING the handoff)
-            self._handoff(req, slot, req_span, req_attrs)
+            self._handoff(req, slot, req_span)
             return None
         now = self.clock.now()
         result = RequestResult(
@@ -534,9 +544,8 @@ class ContinuousBatcher:
             arrival_s=req.arrival_s, admitted_s=now, first_token_s=now,
             queue_wait_s=t_claim - req.arrival_s,
             prefill_s=now - t_claim)
-        dec_span = tracer.span("decode", rid=req.rid, slot=slot)
-        dec_span.__enter__()
-        live[slot] = _Live(req, result, req_span, dec_span, now, req_attrs)
+        dec_span = tracer.begin("decode", rid=req.rid, slot=slot)
+        live[slot] = _Live(req, result, req_span, dec_span, now)
         self._arm_multi(slot, live[slot])
         self._draft_admit(req.prompt, slot, first)
         if self._finished(live[slot]):
@@ -553,15 +562,13 @@ class ContinuousBatcher:
         kv, tracer = self.kv, self.tracer
         lp = self._check_capacity(req)
         t_claim = self.clock.now()
-        req_span = tracer.span("request", rid=req.rid, prompt_len=lp,
-                               max_new_tokens=req.max_new_tokens)
-        req_attrs = req_span.__enter__() or {}
+        req_span = tracer.begin("request", rid=req.rid, prompt_len=lp,
+                                max_new_tokens=req.max_new_tokens)
         slot, reused = kv.begin_insert(req.prompt)
         if hasattr(kv, "note_admission"):
             kv.note_admission(slot, lp + req.max_new_tokens)
         pending[slot] = {"req": req, "span": req_span, "lp": lp,
                          "admitted_s": t_claim, "reused": reused,
-                         "attrs": req_attrs,
                          "queue_wait_s": t_claim - req.arrival_s}
 
     def _promote(self, slot: int, pend: dict, first: int,
@@ -571,7 +578,7 @@ class ContinuousBatcher:
         the caller must not deliver the first token locally)."""
         req = pend["req"]
         if self.handoff_out is not None:
-            self._handoff(req, slot, pend["span"], pend["attrs"])
+            self._handoff(req, slot, pend["span"])
             return False
         now = self.clock.now()
         result = RequestResult(
@@ -580,10 +587,8 @@ class ContinuousBatcher:
             first_token_s=now,
             queue_wait_s=pend["queue_wait_s"],
             prefill_s=now - pend["admitted_s"])
-        dec_span = self.tracer.span("decode", rid=req.rid, slot=slot)
-        dec_span.__enter__()
-        live[slot] = _Live(req, result, pend["span"], dec_span, now,
-                           pend["attrs"])
+        dec_span = self.tracer.begin("decode", rid=req.rid, slot=slot)
+        live[slot] = _Live(req, result, pend["span"], dec_span, now)
         self._arm_multi(slot, live[slot])
         self._draft_admit(req.prompt, slot, first)
         if self._finished(live[slot]):
@@ -604,7 +609,7 @@ class ContinuousBatcher:
         remaining = lv.req.max_new_tokens - len(lv.result.tokens)
         self.kv.set_decode_limits(slot, lv.req.eos_id, max(remaining, 0))
 
-    def _handoff(self, req: Request, slot: int, span, attrs) -> None:
+    def _handoff(self, req: Request, slot: int, span) -> None:
         """Prefill-role completion: serialize the finished slot's KV
         (SlotKVCache.extract_handoff — the jitted block read programs +
         device_get), free the slot, and deliver (req, payload) to the
@@ -621,13 +626,13 @@ class ContinuousBatcher:
                 payload = kv.extract_handoff(slot)
         except BaseException:
             kv.evict(slot)
-            span.__exit__(None, None, None)
+            self.tracer.end(span)
             raise
         kv.evict(slot)
         self._handoffs_out += 1
-        attrs.update(handed_off=True,
-                     handoff_blocks=len(payload["blocks"]))
-        span.__exit__(None, None, None)
+        span.attrs.update(handed_off=True,
+                          handoff_blocks=len(payload["blocks"]))
+        self.tracer.end(span)
         self.handoff_out(req, payload)
 
     def _draft_admit(self, prompt, slot: int, first: int) -> None:
@@ -666,12 +671,12 @@ class ContinuousBatcher:
             reg.record("itl", gap)
         if self.slo is not None:
             r.slo_met = self.slo.observe(r.ttft_s, r.itl_s)
-        lv.req_attrs.update(
+        lv.req_span.attrs.update(
             queue_wait_s=r.queue_wait_s, prefill_s=r.prefill_s,
             decode_s=r.decode_s, ttft_s=r.ttft_s, tokens=len(r.tokens),
             **({} if r.slo_met is None else {"slo_met": r.slo_met}))
-        lv.dec_span.__exit__(None, None, None)
-        lv.req_span.__exit__(None, None, None)
+        self.tracer.end(lv.dec_span)
+        self.tracer.end(lv.req_span)
         self.kv.evict(slot)
         if self.draft_kv is not None and self.draft_kv.active[slot]:
             self.draft_kv.evict(slot)
@@ -716,15 +721,16 @@ class ContinuousBatcher:
         ~30 s — shorter than a sparse workload's gaps)."""
         clock = self.clock
         slice_s = getattr(clock, "poll_slice_s", float("inf"))
-        while True:
-            now = clock.now()
-            nxt = queue.next_arrival()
-            if nxt is None or now >= nxt:
-                return
-            if self._check_preempt(iters, queue):
-                return   # the loop top turns this into the drain/break
-            self.idle_polls += 1
-            clock.wait_until(min(nxt, now + slice_s))
+        with self.tracer.span("idle_wait"):
+            while True:
+                now = clock.now()
+                nxt = queue.next_arrival()
+                if nxt is None or now >= nxt:
+                    return
+                if self._check_preempt(iters, queue):
+                    return   # the loop top turns this into the drain/break
+                self.idle_polls += 1
+                clock.wait_until(min(nxt, now + slice_s))
 
     # ------------------------------------------------------------- the loop
     def _serve(self, queue: RequestQueue, live: dict[int, _Live],
@@ -1051,7 +1057,11 @@ class ContinuousBatcher:
                     for L in contexts)
                 self._rf_decode_bytes += \
                     self._rf_cost.decode_step_bytes(contexts)
-            with self.tracer.span("decode_step", active=len(live)):
+            # the span ends after the host holds the round's tokens
+            # (advance returns them through np.asarray): its duration is
+            # the decode step, and the gap to the next one is the stall
+            with self.tracer.span("decode_step", active=len(live),
+                                  slots=kv.slots):
                 toks = kv.advance()
             return {slot: [int(toks[slot])] for slot in live}
         return self._spec_round(live, k_eff)
@@ -1112,7 +1122,7 @@ class ContinuousBatcher:
                 for j in range(k_eff):
                     block[:, j + 1] = draft.advance()
                     self._draft_iterations += 1
-        with tracer.span("decode_step", active=len(live),
+        with tracer.span("decode_step", active=len(live), slots=kv.slots,
                          verify_width=k_eff + 1):
             g = kv.verify_block(block)
         emitted: dict[int, list[int]] = {}
@@ -1147,6 +1157,44 @@ class ContinuousBatcher:
                 if full[s]:
                     draft.tokens[s] = emitted[s][-1]
         return emitted
+
+    def _release_failed_window(self, live: dict[int, _Live],
+                               pending: dict[int, dict]) -> None:
+        """``_serve`` raised: leave the slot table as a later window needs
+        it, and end the in-flight requests' spans."""
+        # a torn fused round first: host mirrors lag the device while a
+        # round is in flight, and evict() below edits those mirrors —
+        # drop the outstanding handles (their tokens are lost with the
+        # window) before touching slots
+        self.kv.abandon_multi()
+        if self.draft_kv is not None:
+            self.draft_kv.abandon_multi()
+        # a failed window must not poison the slot table — bench windows
+        # share ONE SlotKVCache, and a leaked active slot shrinks every
+        # later window's capacity (zero free slots + zero live = a
+        # busy-spin).  Free the in-flight slots (decoding AND
+        # mid-prefill) and end their spans so the records written so far
+        # survive into the partial-results artifact.
+        for slot in sorted(live):
+            lv = live.pop(slot)
+            self.tracer.end(lv.dec_span)
+            self.tracer.end(lv.req_span)
+            self.kv.evict(slot)
+            if (self.draft_kv is not None
+                    and self.draft_kv.active[slot]):
+                self.draft_kv.evict(slot)
+        for slot in sorted(pending):
+            pend = pending.pop(slot)
+            self.tracer.end(pend["span"])
+            # a failure between the FINAL chunk and promotion leaves the
+            # slot pending HERE but already active in the kv (its kv-side
+            # pending entry is gone) — release whichever state it
+            # reached; aborting an activated slot would raise over the
+            # original error
+            if self.kv.has_pending(slot):
+                self.kv.abort_insert(slot)
+            elif self.kv.active[slot]:
+                self.kv.evict(slot)
 
     def run(self, requests: Iterable[Request] | RequestQueue,
             on_token: Callable[[int, int], None] | None = None,
@@ -1204,45 +1252,16 @@ class ContinuousBatcher:
             self.clock.start()
             t_start = self.clock.now()
             wall0 = time.perf_counter()
-            try:
-                decode_iterations, prefills, chunks = self._serve(
-                    queue, live, pending, on_token)
-            except BaseException:
-                # a torn fused round first: host mirrors lag the device
-                # while a round is in flight, and evict() below edits
-                # those mirrors — drop the outstanding handles (their
-                # tokens are lost with the window) before touching slots
-                self.kv.abandon_multi()
-                if self.draft_kv is not None:
-                    self.draft_kv.abandon_multi()
-                # a failed window must not poison the slot table — bench
-                # windows share ONE SlotKVCache, and a leaked active slot
-                # shrinks every later window's capacity (zero free slots
-                # + zero live = a busy-spin).  Free the in-flight slots
-                # (decoding AND mid-prefill) and close their spans so the
-                # records written so far survive into the partial-results
-                # artifact.
-                for slot in sorted(live):
-                    lv = live.pop(slot)
-                    lv.dec_span.__exit__(None, None, None)
-                    lv.req_span.__exit__(None, None, None)
-                    self.kv.evict(slot)
-                    if (self.draft_kv is not None
-                            and self.draft_kv.active[slot]):
-                        self.draft_kv.evict(slot)
-                for slot in sorted(pending):
-                    pend = pending.pop(slot)
-                    pend["span"].__exit__(None, None, None)
-                    # a failure between the FINAL chunk and promotion
-                    # leaves the slot pending HERE but already active in
-                    # the kv (its kv-side pending entry is gone) —
-                    # release whichever state it reached; aborting an
-                    # activated slot would raise over the original error
-                    if self.kv.has_pending(slot):
-                        self.kv.abort_insert(slot)
-                    elif self.kv.active[slot]:
-                        self.kv.evict(slot)
-                raise
+            # the window's root: a reader takes the records inside the
+            # last of these and so leaves warm-up windows out
+            with self.tracer.span("serve_run", offered=offered,
+                                  slots=self.kv.slots, mode=self.mode):
+                try:
+                    decode_iterations, prefills, chunks = self._serve(
+                        queue, live, pending, on_token)
+                except BaseException:
+                    self._release_failed_window(live, pending)
+                    raise
             wall_elapsed = time.perf_counter() - wall0
             elapsed = self.clock.now() - t_start
         results = sorted(self._results, key=lambda r: r.rid)

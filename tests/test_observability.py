@@ -8,6 +8,7 @@ wiring that emits the report through the CLI with telemetry enabled at
 ``steps_per_call > 1``.
 """
 
+import contextlib
 import json
 import os
 import signal
@@ -144,13 +145,178 @@ def test_tracer_aggregates_without_file_sink():
 
 
 def test_null_tracer_is_inert():
-    with NULL_TRACER.span("anything", x=1):
-        pass
+    with NULL_TRACER.span("anything", x=1) as attrs:
+        attrs["late"] = 2           # the dict is there to write to
     NULL_TRACER.gauge("g", 1)
     NULL_TRACER.event("e")
     NULL_TRACER.counter("c")
+    handle = NULL_TRACER.begin("request", rid=1)
+    handle.attrs.update(tokens=3)
+    NULL_TRACER.end(handle)
+    assert NULL_TRACER.records() == [] == NULL_TRACER.records(root="x")
     assert NULL_TRACER.span_summary() == {}
     assert not NULL_TRACER.enabled
+
+
+class _FakeAnnotation:
+    """Stands where ``jax.profiler.TraceAnnotation`` is: keeps what the
+    tracer mirrors into a profile."""
+
+    def __init__(self, seen):
+        self.seen = seen
+
+    def __call__(self, name, **kwargs):
+        self.seen.append((name, kwargs))
+        return contextlib.nullcontext()
+
+
+def test_span_records_carry_identity_and_nesting(tmp_path):
+    """One record per finished span: both ends on one clock, an id, the
+    enclosing lexical span as parent, the rid, the attributes as they were
+    at exit.  A detached span is recorded like any other, is nobody's
+    parent and is not mirrored into the profile."""
+    tracer = Tracer(path=tmp_path / "t.jsonl")
+    seen = []
+    tracer._annotation = _FakeAnnotation(seen)
+    t_before = time.perf_counter()
+    with tracer.span("serve_run", offered=2):
+        req = tracer.begin("request", rid=7, prompt_len=5)
+        with tracer.span("prefill", rid=7, prompt_len=5) as attrs:
+            attrs["padded_len"] = 8
+        with tracer.span("decode_step", active=1, slots=4):
+            with tracer.span("inner"):
+                time.sleep(0.002)
+        req.attrs.update(tokens=3)
+        tracer.end(req)
+    t_after = time.perf_counter()
+    tracer.close()
+    recs = {r["name"]: r for r in tracer.records()}
+    assert list(recs) == ["prefill", "inner", "decode_step", "request",
+                          "serve_run"]                  # in finishing order
+    assert set(recs["prefill"]) == {"name", "start", "end", "id", "parent",
+                                    "rid", "attrs"}
+    ids = [r["id"] for r in recs.values()]
+    assert len(set(ids)) == len(ids)
+    root = recs["serve_run"]
+    assert root["parent"] is None and root["rid"] is None
+    assert t_before <= root["start"] <= root["end"] <= t_after
+    for name in ("prefill", "decode_step", "request"):
+        assert recs[name]["parent"] == root["id"]
+        assert root["start"] <= recs[name]["start"] <= recs[name]["end"] \
+            <= root["end"]
+    # opened while `request` was open, yet the detached span is not its
+    # parent: the lexical root is
+    assert recs["inner"]["parent"] == recs["decode_step"]["id"]
+    assert recs["inner"]["end"] - recs["inner"]["start"] >= 0.002
+    assert recs["prefill"]["rid"] == 7 == recs["request"]["rid"]
+    assert recs["prefill"]["attrs"]["padded_len"] == 8
+    assert recs["request"]["attrs"] == {"rid": 7, "prompt_len": 5,
+                                        "tokens": 3}
+    # the profile gets the lexical spans under their names, with the id
+    assert seen == [(name, {"id": recs[name]["id"]}) for name in
+                    ("serve_run", "prefill", "decode_step", "inner")]
+    # the file carries the same identity
+    lines = [json.loads(line) for line in
+             (tmp_path / "t.jsonl").read_text().splitlines()]
+    spans = {rec["name"]: rec for rec in lines if rec["event"] == "span"}
+    assert spans["inner"]["parent"] == recs["decode_step"]["id"]
+    assert spans["prefill"]["t"] == recs["prefill"]["start"]
+    assert spans["prefill"]["dur_s"] == pytest.approx(
+        recs["prefill"]["end"] - recs["prefill"]["start"])
+    assert tracer.stats()["records"] == 5
+    assert tracer.stats()["records_dropped"] == 0
+
+
+def test_record_ring_is_bounded_and_counts_what_it_drops(monkeypatch):
+    from distributed_tensorflow_tpu.observability import trace
+
+    monkeypatch.setattr(trace, "RING_CAPACITY", 8)
+    tracer = Tracer()
+    for i in range(11):
+        with tracer.span("step", i=i):
+            pass
+    recs = tracer.records()
+    assert [r["attrs"]["i"] for r in recs] == list(range(3, 11))
+    assert tracer.dropped == 3
+    assert tracer.span_summary()["step"]["count"] == 11   # aggregates stay
+
+
+def test_records_of_a_root_are_the_last_roots_interval_only():
+    tracer = Tracer()
+    assert tracer.records(root="serve_run") == []
+    with tracer.span("warmup"):
+        pass
+    for window in range(2):
+        with tracer.span("serve_run", window=window):
+            with tracer.span("decode_step", window=window):
+                pass
+            open_across = tracer.begin("request", window=window)
+        tracer.end(open_across)      # ends after the root: not inside it
+    got = tracer.records(root="serve_run")
+    assert [r["name"] for r in got] == ["serve_run", "decode_step"]
+    assert all(r["attrs"]["window"] == 1 for r in got)
+    assert tracer.records(root="no_such_span") == []
+    assert len(tracer.records()) == 7
+
+
+@pytest.mark.parametrize("threads", [1, 4])
+def test_records_under_a_shared_tracer(threads):
+    """The fleet shares ONE tracer across its replica threads: no record
+    and no count is lost, ids stay unique, and a span's parent is the span
+    open on ITS thread."""
+    import sys
+    import threading
+
+    tracer = Tracer()
+    rounds = 300
+    errors = []
+
+    def work(k):
+        try:
+            for i in range(rounds):
+                with tracer.span("outer", thread=k, i=i):
+                    handle = tracer.begin("life", thread=k)
+                    with tracer.span("inner", thread=k, i=i):
+                        pass
+                    tracer.end(handle)
+        except BaseException as e:      # surfaced below, in the test's thread
+            errors.append(e)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        pool = [threading.Thread(target=work, args=(k,))
+                for k in range(threads)]
+        for t in pool:
+            t.start()
+        for t in pool:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in pool)
+    finally:
+        sys.setswitchinterval(old)
+    assert not errors
+    recs = tracer.records()
+    assert len(recs) == 3 * rounds * threads and tracer.dropped == 0
+    assert len({r["id"] for r in recs}) == len(recs)
+    summary = tracer.span_summary()
+    assert all(summary[n]["count"] == rounds * threads
+               for n in ("outer", "inner", "life"))
+    outer = {r["id"]: r for r in recs if r["name"] == "outer"}
+    for r in recs:
+        if r["name"] == "outer":
+            assert r["parent"] is None
+        else:
+            assert outer[r["parent"]]["attrs"]["thread"] \
+                == r["attrs"]["thread"]
+    assert tracer.overhead_s > 0
+
+
+def test_recorder_is_one_tracer_per_process():
+    from distributed_tensorflow_tpu.observability import recorder
+
+    tracer = recorder()
+    assert tracer is recorder() and tracer.enabled
+    assert tracer.stats().get("written") is None      # no file behind it
 
 
 # round 20 fast-lane repair: xprof-window e2e rides the slow lane

@@ -399,6 +399,155 @@ def test_scheduler_emits_request_spans(model_params, tmp_path):
     assert spans["decode_step"]["count"] >= 1
 
 
+# ------------------------------------------- the serve loop's own records
+
+
+def _record_requests():
+    # rid 2 falls due long after the first two are done: an idle wait
+    arrivals = [0.0, 1.0, 40.0]
+    return [Request(rid=10 + i, prompt=p, max_new_tokens=4,
+                    arrival_s=arrivals[i])
+            for i, p in enumerate(_prompts(3, seed=11, lo=3, hi=14))]
+
+
+@pytest.fixture(scope="module")
+def recorded_window(model_params):
+    """One small window with NO tracer passed: what it leaves in the
+    process-wide recorder, read when the run has ended."""
+    from distributed_tensorflow_tpu.observability import recorder
+
+    model, params = model_params
+    kv = SlotKVCache(model, params, slots=2)
+    summary = ContinuousBatcher(kv, clock=VirtualClock()).run(
+        _record_requests())
+    return {"summary": summary, "kv": kv,
+            "records": recorder().records(root="serve_run")}
+
+
+def test_default_run_records_one_root_and_one_request_each(recorded_window):
+    recs, summary = recorded_window["records"], recorded_window["summary"]
+    root = recs[0]
+    assert root["name"] == "serve_run" and root["parent"] is None
+    assert root["attrs"] == {"offered": 3, "slots": 2, "mode": "continuous"}
+    assert sum(r["name"] == "serve_run" for r in recs) == 1
+    requests = {r["rid"]: r for r in recs if r["name"] == "request"}
+    assert sorted(requests) == [10, 11, 12]
+    for res in summary["results"]:
+        attrs = requests[res.rid]["attrs"]
+        assert attrs["prompt_len"] == res.prompt_len
+        assert attrs["max_new_tokens"] == 4 and attrs["tokens"] == 4
+        assert attrs["queue_wait_s"] == res.queue_wait_s
+        assert attrs["ttft_s"] == res.ttft_s
+        assert {"prefill_s", "decode_s"} <= set(attrs)
+        # detached: recorded under the root, nobody's parent
+        assert requests[res.rid]["parent"] == root["id"]
+    decodes = [r for r in recs if r["name"] == "decode"]
+    assert sorted(r["rid"] for r in decodes) == [10, 11, 12]
+    detached = {r["id"] for r in recs if r["name"] in ("request", "decode")}
+    assert not detached & {r["parent"] for r in recs}
+    # every record lies inside the root's interval
+    assert all(root["start"] <= r["start"] <= r["end"] <= root["end"]
+               for r in recs)
+
+
+def test_default_run_records_every_prefill_with_its_bucket(recorded_window):
+    recs, kv = recorded_window["records"], recorded_window["kv"]
+    prefills = [r for r in recs if r["name"] == "prefill"]
+    assert sorted(r["rid"] for r in prefills) == [10, 11, 12]
+    # the cache counts the scan steps its programs ran; the scheduler
+    # does not recompute the bucket rule
+    assert sum(r["attrs"]["padded_len"] for r in prefills) \
+        == kv.prefill_tokens_padded
+    assert sum(r["attrs"]["prompt_len"] for r in prefills) \
+        == kv.prefill_tokens_computed
+    for r in prefills:
+        lp, lpad = r["attrs"]["prompt_len"], r["attrs"]["padded_len"]
+        assert lpad >= lp and lpad in (8, 16)
+    # each program's first call is a program_build under the span that
+    # made the call
+    builds = {r["attrs"]["program"]: r for r in recs
+              if r["name"] == "program_build"}
+    assert "kv_decode_step" in builds
+    assert {f"kv_prefill_l{r['attrs']['padded_len']}" for r in prefills} \
+        <= set(builds)
+    by_id = {r["id"]: r for r in recs}
+    assert by_id[builds["kv_decode_step"]["parent"]]["name"] == "decode_step"
+
+
+def test_default_run_records_every_decode_round_and_idle_wait(
+        recorded_window):
+    recs, summary = recorded_window["records"], recorded_window["summary"]
+    rounds = [r for r in recs if r["name"] == "decode_step"]
+    assert len(rounds) == summary["decode_iterations"] > 0
+    assert all(r["attrs"]["slots"] == 2 and 1 <= r["attrs"]["active"] <= 2
+               for r in rounds)
+    assert any(r["attrs"]["active"] == 2 for r in rounds)
+    assert all(r["parent"] == recs[0]["id"] for r in rounds)
+    waits = [r for r in recs if r["name"] == "idle_wait"]
+    assert len(waits) >= 1 and summary["idle_polls"] >= 1
+
+
+def test_null_tracer_records_nothing_and_serves_the_same_tokens(
+        model_params, recorded_window):
+    from distributed_tensorflow_tpu.observability import (
+        NULL_TRACER, recorder)
+
+    model, params = model_params
+    kv = SlotKVCache(model, params, slots=2)
+    last = recorder().records()[-1]["id"]
+    summary = ContinuousBatcher(kv, tracer=NULL_TRACER,
+                                clock=VirtualClock()).run(_record_requests())
+    assert recorder().records()[-1]["id"] == last     # not one record more
+    assert kv.tracer is NULL_TRACER
+    assert [r.tokens for r in summary["results"]] \
+        == [r.tokens for r in recorded_window["summary"]["results"]]
+    assert summary["decode_iterations"] \
+        == recorded_window["summary"]["decode_iterations"]
+
+
+class _SlowToken:
+    """A first token that takes 50 ms to reach the host."""
+
+    def __init__(self, token):
+        self.token = token
+
+    def __int__(self):
+        import time
+
+        time.sleep(0.05)
+        return int(self.token)
+
+
+@pytest.mark.parametrize("path", ["insert", "final_chunk"])
+def test_prefill_s_covers_the_first_tokens_materialisation(model_params,
+                                                           path):
+    """``phase_times()["prefill_s"]`` times the prefill, not its enqueue:
+    the clock stops after the host holds the first token."""
+    model, params = model_params
+    kv = SlotKVCache(model, params, slots=1)
+    prompt = _prompts(1, seed=12)[0]
+
+    def admit():
+        if path == "insert":
+            return kv.insert(prompt)
+        slot, _ = kv.begin_insert(prompt)
+        return slot, kv.prefill_chunk(slot)
+
+    slot, first = admit()            # builds the real program
+    kv.evict(slot)
+    programs = kv._prefills if path == "insert" else kv._chunks
+    (lpad, real), = programs.items()
+
+    def slow(*args):
+        cache, token = real(*args)
+        return cache, _SlowToken(token)
+
+    programs[lpad] = slow
+    before = kv.phase_times()["prefill_s"]
+    assert admit() == (slot, first)
+    assert kv.phase_times()["prefill_s"] - before >= 0.05
+
+
 # ---------------------------------------- chunked prefill + prefix caching
 
 
