@@ -41,7 +41,9 @@ import functools
 from typing import Any
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
+from jax import lax
 
 from distributed_tensorflow_tpu.parallel import collectives as coll
 from distributed_tensorflow_tpu.parallel import compression
@@ -56,6 +58,36 @@ def _part(init, spec, enabled: bool):
     models/bert.py:48-52: unannotated modules keep plain initializers so
     non-GSPMD engines see ordinary unboxed params)."""
     return nn.with_partitioning(init, spec) if enabled else init
+
+
+def write_slot_rows(table, update, pos):
+    """``table[b, pos[b, j]] = update[b, j]`` for every slot row ``b`` and
+    block position ``j``: how the monolithic slot table takes a step's new
+    K/V rows (and, under int8 storage, their scales).
+
+    ``table`` is ``(slots, max_len, ...)``, ``update`` ``(slots, L, ...)``
+    in the table's dtype, ``pos`` ``(slots, L)``.  One scatter in the form
+    ``jax.vmap`` gives a per-slot ``lax.dynamic_update_slice``: the slot
+    axis is a batching dimension and the update window is one whole
+    position in the table's own shape, nothing collapsed.  The TPU
+    compiler turns that form into a loop of in-place
+    ``dynamic-update-slice`` in whatever layout the table has; the
+    two-index ``.at[rows, pos].set`` it served by re-laying the whole
+    table out and back (on the v5e two copies of every leaf a step, 3.2x
+    padded: tests/test_tpu_compile.py holds that they stay away).
+
+    DROP RULE: a position outside ``[0, max_len)`` leaves the table as it
+    was — pad rows of a chunk bucket that run past the table, and a freed
+    slot frozen at ``max_len``, rely on it.  It is the scatter's own
+    out-of-bounds rule (``FILL_OR_DROP``; ``dynamic_update_slice`` itself
+    would clamp and overwrite position ``max_len - 1``)."""
+    n = table.ndim
+    dnums = lax.ScatterDimensionNumbers(
+        update_window_dims=tuple(range(2, n + 1)), inserted_window_dims=(),
+        scatter_dims_to_operand_dims=(1,), operand_batching_dims=(0,),
+        scatter_indices_batching_dims=(0,))
+    return lax.scatter(table, pos[..., None], update[:, :, None], dnums,
+                       mode=lax.GatherScatterMode.FILL_OR_DROP)
 
 
 def apply_rope(x, pos, base: float = 10000.0):
@@ -98,9 +130,10 @@ class CausalSelfAttention(nn.Module):
     decode_slots: bool = False   # serving mode: the batch dim is a SLOT
                                # table (serving/kv_cache.py) — the caller
                                # passes per-slot write positions, cache
-                               # writes are per-row scatters, and validity
-                               # is length-driven, so one compiled decode
-                               # step advances slots of any age
+                               # writes go row by row (write_slot_rows),
+                               # and validity is length-driven, so one
+                               # compiled decode step advances slots of
+                               # any age
     kv_quant: bool = False     # int8 KV storage (decode_slots only): K/V
                                # cached as int8 with one f32 max-abs scale
                                # per written vector (slot × position ×
@@ -186,17 +219,18 @@ class CausalSelfAttention(nn.Module):
                     "kv_quant=True is a slot-table storage mode: it "
                     "requires decode_slots=True (the serving engine owns "
                     "the quantized table)")
-            import jax
-
             b = x.shape[0]
             if self.decode_slots:
                 # SLOT decode (serving/kv_cache.py): each batch row is an
                 # independent slot with its own age.  The write index is
                 # the caller-supplied per-slot position (= the slot's
-                # current length), the write a per-row scatter, and the
-                # validity mask length-driven — so the SAME compiled step
-                # advances a slot mid-prefill-history and a slot hundreds
-                # of tokens deep at once.  No cursor/overflow variables:
+                # current length), the write ``write_slot_rows`` (each
+                # slot's row updated in place, in the table's own layout;
+                # a position at or past max_len is DROPPED there, not
+                # clamped), and the validity mask length-driven — so the
+                # SAME compiled step advances a slot mid-prefill-history
+                # and a slot hundreds of tokens deep at once.  No
+                # cursor/overflow variables:
                 # positions are external state owned by the serving
                 # engine, which guards capacity at admission time
                 # (prompt + max_new_tokens ≤ max_len — the host-side
@@ -211,7 +245,7 @@ class CausalSelfAttention(nn.Module):
                 # monolithic admission (tests/test_serving.py).
                 # TOKEN-BLOCK CONTRACT (speculative verify): the same
                 # mode also accepts a (B, L) block of L consecutive
-                # tokens per slot — all L K/V vectors scatter into the
+                # tokens per slot — all L K/V vectors are written into the
                 # cache first, then each query attends under a PER-QUERY
                 # validity mask (positions ≤ its own), so position j's
                 # logits condition on exactly the block prefix 0..j plus
@@ -223,12 +257,13 @@ class CausalSelfAttention(nn.Module):
                 # serving/kv_cache.py advance_multi): a lax.scan drives
                 # this same step k times with token feedback on device,
                 # freezing each slot's position once it deactivates
-                # (EOS/budget) — a deactivated row keeps scattering its
+                # (EOS/budget) — a deactivated row keeps writing its
                 # stale token at the SAME frozen position every
                 # remaining iteration.  That rewrite is safe by the two
-                # properties already stated above: the scatter is
+                # properties already stated above: the write is
                 # per-(row, position) so it only ever touches the one
-                # cell past the frozen length, and validity is derived
+                # cell past the frozen length (none at all once that is
+                # max_len: the drop rule), and validity is derived
                 # from the caller's length vector alone, so the junk
                 # cell is invisible to attention until a real token
                 # advances the length and overwrites it first.  No
@@ -289,16 +324,15 @@ class CausalSelfAttention(nn.Module):
                                           causal=True)
                 elif x.shape[1] == 1 and not self.kv_quant:
                     idx = pos[:, 0]
-                    rows = jnp.arange(b)
                     # cast to the table's dtype: the serving engine may
                     # store the KV table narrower than the compute dtype
                     # (SlotKVCache kv_dtype — bf16 halves KV memory); a
                     # same-dtype astype is the identity, so the default
                     # program is untouched
-                    ck.value = ck.value.at[rows, idx].set(
-                        k[:, 0].astype(ck.value.dtype))
-                    cv.value = cv.value.at[rows, idx].set(
-                        v[:, 0].astype(cv.value.dtype))
+                    ck.value = write_slot_rows(
+                        ck.value, k.astype(ck.value.dtype), pos)
+                    cv.value = write_slot_rows(
+                        cv.value, v.astype(cv.value.dtype), pos)
                     valid = (jnp.arange(self.max_len)[None, :]
                              <= idx[:, None]).astype(self.dtype)
                     out = dense_attention(
@@ -306,28 +340,28 @@ class CausalSelfAttention(nn.Module):
                         causal=False, kv_mask=valid)
                 else:
                     # token-block write (speculative verify) and/or int8
-                    # storage: scatter every position's K/V (+ scale),
+                    # storage: write every position's K/V (+ scale) with
+                    # the same ``write_slot_rows`` as the branch above,
                     # then attend each query against the table under its
                     # own position mask — the L == 1 case of this path is
-                    # the same math as the branch above
+                    # the same math as that branch
                     idx = pos                       # (B, L)
-                    rows = jnp.arange(b)[:, None]
                     if self.kv_quant:
                         qk, sk = compression.int8_channel_encode(k)
                         qv, sv = compression.int8_channel_encode(v)
-                        ck.value = ck.value.at[rows, idx].set(qk)
-                        cv.value = cv.value.at[rows, idx].set(qv)
-                        ks.value = ks.value.at[rows, idx].set(sk)
-                        vs.value = vs.value.at[rows, idx].set(sv)
+                        ck.value = write_slot_rows(ck.value, qk, idx)
+                        cv.value = write_slot_rows(cv.value, qv, idx)
+                        ks.value = write_slot_rows(ks.value, sk, idx)
+                        vs.value = write_slot_rows(vs.value, sv, idx)
                         keys = compression.int8_channel_decode(
                             ck.value, ks.value, self.dtype)
                         vals = compression.int8_channel_decode(
                             cv.value, vs.value, self.dtype)
                     else:
-                        ck.value = ck.value.at[rows, idx].set(
-                            k.astype(ck.value.dtype))
-                        cv.value = cv.value.at[rows, idx].set(
-                            v.astype(cv.value.dtype))
+                        ck.value = write_slot_rows(
+                            ck.value, k.astype(ck.value.dtype), idx)
+                        cv.value = write_slot_rows(
+                            cv.value, v.astype(cv.value.dtype), idx)
                         keys, vals = ck.value, cv.value
                     valid = (jnp.arange(self.max_len)[None, None, :]
                              <= idx[:, :, None]).astype(self.dtype)
@@ -439,11 +473,11 @@ class CausalSelfAttention(nn.Module):
                 "serving engine passes each slot's block table")
         idx = pos                                    # (B, L)
         # positions past max_len (pad rows of a chunk-scan bucket) must
-        # DROP like the monolithic scatter does — but gather CLAMPS, so
-        # an unclamped table lookup would alias the slot's own last
-        # block.  Route oob positions to an oob OFFSET instead: the
-        # block-id gather is clamped harmlessly and the scatter's
-        # default drop rule discards the write.
+        # DROP like the monolithic write does (write_slot_rows' drop
+        # rule) — but gather CLAMPS, so an unclamped table lookup would
+        # alias the slot's own last block.  Route oob positions to an
+        # oob OFFSET instead: the block-id gather is clamped harmlessly
+        # and the scatter's default drop rule discards the write.
         j = idx // blk
         oob = j >= block_tables.shape[1]
         blk_ids = jnp.take_along_axis(

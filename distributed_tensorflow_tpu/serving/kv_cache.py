@@ -187,7 +187,7 @@ class SlotKVCache:
         if kv_dtype is not None and not self.quantized:
             # --serve-kv-dtype bfloat16: store the K/V table narrower than
             # the model computes (bf16 halves KV memory → double the slots
-            # per chip).  The model's slot-scatter writes cast to the
+            # per chip).  The model's slot writes cast to the
             # table's dtype (models/gpt.py) and the attention read
             # promotes back, so the decode program stays the one compiled
             # step.  (int8 needs no cast here — the kv_quant model
@@ -379,13 +379,17 @@ class SlotKVCache:
         dm = self.dm
 
         def step(params, cache, tokens, lengths, active, rng):
-            # write index = current length; inactive (free) slots scatter
-            # garbage into their own rows only, which the next insert's
-            # prefill overwrites — validity is length-driven, so stale
-            # positions are never attended.  The advanced token AND
-            # length vectors are program outputs so the next iteration
-            # can consume them on device (`_dev_learn`) instead of
-            # re-uploading host mirrors.
+            # write index = current length, written by models/gpt.py
+            # ``write_slot_rows``: each slot's row in place in the donated
+            # table (no copy of a table leaf: tests/test_tpu_compile.py).
+            # Inactive (free) slots write garbage into their own rows
+            # only, which the next insert's prefill overwrites — validity
+            # is length-driven, so stale positions are never attended —
+            # and a slot freed at length max_len writes nothing: the
+            # helper DROPS a position past the table.  The advanced token
+            # AND length vectors are program outputs so the next
+            # iteration can consume them on device (`_dev_learn`) instead
+            # of re-uploading host mirrors.
             logits, upd = dm.apply(
                 {"params": params, "cache": cache}, tokens[:, None],
                 train=False, positions=lengths[:, None], mutable=["cache"])
@@ -404,7 +408,7 @@ class SlotKVCache:
         generated token from the logits at the last REAL prompt position.
         Steps past ``prompt_len`` write garbage K/V beyond the slot's
         length — invisible under the length mask and overwritten as
-        decoding advances (the same argument that makes free-slot scatter
+        decoding advances (the same argument that makes free-slot
         writes safe).  The decode step is untouched: admission never
         recompiles it."""
         dm = self.dm
@@ -445,8 +449,11 @@ class SlotKVCache:
         first generated token, exactly as in the monolithic prefill.
         Padding past ``n_valid`` writes garbage K/V that the next chunk
         (which starts at ``start+n_valid``) or decode overwrites, and
-        out-of-range scatter rows are dropped — the same argument that
-        makes monolithic pad writes safe."""
+        pad rows whose position runs past ``max_len`` are dropped — the
+        drop rule lives in models/gpt.py ``write_slot_rows`` (the
+        scatter's own out-of-bounds rule; tests/test_serving.py holds it
+        against a clamping write, which would overwrite the real token at
+        ``max_len - 1``)."""
         dm = self.dm
 
         def chunk(params, cache, slot, tokens, start, n_valid, rng):
@@ -478,7 +485,7 @@ class SlotKVCache:
         """Compiled speculative-verify step for one (slots, width) token
         block: per slot, ``width`` consecutive tokens (the committed
         pending token + width-1 draft proposals) enter at positions
-        ``length .. length+width-1``; every position's K/V scatters into
+        ``length .. length+width-1``; every position's K/V is written into
         the cache and every position's logits take their greedy argmax in
         ONE batched slot-decode-style program (the models/gpt.py
         token-block contract — each query masked to positions ≤ its own).

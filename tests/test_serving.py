@@ -1107,6 +1107,72 @@ def test_kv_dtype_surfaces_in_serve_summary(model_params):
     assert summary32["serve_kv_dtype"] == "float32"
 
 
+def _two_index_scatter(table, update, pos):
+    """The slot table's write before ``write_slot_rows``: the oracle."""
+    rows = jnp.arange(table.shape[0])[:, None]
+    return table.at[rows, pos].set(update)
+
+
+@pytest.mark.parametrize("past_end", [False, True],
+                         ids=["in_range", "past_max_len"])
+@pytest.mark.parametrize("width", [1, 3], ids=["one_token", "token_block"])
+@pytest.mark.parametrize("kv_dtype", ["float32", "bfloat16", "int8"])
+def test_slot_table_write_matches_two_index_scatter(monkeypatch, kv_dtype,
+                                                    width, past_end):
+    """``models/gpt.write_slot_rows`` through both monolithic branches of
+    the slot-decode attention (one token; token block, which int8 always
+    takes) leaves the table bitwise as the old ``.at[rows, pos].set`` did,
+    and keeps its DROP RULE: a position at or past ``max_len`` changes
+    nothing — position ``max_len - 1``, where ``dynamic_update_slice``
+    would clamp to, keeps the real token it held."""
+    from distributed_tensorflow_tpu.models import gpt as gpt_mod
+
+    slots, max_len = 4, 32
+    dm = tiny_gpt(max_len=max_len).clone(
+        decode=True, decode_slots=True, attention_impl="dense",
+        kv_quant=kv_dtype == "int8")
+    dummy = jnp.zeros((slots, 1), jnp.int32)
+    variables = dm.init(jax.random.key(0), dummy, train=False,
+                        positions=dummy)
+    rng = np.random.default_rng(26)
+
+    def filled(leaf):       # every cell holds a "real token" beforehand
+        if leaf.dtype == jnp.int8:
+            return jnp.asarray(rng.integers(-127, 128, leaf.shape), jnp.int8)
+        dtype = leaf.dtype if kv_dtype == "int8" else jnp.dtype(kv_dtype)
+        return jnp.asarray(rng.normal(size=leaf.shape), dtype)
+
+    before = jax.tree.map(filled, variables["cache"])
+    start = (np.array([max_len - 1, max_len, max_len + 5, 7]) if past_end
+             else np.array([0, 5, max_len - width, 17]))
+    pos = jnp.asarray(start[:, None] + np.arange(width)[None, :], jnp.int32)
+    toks = jnp.asarray(rng.integers(0, 64, (slots, width)), jnp.int32)
+
+    def run():
+        _, upd = dm.apply({"params": variables["params"], "cache": before},
+                          toks, train=False, positions=pos,
+                          mutable=["cache"])
+        return upd["cache"]
+
+    got = run()
+    monkeypatch.setattr(gpt_mod, "write_slot_rows", _two_index_scatter)
+    want = run()
+    changed = 0
+    for b, g, w in zip(*map(jax.tree.leaves, (before, got, want))):
+        assert g.dtype == b.dtype and g.shape == b.shape
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+        b, g = np.asarray(b), np.asarray(g)
+        for s in range(slots):
+            live = [int(p) for p in np.asarray(pos[s]) if p < max_len]
+            rest = np.setdiff1d(np.arange(max_len), live)
+            np.testing.assert_array_equal(g[s, rest], b[s, rest])
+            changed += int((g[s, live] != b[s, live]).any())
+    # the in-range rows did take their tokens (the check above is not
+    # vacuous), and with past_end two slots were dropped whole
+    leaves = len(jax.tree.leaves(before))
+    assert changed == leaves * (2 if past_end else slots)
+
+
 @pytest.mark.slow    # round 20 fast-lane repair: kv-dtype threading
 # is covered fast by the library suites; the e2e representative is
 # test_harness_serve_e2e_fsdp
