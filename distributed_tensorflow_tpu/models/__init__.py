@@ -93,9 +93,16 @@ def create_model(name: str, num_classes: int = 10, **kw) -> nn.Module:
         # through the same parameter every classifier uses
         kw.setdefault("vocab_size", num_classes)
         return GPTLM(**kw)
+    if name == "mla_moe":
+        from distributed_tensorflow_tpu.models.mla_moe import LatentMoELM
+
+        if "param_dtype" in kw:
+            kw["param_dtype"] = resolve_dtype(kw["param_dtype"])
+        kw.setdefault("vocab_size", num_classes)
+        return LatentMoELM(**kw)
     if name not in _REGISTRY:
         raise KeyError(f"unknown model '{name}'; known: {sorted(_REGISTRY)} "
-                       f"+ resnet20, bert_tiny, moe, gpt")
+                       f"+ resnet20, bert_tiny, moe, gpt, mla_moe")
     return _REGISTRY[name](num_classes=num_classes, **kw)
 
 

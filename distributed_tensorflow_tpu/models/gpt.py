@@ -670,6 +670,17 @@ class GPTLM(nn.Module):
                                  # 'data' axis shards the slots)
 
     causal_lm = True  # read by engines/harness to select the LM data layout
+    prefill_form = "scan"   # SlotKVCache prefills by a lax.scan of the
+                            # one-token slot-decode step
+
+    def slot_decode_clone(self, *, partition_model: bool = False,
+                          kv_quant: bool = False, **paged) -> "GPTLM":
+        """The module ``serving/kv_cache.SlotKVCache`` serves from: dense
+        cache attention over a slot table, dropout off."""
+        return self.clone(decode=True, decode_slots=True,
+                          attention_impl="dense",
+                          partition_model=partition_model, dropout_rate=0.0,
+                          kv_quant=kv_quant, **paged)
 
     @nn.compact
     def __call__(self, token_ids, train: bool = False, positions=None,

@@ -1,11 +1,14 @@
-"""Mixture-of-Experts classifier with Switch-style top-1 routing.
+"""Mixture-of-Experts layers: a capacity-limited Switch/GShard layer and a
+dropless top-k layer.
 
 No reference counterpart (SURVEY.md §2.2: "EP (expert parallel): NO — no MoE
 anywhere"); this is TPU-native new capability completing the parallelism
 matrix (dp/tp/pp/sp/ep).
 
-TPU-first design — the GShard/Switch dense-dispatch formulation, which is
-what XLA partitions well:
+``MoELayer`` is the OLD layer, the GShard/Switch dense-dispatch
+formulation that GSPMD partitions well for training under
+``engines/expert_parallel.py``: top-1 or top-2 only, softmax gates, ReLU
+experts, and a CAPACITY per expert past which tokens are dropped.
 
 * Expert FFN weights are *stacked* with a leading expert dimension and
   annotated ``with_partitioning`` on the ``expert`` mesh axis — each device
@@ -21,6 +24,13 @@ what XLA partitions well:
 The Switch load-balancing auxiliary loss is sown into the
 ``intermediates`` collection as ``aux_loss``; the expert-parallel engine
 adds ``aux_weight ×`` it to the task loss.
+
+``DroplessMoE`` is the layer of today's sparse decoders
+(models/mla_moe.py): any k, sigmoid scores with a choice bias, SwiGLU
+experts, shared experts, NO capacity and no ``[tokens, E, capacity]``
+tensor — the (token, choice) pairs are sorted by expert and go through one
+grouped matrix product (``lax.ragged_dot``), so cost is linear in tokens
+and no token is ever dropped.  It is told which experts it holds.
 """
 
 from __future__ import annotations
@@ -33,7 +43,10 @@ from distributed_tensorflow_tpu.parallel import mesh as meshlib
 
 
 class MoELayer(nn.Module):
-    """Top-k (k ∈ {1, 2}) routed expert FFN over tokens (leading axis of x).
+    """Capacity-limited Switch/GShard layer: top-k (k ∈ {1, 2}) routed
+    expert FFN over tokens (leading axis of x); over capacity a token is
+    DROPPED.  ``DroplessMoE`` below is the layer for more than two experts
+    per token, sigmoid scores, shared experts or no drops.
 
     ``router_top_k=1`` is Switch routing; ``2`` is GShard-style top-2 with
     renormalized gates and priority positions (top-1 assignments claim
@@ -78,7 +91,9 @@ class MoELayer(nn.Module):
     def __call__(self, x):
         if self.router_top_k not in (1, 2):
             raise ValueError(
-                f"router_top_k must be 1 or 2, got {self.router_top_k}")
+                f"router_top_k must be 1 or 2, got {self.router_top_k}: "
+                f"MoELayer is the capacity-limited Switch/GShard layer; "
+                f"DroplessMoE routes any k without drops")
         tokens, d = x.shape
         e = self.num_experts
         gs = self.group_size
@@ -176,6 +191,117 @@ class MoELayer(nn.Module):
         y = jnp.einsum("gsec,gecd->gsd", combine.astype(self.dtype),
                        expert_out)
         return y.reshape(tokens, d)
+
+
+class DroplessMoE(nn.Module):
+    """Dropless top-k routed SwiGLU experts with shared experts, over
+    tokens (leading axis of x).
+
+    ``s = sigmoid(x W_g)`` in float32 over all ``num_experts``; the
+    ``top_k`` experts of a token are the largest of ``s + b`` (``b`` the
+    choice bias: it moves the choice and never the weight); their weights
+    are ``s`` without ``b``, divided by their sum when ``norm_topk`` and
+    times ``routed_scale``.  ``y = sum_i w_i E_i(x) + S(x)``: each ``E_i``
+    a SwiGLU of width ``hidden``, ``S`` one SwiGLU of width
+    ``shared_hidden`` (0 = none).
+
+    ``held = (first, count)`` names the experts whose weights live here
+    (None = all).  The router keeps its full width; a (token, choice)
+    pair whose expert is not held contributes nothing, so the layer
+    returns its own experts' part of the result plus the shared expert —
+    what one chip of an expert-parallel deployment computes before the
+    exchange (tests/test_mla_moe.py adds the shares up).
+
+    Dispatch: the ``tokens * top_k`` pairs are sorted by expert (pairs
+    that are not held, or whose token is not ``valid``, sort last and lie
+    past the last group), the tokens gathered in that order, and the three
+    expert matrices applied as grouped products over the group sizes.  No
+    capacity: every pair is computed.  Each token's expert choice is sown
+    as ``expert_choice`` into ``intermediates`` (the serving engine counts
+    routing load from it)."""
+
+    num_experts: int = 8
+    top_k: int = 2
+    hidden: int = 256
+    shared_hidden: int = 0
+    routed_scale: float = 1.0
+    norm_topk: bool = True
+    held: tuple[int, int] | None = None
+    dtype: jnp.dtype = jnp.float32
+    param_dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x, valid=None):
+        t, d = x.shape
+        e, k = self.num_experts, self.top_k
+        first, n = self.held if self.held is not None else (0, e)
+        if not (0 <= first and n >= 1 and first + n <= e and 1 <= k <= e):
+            raise ValueError(
+                f"held={self.held} / top_k={k} do not fit {e} experts")
+        init = nn.initializers.lecun_normal()
+
+        # --- router (f32, true f32 products: a choice is a comparison) ---
+        w_router = self.param("router", init, (d, e), self.param_dtype)
+        bias = self.param("choice_bias", nn.initializers.zeros_init(), (e,),
+                          jnp.float32)
+        scores = jax.nn.sigmoid(jnp.dot(
+            x.astype(jnp.float32), w_router.astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST))
+        _, choice = jax.lax.top_k(scores + bias, k)             # [T, k]
+        weight = jnp.take_along_axis(scores, choice, axis=-1)
+        if self.norm_topk:
+            weight = weight / (weight.sum(-1, keepdims=True) + 1e-20)
+        weight = weight * self.routed_scale
+        self.sow("intermediates", "expert_choice", choice)
+
+        # --- sort the pairs by held expert; the rest sort last -----------
+        local = choice - first
+        here = (local >= 0) & (local < n)
+        if valid is not None:
+            here = here & valid[:, None]
+        local = jnp.where(here, local, n).reshape(-1)           # [T*k]
+        order = jnp.argsort(local, stable=True)
+        sizes = jnp.bincount(local, length=n + 1)[:n].astype(jnp.int32)
+        xs = x.astype(self.dtype)[order // k]                   # [T*k, d]
+
+        def experts(name, shape):
+            return self.param(name, init, (n,) + shape,
+                              self.param_dtype).astype(self.dtype)
+
+        gate = jax.lax.ragged_dot(xs, experts("w_gate", (d, self.hidden)),
+                                  sizes)
+        up = jax.lax.ragged_dot(xs, experts("w_up", (d, self.hidden)), sizes)
+        ys = jax.lax.ragged_dot(jax.nn.silu(gate) * up,
+                                experts("w_down", (self.hidden, d)), sizes)
+        # rows past the last group belong to no expert here: whatever the
+        # grouped product left there is not a result
+        ys = jnp.where((jnp.arange(t * k) < sizes.sum())[:, None], ys, 0)
+        ys = ys[jnp.argsort(order)].reshape(t, k, d)            # unsort
+        y = jnp.einsum("tkd,tk->td", ys.astype(jnp.float32),
+                       jnp.where(here, weight, 0.0))
+        y = y.astype(self.dtype)
+        if self.shared_hidden:
+            y = y + SwiGLU(self.shared_hidden, self.dtype, self.param_dtype,
+                           name="shared")(x)
+        return y
+
+
+class SwiGLU(nn.Module):
+    """``W_down(silu(W_gate x) * (W_up x))``, no biases."""
+
+    hidden: int
+    dtype: jnp.dtype = jnp.float32
+    param_dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        def dense(width, name):
+            return nn.Dense(width, use_bias=False, dtype=self.dtype,
+                            param_dtype=self.param_dtype, name=name)
+
+        h = jax.nn.silu(dense(self.hidden, "gate")(x)) \
+            * dense(self.hidden, "up")(x)
+        return dense(x.shape[-1], "down")(h)
 
 
 _MOE_GROUP_TARGET = 1024  # ~GShard group size: big enough that per-group

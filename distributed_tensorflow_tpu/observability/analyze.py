@@ -349,14 +349,23 @@ def serve_waterfall(records: list[dict]) -> dict[str, Any]:
     number in time order, retried rows carry the hop's
     ``original_arrival_s`` (retry TTFT is charged from the ORIGINAL
     arrival — the row is keyed to it, not to the requeue time), and the
-    hops ride the output as ``requeues``."""
+    hops ride the output as ``requeues``.
+
+    ``windows`` holds one entry per ``serve_run`` span with the table's
+    counters over that window (``cache_bytes_per_token``,
+    ``expert_assignments``)."""
     rows: list[dict[str, Any]] = []
+    windows: list[dict[str, Any]] = []
     chunk_recs: list[dict[str, Any]] = []
     shed: list[dict[str, Any]] = []
     requeues: list[dict[str, Any]] = []
     for rec in records:
         kind = rec.get("event")
         rid = rec.get("rid")
+        if kind == "span" and rec.get("name") == "serve_run":
+            windows.append({k: rec.get(k) for k in (
+                "t", "dur_s", "offered", "slots", "cache_bytes_per_token",
+                "expert_assignments")})
         if rid is None:
             continue
         if kind == "event" and rec.get("name") == "requeue":
@@ -439,6 +448,7 @@ def serve_waterfall(records: list[dict]) -> dict[str, Any]:
     met = [r["slo_met"] for r in rows if r.get("slo_met") is not None]
     return {
         "requests": rows,
+        "windows": windows,
         "shed": shed,
         "requeues": requeues,
         "requests_n": len(rows),
@@ -510,6 +520,12 @@ def render_waterfall_text(wf: dict[str, Any], width: int = 60) -> str:
         out.append(f"{str(s['rid']):>6} |{' ' * off}x"
                    f"{'':<{max(width + 3 - off, 0)}}"
                    f"| shed (429) at depth {s.get('queue_depth')}")
+    for w in wf.get("windows", ()):
+        if w.get("cache_bytes_per_token") is not None:
+            out.append(f"window of {w.get('offered')} offered on "
+                       f"{w.get('slots')} slots: table "
+                       f"{w['cache_bytes_per_token']} bytes a token, "
+                       f"{w.get('expert_assignments')} expert assignments")
     out.append(f"legend: .=queue =prefill #=decode x=shed >=requeue; "
                f"{wf['requests_n']} served, {wf['shed_n']} shed, "
                f"{wf.get('requeue_n', 0)} requeued")

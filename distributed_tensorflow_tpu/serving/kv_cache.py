@@ -12,18 +12,23 @@ decode program never recompiles.
 
 Device-side contract (everything else lives in serving/scheduler.py):
 
-* the cache is a pytree of ``(slots, max_len, kv_heads, head_dim)`` leaves
-  (models/gpt.py slot-decode mode — deliberately no scalar cursors, so
-  every leaf shards the slot dim over the mesh's ``data`` axis and, for
-  tensor-parallel models, the kv-head dim over ``model``;
-  parallel/mesh.py ``kv_slot_sharding``);
+* the cache is a pytree of ``(slots, max_len, ...)`` leaves, whatever the
+  model's own slot-decode mode keeps a token: per-head keys and values
+  ``(slots, max_len, kv_heads, head_dim)`` for models/gpt.py, a latent and
+  one rotated key head ``(slots, max_len, rank)`` for models/mla_moe.py.
+  Deliberately no scalar cursors, so every leaf shards the slot dim over
+  the mesh's ``data`` axis and, for tensor-parallel models, the kv-head
+  dim over ``model`` (parallel/mesh.py ``kv_slot_sharding``);
 * ``advance`` is the one jitted decode step: (tokens, lengths, active)
   vectors in, next tokens out, cache donated through;
-* ``insert`` is a jitted prefill that feeds a new request's prompt through
-  the SAME per-token decode math inside a ``lax.scan`` over the padded
-  prompt, against only that slot's cache slice (batch 1), then writes the
-  slice back — compiled once per padded length bucket (powers of two), so
-  steady-state admission never triggers XLA;
+* ``insert`` is a jitted prefill against only that slot's cache slice
+  (batch 1), written back once — compiled once per padded length bucket
+  (powers of two), so steady-state admission never triggers XLA.  Its
+  form is the model class's (``prefill_form``): ``"scan"`` feeds the
+  prompt through the SAME per-token decode math inside a ``lax.scan``
+  over the padded prompt (models/gpt.py); ``"batched"`` is ONE call of
+  the model over the whole padded prompt (models/mla_moe.py, whose
+  one-token step would read every expert a prompt token);
 * ``begin_insert``/``prefill_chunk`` split that admission into fixed
   token-budget chunks (Sarathi-Serve, arXiv:2403.02310): each chunk resumes
   at the slot's fill position (the chunk program takes a traced ``start``,
@@ -68,7 +73,8 @@ import numpy as np
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from distributed_tensorflow_tpu.models.gpt import GPTLM
+import flax.linen as nn
+
 from distributed_tensorflow_tpu.observability.trace import recorder
 from distributed_tensorflow_tpu.parallel import mesh as meshlib
 
@@ -101,13 +107,19 @@ class BlockPoolExhausted(RuntimeError):
 
 
 class SlotKVCache:
-    """Fixed slot table + compiled prefill/decode programs for one GPTLM.
+    """Fixed slot table + compiled prefill/decode programs for one model
+    with a slot-decode mode (``models/gpt.GPTLM``,
+    ``models/mla_moe.LatentMoELM``).
 
-    ``model`` is the TRAINING-mode module (any attention impl); it is
-    cloned into slot-decode mode exactly like ``generate`` clones into
-    cursor-decode mode — dense cache attention, dropout off, Megatron TP
-    layout kept when ``mesh`` has a 'model' axis and the model was
-    partitioned.  ``params`` may be a TP engine's committed TrainState
+    ``model`` is the TRAINING-mode module (any attention impl); its
+    ``slot_decode_clone`` gives the module served from — for ``GPTLM``
+    exactly like ``generate`` clones into cursor-decode mode: dense cache
+    attention, dropout off, Megatron TP layout kept when ``mesh`` has a
+    'model' axis and the model was partitioned.  The table's leaves come
+    from that module's own abstract init.  What is built for the scan
+    prefill only — the paged layout, chunk resume, the prefix pool, int8
+    storage, multi-step dispatch, speculative verify, KV handoff — raises
+    ``NotImplementedError`` by name for a model whose prefill is batched.  ``params`` may be a TP engine's committed TrainState
     params (used in place) or host/single-device params (replicated).
 
     Host-side bookkeeping (`lengths`, `active`, `tokens`) lives on numpy:
@@ -125,7 +137,7 @@ class SlotKVCache:
             return super().__new__(PagedSlotKVCache)
         return super().__new__(cls)
 
-    def __init__(self, model: GPTLM, params, slots: int, *,
+    def __init__(self, model: nn.Module, params, slots: int, *,
                  mesh=None, greedy: bool = True, temperature: float = 1.0,
                  prefill_bucket: int = 8, rng=None, kv_dtype=None,
                  prefix_cache_blocks: int = 0, prefix_block: int = 16,
@@ -169,12 +181,14 @@ class SlotKVCache:
         if kv_dtype is not None:
             kv_dtype = jnp.dtype(kv_dtype)
             self.quantized = kv_dtype == jnp.dtype(jnp.int8)
-        keep_tp = (mesh is not None and model.partition_model
+        keep_tp = (mesh is not None
+                   and getattr(model, "partition_model", False)
                    and meshlib.MODEL_AXIS in mesh.axis_names)
-        self.dm = model.clone(decode=True, decode_slots=True,
-                              attention_impl="dense",
-                              partition_model=keep_tp, dropout_rate=0.0,
-                              kv_quant=self.quantized)
+        self.prefill_form = model.prefill_form
+        if prefix_cache_blocks:
+            self._scan_model_only("the prefix pool")
+        self.dm = model.slot_decode_clone(partition_model=keep_tp,
+                                          kv_quant=self.quantized)
         self._rng = rng if rng is not None else jax.random.key(0)
 
         # zero slot cache from an abstract init — zeros-from-shape IS the
@@ -278,6 +292,11 @@ class SlotKVCache:
         ``tracer`` takes the ``program_build`` spans (the batcher that
         drives this table hands it its own)."""
         self.tracer = recorder()
+        # a model with routed experts: the decode step also returns the
+        # round's routing load (advance -> last_routing)
+        self.expert_layers = int(getattr(self.dm, "expert_layers", 0))
+        self.last_routing: dict[str, float] | None = None
+        self.expert_assignments = 0     # (token, expert) pairs, cumulative
         self.eos_tok = np.full(self.slots, -1, np.int32)
         self.budget = np.zeros(self.slots, np.int32)
         self.halted = np.zeros(self.slots, np.bool_)
@@ -288,6 +307,15 @@ class SlotKVCache:
         self._multi_snap = None     # host view at the last dispatch
         self._multi_pending: list[dict] = []    # in-flight rounds (FIFO)
         self._inflight = np.zeros(self.slots, np.int32)
+
+    def _scan_model_only(self, feature: str) -> None:
+        """What rests on the one-token scan prefill and per-head K/V
+        leaves is refused by name for a model whose prefill is batched."""
+        if self.prefill_form != "scan":
+            raise NotImplementedError(
+                f"{feature} is not implemented for a model with a "
+                f"{self.prefill_form} prefill (models/mla_moe.py): the "
+                f"monolithic table with insert/advance/evict is")
 
     def _place_params(self, params):
         """Param placement rule (shared by __init__ and ``swap_params``):
@@ -397,6 +425,27 @@ class SlotKVCache:
             return (upd["cache"], jnp.where(active, nxt, tokens),
                     jnp.where(active, lengths + 1, lengths))
 
+        def routed_step(params, cache, tokens, lengths, active, rng):
+            """The step above for a model with routed experts, which also
+            returns ``[experts touched, summed over expert layers; largest
+            count any expert received]`` over the ACTIVE slots' choices:
+            two integers beside the tokens."""
+            logits, upd = dm.apply(
+                {"params": params, "cache": cache}, tokens[:, None],
+                train=False, positions=lengths[:, None],
+                mutable=["cache", "intermediates"])
+            nxt = self._sample(logits[:, -1], rng).astype(tokens.dtype)
+            choice = jnp.stack(jax.tree.leaves(upd["intermediates"]))
+            counts = jnp.sum(
+                jax.nn.one_hot(choice, dm.num_experts, dtype=jnp.int32)
+                * active[None, :, None, None].astype(jnp.int32), axis=(1, 2))
+            routing = jnp.stack([jnp.sum(counts > 0), jnp.max(counts)])
+            return (upd["cache"], jnp.where(active, nxt, tokens),
+                    jnp.where(active, lengths + 1, lengths), routing)
+
+        if self.expert_layers:
+            return self._jit(routed_step, "kv_decode_step_routed",
+                             donate_argnums=1)
         return self._jit(step, "kv_decode_step", donate_argnums=1)
 
     def _prefill(self, lpad: int):
@@ -412,6 +461,28 @@ class SlotKVCache:
         writes safe).  The decode step is untouched: admission never
         recompiles it."""
         dm = self.dm
+
+        def batched(params, cache, slot, tokens, prompt_len, rng):
+            """``prefill_form == "batched"``: ONE call of the model over
+            the padded prompt from position 0 (it attends within the block
+            and writes the slot's sub-table in one piece); logits come
+            back for the last real position only."""
+            sub = jax.tree.map(
+                lambda t: lax.dynamic_slice_in_dim(t, slot, 1, 0), cache)
+            logits, upd = dm.apply(
+                {"params": params, "cache": sub}, tokens[None, :],
+                train=False,
+                positions=jnp.arange(lpad, dtype=jnp.int32)[None, :],
+                prompt_len=prompt_len[None], mutable=["cache"])
+            first = self._sample(logits[:, -1], rng)[0]
+            cache = jax.tree.map(
+                lambda full, s: lax.dynamic_update_slice_in_dim(
+                    full, s, slot, 0), cache, upd["cache"])
+            return cache, first.astype(tokens.dtype)
+
+        if self.prefill_form == "batched":
+            return self._jit(batched, f"kv_prefill_batched_l{lpad}",
+                             donate_argnums=1)
 
         def prefill(params, cache, slot, tokens, prompt_len, rng):
             sub = jax.tree.map(
@@ -723,10 +794,16 @@ class SlotKVCache:
         self._phase_s["prefill_s"] += time.perf_counter() - t0
         self.prefill_tokens_computed += lp
         self.prefill_tokens_padded += lpad
+        self._count_assignments(lp)
         self.active[slot] = True
         self.lengths[slot] = lp
         self.tokens[slot] = first
         return slot, first
+
+    def _count_assignments(self, tokens: int) -> None:
+        if self.expert_layers:
+            self.expert_assignments += (
+                tokens * self.expert_layers * self.dm.experts_per_token)
 
     # ------------------------------------------- chunked (resumable) prefill
     def begin_insert(self, prompt,
@@ -740,6 +817,7 @@ class SlotKVCache:
         and ``reused_tokens`` positions are skipped — prefill resumes at
         the first uncached block.  At least the prompt's final token is
         always computed (its logits sample the first generated token)."""
+        self._scan_model_only("chunked (resumable) prefill")
         prompt, lp, slot = self._claim_slot(prompt, slot)
         reused = self._restore_prefix(prompt, lp, slot)
         self.reserved[slot] = True
@@ -870,6 +948,7 @@ class SlotKVCache:
         greedy continuation on the decode replica is bitwise what the
         prefill replica would have produced.  The slot stays active:
         the caller evicts after a successful transfer."""
+        self._scan_model_only("KV handoff")
         if not self.active[slot]:
             raise RuntimeError(f"slot {slot} is not active")
         if self._handoff_read is None:
@@ -895,6 +974,7 @@ class SlotKVCache:
         without running any program.  The slot comes up active at the
         transferred length and the next ``advance`` continues the
         sequence bitwise (same storage dtype both sides)."""
+        self._scan_model_only("KV handoff")
         length = self._check_handoff_payload(payload, self._handoff_block())
         slot = self._claim_restore_slot(length, slot)
         if self._handoff_write is None:
@@ -1030,12 +1110,18 @@ class SlotKVCache:
                 "a fused multi-step round is in flight — drain it before "
                 "a single-step advance (host mirrors lag the device)")
         t0 = time.perf_counter()
-        self.cache, d_nxt, d_len = self._step(
+        self.cache, d_nxt, d_len, *routing = self._step(
             self.params, self.cache,
             self._dev_cached("tokens", self.tokens),
             self._dev_cached("lengths", self.lengths),
             self._dev_cached("mask", mask), self._next_rng())
         nxt = np.asarray(d_nxt)
+        if routing:
+            touched, load_max = (int(v) for v in np.asarray(routing[0]))
+            self.last_routing = {
+                "experts_touched": touched / self.expert_layers,
+                "expert_load_max": load_max}
+            self._count_assignments(int(mask.sum()))
         self._phase_s["decode_s"] += time.perf_counter() - t0
         self.lengths[mask] += 1
         self.tokens = nxt.astype(np.int32)
@@ -1112,6 +1198,7 @@ class SlotKVCache:
         Slots the device already deactivated (``halted``) are excluded
         from the host mask; fresh host-side edits ride as ``edited``-
         selected uploads."""
+        self._scan_model_only("multi-step decode")
         if k < 1:
             raise ValueError(f"multi-step k must be >= 1, got {k}")
         if k not in self._multis:
@@ -1250,6 +1337,7 @@ class SlotKVCache:
         (lengths/tokens) is NOT touched here: the scheduler owns
         acceptance, and rejected positions are rolled back by length
         bookkeeping alone (no KV rewrite)."""
+        self._scan_model_only("speculative verify")
         if not self.greedy:
             raise ValueError(
                 "verify_block requires greedy sampling: the exact "
@@ -1335,6 +1423,18 @@ class SlotKVCache:
         observed: each program's result is materialized before the next
         scheduling decision, so dispatch + device wait both land here."""
         return dict(self._phase_s)
+
+    def counters(self) -> dict[str, int]:
+        """Cumulative counts beside ``phase_times``: prompt tokens fed
+        through a prefill program and the positions those programs ran
+        (pads included), the (token, expert) routing assignments made
+        (0 for a model without experts), and what one token of one slot
+        keeps in the table, all layers together."""
+        return {"prefill_tokens_computed": self.prefill_tokens_computed,
+                "prefill_tokens_padded": self.prefill_tokens_padded,
+                "expert_assignments": self.expert_assignments,
+                "cache_bytes_per_token":
+                    self.kv_bytes_per_slot() // self.max_len}
 
     def kv_bytes_per_slot(self) -> int:
         """Stored KV-table bytes per serving slot: every cache leaf —
@@ -1440,7 +1540,7 @@ class PagedSlotKVCache(SlotKVCache):
     even decode on the gather path (the parity oracle in paged clothes).
     """
 
-    def __init__(self, model: GPTLM, params, slots: int, *,
+    def __init__(self, model: nn.Module, params, slots: int, *,
                  mesh=None, greedy: bool = True, temperature: float = 1.0,
                  prefill_bucket: int = 8, rng=None, kv_dtype=None,
                  prefix_cache_blocks: int = 0, prefix_block: int = 16,
@@ -1503,20 +1603,19 @@ class PagedSlotKVCache(SlotKVCache):
         if kv_dtype is not None:
             kv_dtype = jnp.dtype(kv_dtype)
             self.quantized = kv_dtype == jnp.dtype(jnp.int8)
-        keep_tp = (mesh is not None and model.partition_model
+        keep_tp = (mesh is not None
+                   and getattr(model, "partition_model", False)
                    and meshlib.MODEL_AXIS in mesh.axis_names)
+        self.prefill_form = model.prefill_form
+        self._scan_model_only("the paged layout")
         # fused clone for the decode/verify hot ops, gather clone for the
         # prefill scan (bitwise-monolithic math) — same params, same
         # cache variables, only the read path differs
         self.paged_fused = bool(paged_fused)
-        self.dm = model.clone(decode=True, decode_slots=True,
-                              attention_impl="dense",
-                              partition_model=keep_tp, dropout_rate=0.0,
-                              kv_quant=self.quantized,
-                              paged_blocks=self.num_blocks + 1,
-                              paged_block=block,
-                              paged_fused=self.paged_fused,
-                              paged_mesh=mesh)
+        self.dm = model.slot_decode_clone(
+            partition_model=keep_tp, kv_quant=self.quantized,
+            paged_blocks=self.num_blocks + 1, paged_block=block,
+            paged_fused=self.paged_fused, paged_mesh=mesh)
         self.dm_gather = self.dm.clone(paged_fused=False)
         self._rng = rng if rng is not None else jax.random.key(0)
 

@@ -514,7 +514,8 @@ class ContinuousBatcher:
             # the span ends after the host holds the first token (insert
             # returns it as an int): its duration is the prefill, not the
             # enqueue — the stall and queue-wait metrics rest on that
-            with tracer.span("prefill", rid=req.rid, prompt_len=lp) as sp:
+            with tracer.span("prefill", rid=req.rid, prompt_len=lp,
+                             form=kv.prefill_form) as sp:
                 slot, first = kv.insert(req.prompt)
                 sp["padded_len"] = kv.prefill_tokens_padded - padded
             self.clock.on_prefill(kv.prefill_tokens_computed - before)
@@ -1061,8 +1062,10 @@ class ContinuousBatcher:
             # (advance returns them through np.asarray): its duration is
             # the decode step, and the gap to the next one is the stall
             with self.tracer.span("decode_step", active=len(live),
-                                  slots=kv.slots):
+                                  slots=kv.slots) as sp:
                 toks = kv.advance()
+                if kv.last_routing is not None:     # a model with experts
+                    sp.update(kv.last_routing)
             return {slot: [int(toks[slot])] for slot in live}
         return self._spec_round(live, k_eff)
 
@@ -1254,14 +1257,21 @@ class ContinuousBatcher:
             wall0 = time.perf_counter()
             # the window's root: a reader takes the records inside the
             # last of these and so leaves warm-up windows out
+            counts_before = self.kv.counters()
             with self.tracer.span("serve_run", offered=offered,
-                                  slots=self.kv.slots, mode=self.mode):
+                                  slots=self.kv.slots, mode=self.mode) as sp:
                 try:
                     decode_iterations, prefills, chunks = self._serve(
                         queue, live, pending, on_token)
                 except BaseException:
                     self._release_failed_window(live, pending)
                     raise
+                # the table's counters over this window, on the window's
+                # own record (`analyze serve` prints them)
+                counts = self.kv.counters()
+                sp["cache_bytes_per_token"] = counts["cache_bytes_per_token"]
+                sp["expert_assignments"] = (counts["expert_assignments"]
+                                            - counts_before["expert_assignments"])
             wall_elapsed = time.perf_counter() - wall0
             elapsed = self.clock.now() - t_start
         results = sorted(self._results, key=lambda r: r.rid)
