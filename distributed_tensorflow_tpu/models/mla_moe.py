@@ -24,8 +24,15 @@ equal):
 
 * EXPANDED (training-mode forward, prefill): ``k = [k_n, k_r]``, ``q =
   [q_n, q_r]``, scores ``q k^T / sqrt(d_n + d_r)``, causal, softmax in
-  float32, ``o = P v``.  Computed in query blocks against the keys at or
-  before the block, so ``H x L x L`` scores never exist.
+  float32, ``o = P v``.  Computed in query blocks of ``ATTN_QUERY_BLOCK``
+  against the keys at or before the block, so ``H x L x L`` scores never
+  exist.  A block whose keys fit ``ATTN_KEY_BLOCK`` meets them in one
+  piece (one softmax); a later one takes them a key block at a time and
+  carries the softmax across the pieces in float32 (running maximum, sum
+  and unnormalised output), so no score tile is wider than the key block
+  either: on the v5e the softmax over rows of more than 4,096 keys takes
+  36 times what its bytes need, whatever the query block (PERF.md
+  section 5).
 * ABSORBED (the slot-decode step): the cache holds, a token a layer, ``c``
   (``r`` values) and the rotated ``k_r`` (``d_r``) and nothing else.  With
   ``W_kvb`` split per head into ``W_k`` and ``W_v``: ``q_c = q_n W_k^T``,
@@ -53,7 +60,9 @@ from jax import lax
 from distributed_tensorflow_tpu.models.gpt import write_slot_rows
 from distributed_tensorflow_tpu.models.moe import DroplessMoE, SwiGLU
 
-ATTN_QUERY_BLOCK = 512      # expanded form: H x 512 x L float32 scores at most
+# expanded form: H x 512 x 4096 float32 scores at most
+ATTN_QUERY_BLOCK = 512
+ATTN_KEY_BLOCK = 4096       # chosen on the v5e: PERF.md section 5
 
 
 class RMSNorm(nn.Module):
@@ -87,22 +96,57 @@ def rope_adjacent(x, pos, theta: float):
 
 
 def causal_attention_blocked(q, k, v, scale: float,
-                             block: int = ATTN_QUERY_BLOCK):
-    """Causal softmax attention from position 0 in query blocks.
+                             block: int = ATTN_QUERY_BLOCK,
+                             key_block: int = ATTN_KEY_BLOCK):
+    """Causal softmax attention from position 0, in query blocks against
+    key blocks.
 
-    ``q``, ``k``: (B, L, H, d_qk); ``v``: (B, L, H, d_v).  Block ``i``
-    attends to keys ``[0, (i + 1) * block)`` only, so the work is the
-    causal half and the scores of one block are all that is live."""
+    ``q``, ``k``: (B, L, H, d_qk); ``v``: (B, L, H, d_v).  Query block
+    ``i`` attends to keys ``[0, (i + 1) * block)`` only, so the work is the
+    causal half.  Where those keys fit one key block they are taken in one
+    piece: one product, one softmax, one product.  Where they do not, they
+    are taken ``key_block`` at a time and the softmax is carried across
+    the pieces (running row maximum ``m``, row sum ``l`` and unnormalised
+    output ``acc``, all float32), so no score tile wider than ``key_block``
+    is ever live; only the piece on the diagonal is masked."""
     length = q.shape[1]
+
+    def scores(lo, hi, k0, k1, masked):
+        s = jnp.einsum("bqhd,bkhd->bhqk", q[:, lo:hi], k[:, k0:k1],
+                       preferred_element_type=jnp.float32) * scale
+        if not masked:
+            return s
+        mask = (jnp.arange(k0, k1)[None, :] <= jnp.arange(lo, hi)[:, None])
+        return jnp.where(mask, s, -jnp.inf)
+
     outs = []
     for lo in range(0, length, block):
         hi = min(lo + block, length)
-        s = jnp.einsum("bqhd,bkhd->bhqk", q[:, lo:hi], k[:, :hi],
-                       preferred_element_type=jnp.float32) * scale
-        mask = (jnp.arange(hi)[None, :] <= jnp.arange(lo, hi)[:, None])
-        p = jax.nn.softmax(jnp.where(mask, s, -jnp.inf), axis=-1)
-        outs.append(jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype),
-                               v[:, :hi]))
+        if hi <= key_block:
+            p = jax.nn.softmax(scores(lo, hi, 0, hi, True), axis=-1)
+            outs.append(jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype),
+                                   v[:, :hi]))
+            continue
+        # the first piece holds key 0, which every query sees: m is finite
+        # from there on and a row with no key in a later piece adds zeros
+        m = l = acc = None
+        for k0 in range(0, hi, key_block):
+            k1 = min(k0 + key_block, hi)
+            s = scores(lo, hi, k0, k1, masked=k1 > lo + 1)
+            # the result does not depend on m: no gradient through it
+            top = lax.stop_gradient(jnp.max(s, axis=-1))
+            m_new = top if m is None else jnp.maximum(m, top)
+            p = jnp.exp(s - m_new[..., None])
+            pv = jnp.einsum("bhqk,bkhd->bhqd", p.astype(v.dtype),
+                            v[:, k0:k1], preferred_element_type=jnp.float32)
+            if m is None:
+                l, acc = jnp.sum(p, axis=-1), pv
+            else:
+                a = jnp.exp(m - m_new)
+                l = l * a + jnp.sum(p, axis=-1)
+                acc = acc * a[..., None] + pv
+            m = m_new
+        outs.append(jnp.swapaxes(acc / l[..., None], 1, 2).astype(v.dtype))
     return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
 
 

@@ -489,6 +489,93 @@ def test_rope_rotates_adjacent_pairs_by_position():
     np.testing.assert_allclose(got[..., 1::2], z.imag, atol=2e-5)
 
 
+def plain_causal_attention(q, k, v, scale):
+    """All scores at once, one softmax a row: what the blocked form has to
+    equal."""
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k, precision="highest") * scale
+    length = q.shape[1]
+    s = jnp.where(jnp.tril(jnp.ones((length, length), bool)), s, -jnp.inf)
+    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v,
+                      precision="highest")
+
+
+def attention_inputs(length, seed=8, d_qk=12, d_v=5):
+    rng = np.random.default_rng(seed)
+    return [jnp.asarray(rng.standard_normal((2, length, 3, d)), jnp.float32)
+            for d in (d_qk, d_qk, d_v)]
+
+
+# (length, query block, key block): a key block that is a multiple of the
+# query block (the module's 4,096 over 512), one that is not (the diagonal
+# piece then starts inside the query block and rows before it see no key
+# of it), lengths that are a multiple of neither, keys that fit one piece
+@pytest.mark.parametrize("length, block, key_block", [
+    (67, 8, 16), (67, 8, 12), (45, 16, 8), (64, 8, 16), (23, 8, 5),
+    (29, 8, 32), (7, 8, 4)])
+def test_blocked_attention_is_plain_causal_attention(length, block,
+                                                     key_block):
+    from distributed_tensorflow_tpu.models.mla_moe import (
+        causal_attention_blocked)
+
+    q, k, v = attention_inputs(length)
+    with jax.default_matmul_precision("highest"):
+        got = causal_attention_blocked(q, k, v, 0.3, block=block,
+                                       key_block=key_block)
+    want = plain_causal_attention(q, k, v, 0.3)
+    assert got.shape == want.shape == (2, length, 3, 5)
+    assert gap(got, want) < TOL
+    assert bool(jnp.isfinite(got).all())
+
+
+def test_blocked_attention_has_plain_attentions_gradient():
+    """The carried softmax is differentiated like any other code (the
+    expanded form is the training-mode forward); the running maximum
+    carries no gradient and the result does not depend on it."""
+    from distributed_tensorflow_tpu.models.mla_moe import (
+        causal_attention_blocked)
+
+    q, k, v = attention_inputs(45, seed=9)
+    w = jnp.asarray(np.random.default_rng(10).standard_normal((2, 45, 3, 5)),
+                    jnp.float32)
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(fn(q, k, v) * w)
+
+    with jax.default_matmul_precision("highest"):
+        got = jax.grad(loss(lambda q, k, v: causal_attention_blocked(
+            q, k, v, 0.3, block=8, key_block=12)), (0, 1, 2))(q, k, v)
+    want = jax.grad(loss(lambda q, k, v: plain_causal_attention(
+        q, k, v, 0.3)), (0, 1, 2))(q, k, v)
+    for g, r in zip(got, want):
+        assert bool(jnp.isfinite(g).all())
+        assert gap(g, r) < TOL
+
+
+def test_keys_that_fit_one_block_take_the_one_softmax_path():
+    """The module's constants: a length up to ``ATTN_KEY_BLOCK`` lowers to
+    one softmax a query block and no carried state, whatever the key
+    block; beyond it no score tile is wider than the key block."""
+    from distributed_tensorflow_tpu.models import mla_moe
+
+    assert mla_moe.ATTN_KEY_BLOCK % mla_moe.ATTN_QUERY_BLOCK == 0
+    q, k, v = (jax.ShapeDtypeStruct((1, 40, 2, d), jnp.float32)
+               for d in (12, 12, 5))
+
+    def text(key_block):
+        return jax.jit(lambda q, k, v: mla_moe.causal_attention_blocked(
+            q, k, v, 0.3, block=8, key_block=key_block)).lower(
+                q, k, v).as_text()
+
+    def widths(t):      # of the (B, H, query block, keys) score tiles
+        return {int(w) for w in re.findall(r"tensor<1x2x8x(\d+)xf32>", t)}
+
+    short = text(40)
+    assert short == text(mla_moe.ATTN_KEY_BLOCK)
+    assert short.count("stablehlo.exponential") == 5     # one a query block
+    assert {8, 16, 24, 32, 40} <= widths(short)
+    assert max(widths(text(16))) == 16
+
+
 def test_bfloat16_weights_are_held_and_served(model, weights, tokens):
     """The serving configuration: weights HELD in bfloat16, products in
     bfloat16, logits float32 and near the float32 program's (bfloat16

@@ -12,15 +12,17 @@ file.  Keep every such test in this one file, so that one worker holds the
 library (a second file could go to a worker whose fixture then skips).
 """
 
+import json
 import os
 import re
+from pathlib import Path
 
 import pytest
 
 import jax
 import jax.numpy as jnp
 
-from distributed_tensorflow_tpu.models import create_model
+from distributed_tensorflow_tpu.models import create_model, mla_moe
 from distributed_tensorflow_tpu.serving import SlotKVCache
 
 
@@ -48,6 +50,18 @@ class _ProgramProbe(SlotKVCache):
         return super()._jit(fn, name, **jit_kwargs)
 
 
+def _shapes_on(sharding):
+    """``on_chip(shape, dtype)`` and ``like(tree)``: shapes placed on the
+    described device (it holds no array)."""
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    def like(tree):
+        return jax.tree.map(lambda t: on_chip(t.shape, t.dtype), tree)
+
+    return on_chip, like
+
+
 def test_decode_step_writes_the_slot_table_in_place(one_chip):
     """The serve cell's decode step (gpt2-large widths, 32 slots x 1024,
     bf16 table, float32 parameters, greedy, the table donated) at 2 layers:
@@ -72,11 +86,7 @@ def test_decode_step_writes_the_slot_table_in_place(one_chip):
                        kv_dtype=jnp.bfloat16)
     step, jit_kwargs = kv.programs["kv_decode_step"]
 
-    def on_chip(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
-    def like(tree):
-        return jax.tree.map(lambda t: on_chip(t.shape, t.dtype), tree)
+    on_chip, like = _shapes_on(one_chip)
 
     compiled = jax.jit(step, **jit_kwargs).lower(
         like(params), like(kv.cache), on_chip((slots,), jnp.int32),
@@ -91,3 +101,48 @@ def test_decode_step_writes_the_slot_table_in_place(one_chip):
     assert not copies, f"{len(copies)} relayout copies of a table leaf"
     assert memory.temp_size_in_bytes < leaf_bytes, (
         memory.temp_size_in_bytes, leaf_bytes)
+
+
+def test_the_long_prefill_holds_no_score_tile_wider_than_the_key_block(
+        one_chip):
+    """The long-document cell's ``kv_prefill_batched_l8192`` (the published
+    widths of ``benchmarks/configs/kanana-2-30b-a3b-6l.json``, 32 slots x
+    8,192 latents, bfloat16) at ONE layer, the leading dense one: the
+    expanded attention is the same in every layer.
+
+    Until PR 28 a 512-query block met all its keys in one piece and the
+    compiled program held ``f32[32,512,L]`` scores up to ``L`` = 8,192: on
+    the chip the softmax fusion over such a tile took 47 ms where its bytes
+    need 1.3 ms, and 1.5 ms up to ``L`` = 4,096 (PERF.md section 5).  The
+    keys now come ``ATTN_KEY_BLOCK`` at a time: no wider tile is left, and
+    the temporaries fall from 1.55 GB to 1.04 GB at this depth."""
+    config = json.loads((Path(__file__).resolve().parent.parent / "benchmarks"
+                         / "configs" / "kanana-2-30b-a3b-6l.json").read_text())
+    slots, lpad, heads = 32, 8192, config["num_attention_heads"]
+    model = create_model(
+        "mla_moe", dtype="bfloat16", param_dtype="bfloat16", max_len=lpad,
+        vocab_size=config["vocab_size"], hidden=config["hidden_size"],
+        layers=1, first_dense=1, heads=heads,
+        qk_nope_dim=config["qk_nope_head_dim"],
+        qk_rope_dim=config["qk_rope_head_dim"], v_dim=config["v_head_dim"],
+        kv_rank=config["kv_lora_rank"], dense_ffn=config["intermediate_size"])
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32),
+                           train=False))["params"]
+    kv = _ProgramProbe(model, params, slots, greedy=True,
+                       kv_dtype=jnp.bfloat16)
+    kv._prefill(lpad)
+    prefill, jit_kwargs = kv.programs[f"kv_prefill_batched_l{lpad}"]
+
+    on_chip, like = _shapes_on(one_chip)
+
+    compiled = jax.jit(prefill, **jit_kwargs).lower(
+        like(params), like(kv.cache), on_chip((), jnp.int32),
+        on_chip((lpad,), jnp.int32), on_chip((), jnp.int32),
+        like(jax.random.key(0))).compile()
+
+    tiles = {int(keys) for keys in re.findall(
+        rf"f32\[{heads},{mla_moe.ATTN_QUERY_BLOCK},(\d+)\]",
+        compiled.as_text())}
+    assert max(tiles) == mla_moe.ATTN_KEY_BLOCK, sorted(tiles)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.2e9
