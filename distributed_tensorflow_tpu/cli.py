@@ -17,9 +17,9 @@ N+1 processes):
   -m d  -ds fsdp    → fsdp engine      (ZeRO sharded params+optimizer — the
                                         ref's single-home optimizer,
                                         server.py:52-55, TPU-first)
-  -m t/tpu_pod      → sync engine      (BASELINE.json north-star mode)
+  -m t/tpu_pod      → sync engine      (BASELINE.md north-star mode)
 
-``-n`` selects TPU device count (BASELINE.json: "-n maps to device count");
+``-n`` selects TPU device count (BASELINE.md: "-n maps to device count");
 ``-b`` stays the per-worker batch, so the global batch is b×n like the
 reference's aggregate.  ``-ca`` is accepted-and-ignored: core pinning
 simulated "1 node = 1 core" (reference server.py:144-146), and a TPU device

@@ -363,7 +363,7 @@ def load_text_dataset(
     n_train: int = 4096,
     n_test: int = 1024,
 ) -> Dataset:
-    """Text workload loader (BASELINE.json BERT-tiny stretch config).
+    """Text workload loader (BASELINE.md BERT-tiny stretch config).
     Currently synthetic-only: real GLUE needs downloads this env can't do."""
     n = n_train if split == "train" else n_test
     x, y = synthetic_text_classification(

@@ -415,7 +415,7 @@ class Trainer:
             checkpoint_manager.tracer = tracer
         ckpt_wait = 0.0  # training-thread seconds spent in checkpointing
         ckpt_last_step = None  # skip a final save the cadence already wrote
-        # managers outlive fits (bench reuses one): report THIS fit's
+        # a manager may outlive a fit: report THIS fit's
         # overlapped seconds, not the manager's lifetime total
         ckpt_overlap0 = getattr(checkpoint_manager, "overlapped_s", 0.0)
         # batch-stream position of the CURRENT epoch, maintained by the
@@ -544,8 +544,7 @@ class Trainer:
             # WIRE bytes one gradient collective moves per round under the
             # engine's --grad-compression codec, plus the raw (f32-era)
             # figure for comparison — the collective-path size every
-            # scaling analysis starts from (param dtypes are real, the
-            # bench_decode accounting)
+            # scaling analysis starts from (param dtypes are real)
             tracer.event("collective_profile",
                          grad_allreduce_bytes=grad_bytes,
                          grad_allreduce_bytes_raw=grad_bytes_raw,
@@ -987,7 +986,7 @@ class Trainer:
                if watchdog is not None else {}),
             # numeric-health summary (engine health layer on): run maxima
             # of the per-step stats plus the anomaly record — the section
-            # the run report / bench carry forward
+            # the run report carries forward
             **({"health": {
                 "on_anomaly": on_anomaly,
                 "anomalies": n_anomalies,

@@ -537,9 +537,8 @@ class Engine:
         """UNCOMPRESSED bytes one gradient collective round moves (the
         data-axis allreduce of sync DP), from the REAL param leaf dtypes —
         gradients share the params' shapes and dtypes, so for the
-        replicated-param engines this is the per-step payload (the same
-        itemsize accounting bench_decode uses for its weight-streaming
-        figure, not an assumed 4 B/param).  Engines whose state layout or
+        replicated-param engines this is the per-step payload (the leaves'
+        own itemsize, not an assumed 4 B/param).  Engines whose state layout or
         collective cadence differs override this (async/gossip stack a
         leading per-device axis and sync every ``sync_every`` steps).
         0 when the state carries no param pytree."""
@@ -562,7 +561,7 @@ class Engine:
         codec is a quantize→dequantize roundtrip, so this is the codec's
         payload ACCOUNTING, not the executed transfer
         (parallel/compression.py module docstring).  Telemetry (the
-        tracer's ``collective_profile`` event, the fit result, bench.py)
+        tracer's ``collective_profile`` event, the fit result)
         reports BOTH figures so the compression win is visible."""
         params = getattr(state, "params", None)
         if params is None:
@@ -593,9 +592,8 @@ class Engine:
 
     def param_bytes_per_device(self, state: TrainState) -> int:
         """Per-device parameter bytes — THE storage number the precision
-        policy halves (bf16 storage ≈ f32/2): reported in the fit result,
-        run report and bench lines, gated lower-is-better by
-        ``analyze diff``."""
+        policy halves (bf16 storage ≈ f32/2): reported in the fit result
+        and run report, gated lower-is-better by ``analyze diff``."""
         return self._bytes_per_device(getattr(state, "params", None))
 
     def opt_state_bytes_per_device(self, state: TrainState) -> int:

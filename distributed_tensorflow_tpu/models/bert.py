@@ -1,8 +1,8 @@
-"""BERT-tiny sequence classifier — BASELINE.json's stretch config.
+"""BERT-tiny sequence classifier — BASELINE.md's stretch config.
 
 The reference has no attention or sequence models anywhere (SURVEY.md §2.2:
 its only model is an MLP on 28×28, reference initializer.py:14-19);
-BASELINE.json adds "BERT-tiny GLUE fine-tune" as a stretch benchmark.
+BASELINE.md adds "BERT-tiny GLUE fine-tune" as a stretch benchmark.
 Standard BERT-tiny shape: 2 layers, hidden 128, 2 heads, FFN 512.
 
 Attention is pluggable (``attention_impl``):

@@ -1,6 +1,6 @@
 """Small convnet for MNIST-class workloads.
 
-The BASELINE.json headline config is "MNIST CNN"; the reference itself ships
+The BASELINE.md headline config is "MNIST CNN"; the reference itself ships
 only the MLP (reference initializer.py:14-19) and hints at uncommitted
 CIFAR-10 experiments (reference .gitignore:1-4).  Conv layers map directly
 onto the MXU; keep channel counts multiples of 8 for good tiling.

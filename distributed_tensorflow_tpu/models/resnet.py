@@ -1,7 +1,7 @@
 """ResNet-20 for CIFAR-10 — the reference's ghost second workload.
 
 The reference never committed its CIFAR-10 experiments (reference
-.gitignore:1-4 lists `cifar10.py`, `cifar10_train.py`), but BASELINE.json
+.gitignore:1-4 lists `cifar10.py`, `cifar10_train.py`), but BASELINE.md
 names "CIFAR-10 ResNet-20, -m centralized -cs async" as a benchmark config.
 Classic He et al. CIFAR variant: 3 stages × 3 basic blocks, widths 16/32/64.
 

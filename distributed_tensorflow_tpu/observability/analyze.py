@@ -13,7 +13,7 @@ answers:
                            scheduler's request/prefill_chunk spans +
                            overload shed events; --text renders bars
   diff BASE NEW            run-vs-run regression diff of two run reports
-                           (or BENCH_*.json lines); exits nonzero iff a
+                           (or summary / result lines); exits nonzero iff a
                            metric regressed beyond --threshold
   timeline TRACE.jsonl     the --timeline gauge series (queue depth, KV
                            blocks, replica load, chunk step time) rendered
@@ -36,9 +36,9 @@ answers:
 
 Inputs are whatever the sinks wrote: a trace JSONL (``--trace``), a metrics
 JSONL (``--metrics-path``), a result JSONL (``--result-path``), the
-harness's printed summary, or a ``bench.py`` line.  ``load_report`` accepts
+harness's printed summary, or a result line.  ``load_report`` accepts
 any of them — for multi-line files the LAST parsable JSON object wins (the
-summary/bench line), and a ``run_report`` found inside a summary is
+summary or result line), and a ``run_report`` found inside a summary is
 flattened into the comparison.
 
 Deliberately stdlib-only (json/math/argparse): the analyzer must run
@@ -266,7 +266,7 @@ def timeline_series(records: Iterable[dict]) -> dict[str, GaugeSeries]:
             "vmin": rec.get("vmin"),
             "vmax": rec.get("vmax"),
         })
-        if key in out:      # several windows in one trace (bench/sweep)
+        if key in out:      # several windows in one trace
             out[key].merge(g)
         else:
             out[key] = g
@@ -337,7 +337,7 @@ def serve_waterfall(records: list[dict]) -> dict[str, Any]:
     ``request`` spans carry queue_wait_s/prefill_s/decode_s/ttft_s attrs
     (attached at finish), ``prefill_chunk`` spans carry the chunk-by-
     chunk fill, and ``overload`` events are the shed (429'd) requests.
-    One row per request SPAN (not per rid: a bench/sweep trace holds
+    One row per request SPAN (not per rid: a trace may hold
     several windows that all reuse rids 0..n−1 — every window's spans
     get their own rows, and each chunk attaches to the request span
     whose [start, end] interval contains it), arrival-ordered — the
@@ -418,7 +418,7 @@ def serve_waterfall(records: list[dict]) -> dict[str, Any]:
                 break
     # failover attribution: a row is a RETRY segment (attempt 2, 3, ...)
     # only when a requeue hop for its rid landed between the previous
-    # same-rid row's start and this row's start — bench traces reuse
+    # same-rid row's start and this row's start — multi-window traces reuse
     # rids 0..n−1 across windows, so bare same-rid counting would tag
     # every later window's rows as phantom retries.  The hop's original
     # arrival keys the retried row (the retry-TTFT accounting rule).
@@ -598,14 +598,14 @@ def health_timeline(records: list[dict], *,
 # ------------------------------------------------------------ run-vs-run
 
 # (key, better-direction) pairs the differ compares when present+numeric in
-# BOTH reports.  Covers run reports, fit summaries AND bench.py lines —
-# one table so a BENCH_*.json trajectory can be diffed against a run.
+# BOTH reports.  Covers run reports, fit summaries and one-line results
+# (``metric``/``value``/``unit``) — one table for all of them.
 _DIFF_METRICS: tuple[tuple[str, str], ...] = (
     ("step_time_p50_s", "lower"), ("step_time_p95_s", "lower"),
     ("step_time_mean_s", "lower"), ("compile_s", "lower"),
     ("elapsed_s", "lower"), ("telemetry_overhead_frac", "lower"),
     ("grad_allreduce_bytes", "lower"),
-    # per-device state footprint (--precision; run report AND bench line):
+    # per-device state footprint (--precision):
     # the storage numbers mixed precision exists to shrink — param bytes
     # halve under bf16 storage; optimizer bytes are gated too so a master
     # policy's f32 copy (a deliberate, bounded cost) cannot silently grow
@@ -616,8 +616,8 @@ _DIFF_METRICS: tuple[tuple[str, str], ...] = (
     # section below): a step that skipped did no training — more skips at
     # equal work is a regression
     ("loss_scale_skipped_steps", "lower"),
-    # exposed gradient-collective seconds (run report AND bench line —
-    # the communication/compute-overlap gate, BASELINE.md: exposed time
+    # exposed gradient-collective seconds (the communication/compute-
+    # overlap gate, BASELINE.md "Exposed-collective accounting": exposed time
     # is the number that must go down; hidden_s is deliberately NOT
     # compared — burying more collective time under compute is the point)
     ("grad_collective_exposed_s", "lower"),
@@ -639,15 +639,15 @@ _DIFF_METRICS: tuple[tuple[str, str], ...] = (
     ("straggler_events", "lower"),
     ("examples_per_sec", "higher"), ("examples_per_sec_per_device", "higher"),
     ("test_accuracy", "higher"),
-    # bench.py line vocabulary ("value"'s direction is resolved per line —
-    # see _value_direction; today's value-bearing bench metrics are rates)
+    # one-line result vocabulary ("value"'s direction is resolved per line —
+    # see _value_direction)
     ("step_time_p50", "lower"), ("step_time_p95", "lower"),
     ("prefetch_starvation", "lower"), ("grad_bytes_per_step_wire", "lower"),
     ("dispatch_value", "higher"), ("trainer_examples_per_sec", "higher"),
     ("mfu", "higher"),
     # health: anomaly count (flattened from the health section below)
     ("health_anomalies", "lower"),
-    # serving (bench --serve line / run report `serve` section, flattened
+    # serving (a serve summary / run report `serve` section, flattened
     # below): latency percentiles gate lower-is-better — TTFT includes
     # queue wait by the BASELINE.md accounting rule, so an admission
     # regression shows up here, not just in throughput — and
@@ -762,7 +762,7 @@ _DIFF_METRICS: tuple[tuple[str, str], ...] = (
 def load_report(path: str | Path) -> dict[str, Any]:
     """One comparable dict from any artifact this repo writes: a JSON
     object, or a JSONL stream whose LAST parsable object wins (result
-    sinks append the summary last; bench prints one line).  A nested
+    sinks append the summary last).  A nested
     ``run_report`` is flattened under the summary's own keys, and the
     ``health`` section's anomaly count surfaces as ``health_anomalies``."""
     text = Path(path).read_text()
@@ -804,7 +804,7 @@ def load_report(path: str | Path) -> dict[str, Any]:
         flat.setdefault("straggler_events", stragglers["events"])
     # a run report's nested `serve` section surfaces its serve_* metrics
     # at the top level so serving runs diff with the same machinery as
-    # training runs (bench --serve lines already emit them flat)
+    # training runs (a bare serve summary already has them flat)
     serve = flat.get("serve")
     if isinstance(serve, dict):
         for key, value in serve.items():
@@ -832,14 +832,14 @@ def load_report(path: str | Path) -> dict[str, Any]:
 
 
 def _value_direction(report: dict[str, Any]) -> str:
-    """Better-direction of a bench line's headline ``value``, resolved
+    """Better-direction of a result line's headline ``value``, resolved
     from the line itself: time-valued metrics/units (ms, seconds) are
-    lower-is-better, rates (the current bench vocabulary — examples/sec,
-    tokens/sec) higher.  Hard-coding 'higher' would invert the verdict
-    the day a time-valued bench metric gains a headline value."""
+    lower-is-better, rates (examples/sec, tokens/sec) higher.
+    Hard-coding 'higher' would invert the verdict for a time-valued
+    headline."""
     probe = f"{report.get('metric', '')} {report.get('unit', '')}".lower()
     # rates first: "…_per_sec_per_chip" CONTAINS the substring "sec_per",
-    # so the time-per test alone misread every rate-valued bench line as
+    # so the time-per test alone misread every rate-valued line as
     # lower-is-better (an examples/sec improvement diffed as a regression)
     if any(s in probe for s in ("per_sec", "per sec", "/sec", "/s ")):
         return "higher"
@@ -867,7 +867,7 @@ def diff_reports(base: dict[str, Any], new: dict[str, Any],
     when it moves in its worse direction by more than ``threshold``
     (relative; a zero baseline uses absolute change).  Returns
     {regressions, improvements, unchanged, compared, threshold} — plus
-    ``metric_mismatch`` (and NO comparisons) when the two inputs are bench
+    ``metric_mismatch`` (and NO comparisons) when the two inputs are result
     lines for different metrics: a decode line diffed against an attention
     line would otherwise compare unrelated numbers silently."""
     m_a, m_b = base.get("metric"), new.get("metric")
@@ -1003,7 +1003,7 @@ def main(argv: list[str] | None = None) -> int:
     sv = sub.add_parser("serve", help="per-request serving waterfall "
                                       "(queue→prefill-chunks→decode)")
     sv.add_argument("trace", help="serving trace JSONL (--trace output "
-                                  "of a --serve run or bench --serve)")
+                                  "of a --serve run)")
     sv.add_argument("--text", action="store_true",
                     help="render ASCII bars instead of JSON")
     sv.add_argument("--width", type=int, default=60,
@@ -1011,8 +1011,8 @@ def main(argv: list[str] | None = None) -> int:
 
     df = sub.add_parser("diff", help="run-vs-run regression diff "
                                      "(exit 1 iff a metric regressed)")
-    df.add_argument("base", help="baseline report/summary/bench JSON(L)")
-    df.add_argument("new", help="candidate report/summary/bench JSON(L)")
+    df.add_argument("base", help="baseline report/summary JSON(L)")
+    df.add_argument("new", help="candidate report/summary JSON(L)")
     df.add_argument("--threshold", type=float, default=0.1,
                     help="relative regression threshold (default 0.1)")
 
@@ -1111,7 +1111,7 @@ def main(argv: list[str] | None = None) -> int:
     if args.cmd == "roofline":
         return _cmd_roofline(args)
     # diff: 0 = no regression, 1 = regression past threshold, 2 = nothing
-    # was compared (mismatched bench metrics, or inputs sharing no known
+    # was compared (mismatched ``metric`` names, or inputs sharing no known
     # metric keys — e.g. an operator diffing two trace files).  A 0 on an
     # empty comparison would read as "no regression" for a typo.
     result = diff_reports(load_report(args.base), load_report(args.new),

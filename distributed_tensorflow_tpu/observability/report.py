@@ -66,7 +66,7 @@ def device_memory(devices) -> list[dict[str, Any]]:
 
 def serve_section(summary: dict[str, Any] | None,
                   n_devices: int = 1, tracer=None) -> dict[str, Any] | None:
-    """Normalize a ContinuousBatcher summary into the run-report/bench
+    """Normalize a ContinuousBatcher summary into the run report's
     ``serve`` section: the per-request result objects are reduced to
     their token streams (``generated_tokens``, one list per request in rid
     order — the section must stay JSON, and a greedy window is compared

@@ -121,8 +121,8 @@ class GPTCostModel:
     """Analytic FLOPs/bytes for one GPT config (models/gpt.py fields).
 
     FLOPs are matmul FLOPs only (2·MACs): embeddings are gathers, LN and
-    softmax are elementwise — excluded, the standard MFU accounting the
-    CNN bench already uses.  MoE counts the ACTIVE path (top-1 through
+    softmax are elementwise — excluded, the standard MFU accounting.
+    MoE counts the ACTIVE path (top-1 through
     one ffn-wide expert — identical FLOPs to dense by construction,
     models/moe.py) plus the router projection.
     """

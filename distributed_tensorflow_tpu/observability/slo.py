@@ -8,9 +8,9 @@ counts only when its TTFT *and* its inter-token latency are inside the
 SLO, and a shed (429'd) request never counts, however fast the rejection
 was.  :class:`SLOMonitor` is that definition as an online accumulator —
 one ``observe`` per completed request, one ``shed`` per rejected one, a
-``summary`` per window — so ``bench.py --serve --sweep`` can walk the
-arrival-rate ladder and report ``serve_max_goodput_under_slo`` as the
-number a capacity plan can actually be written against.
+``summary`` per window — so a sweep over arrival rates can report the
+highest goodput under the SLO as the number a capacity plan is written
+against.
 
 Per-request ITL is judged at a percentile of that request's own gaps
 (p99 by default): a stream that stalls once near the end failed its

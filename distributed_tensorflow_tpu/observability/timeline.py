@@ -241,7 +241,7 @@ class Timeline:
     def stat(self, name: str, field: str,
              replica: int | None = None) -> Any:
         """One summary field of one series, None when the series does not
-        exist — the run-report/bench key accessor."""
+        exist — the run report's key accessor."""
         s = self._series.get(_series_key(name, replica))
         return s.summary().get(field) if s is not None else None
 

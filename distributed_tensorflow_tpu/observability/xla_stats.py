@@ -15,8 +15,8 @@ captures both without changing what runs:
   dispatches the cached executable — same program, one extra host-side
   bookkeeping pass at compile time, zero per-call device syncs.
 * ``capture(name, lowered_or_compiled)`` records programs compiled
-  elsewhere (the bench harness already AOT-lowers the train step for
-  ``cost_analysis`` — the same executable yields its memory analysis at
+  elsewhere (a caller that already AOT-lowers a step for
+  ``cost_analysis`` gets its memory analysis from the same executable at
   no extra compile).
 
 ``peak_bytes_est`` per program is ``argument + output + temp − alias``
@@ -162,7 +162,7 @@ class ProgramLedger:
 
     def capture(self, name: str, compiled, compile_s: float = 0.0) -> None:
         """Record a compiled executable's memory analysis under ``name``
-        (programs compiled elsewhere — bench's AOT train step — enter
+        (programs compiled elsewhere enter
         here at zero extra compile cost).  Cost-analysis flops/bytes ride
         the same executable (round 19's roofline columns)."""
         self._record(name, {**memory_fields(compiled),

@@ -43,7 +43,7 @@ it times the engine's full step, a collective-free twin, and the
 collective alone, and splits the difference into ``exposed_s`` (collective
 seconds still on the critical path) vs ``hidden_s`` (collective seconds
 the schedule buried under compute).  ``exposed_s`` is the number the
-run report / bench emit as ``grad_collective_exposed_s`` and ``analyze
+run report emits as ``grad_collective_exposed_s`` and ``analyze
 diff`` gates lower-is-better (BASELINE.md): the MLPerf way — report the
 time, then make it disappear.
 """

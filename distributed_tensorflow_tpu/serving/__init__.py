@@ -41,10 +41,10 @@ Three layers:
   ``parallel_lanes`` gives each replica its own virtual-time lane so
   fleet time overlaps replicas deterministically.
 
-``bench.py --serve`` drives an open-loop arrival process through both and
-reports requests/sec/chip + latency percentiles; the harness's ``--serve``
-flag runs a post-training serving window whose summary lands in the run
-report, gated by ``analyze diff`` exactly like the training metrics.
+The harness's ``--serve`` flag runs a post-training serving window whose
+summary lands in the run report, gated by ``analyze diff`` exactly like the
+training metrics; ``benchmarks/drivers/serve.py`` drives the open-loop cells
+of the benchmark through ``SlotKVCache`` and ``ContinuousBatcher`` directly.
 """
 
 from distributed_tensorflow_tpu.serving.fleet import (  # noqa: F401

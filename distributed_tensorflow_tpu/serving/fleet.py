@@ -790,8 +790,8 @@ class ReplicaSet:
     rejected by the journal.  The watchdog still cannot tell a stall
     from a first-program XLA compile (the host blocks inside the same
     call), so set the timeout above worst-case compile time or warm the
-    tables before serving (``bench.py --serve`` warms; the harness's
-    post-train window compiles in its first requests).
+    tables before serving (the harness's post-train window compiles in
+    its first requests).
 
     ``retry_limit`` bounds per-request failover attempts (assignments
     beyond the first), with ``retry_backoff_s`` exponential arrival

@@ -131,7 +131,7 @@ class SlotKVCache:
     def __new__(cls, *args, kv_layout: str = "monolithic", **kwargs):
         # --serve-kv-layout dispatch: constructing a SlotKVCache with
         # kv_layout="paged" yields the paged subclass, so every call site
-        # (harness, bench, fleet's build_replica_kvs **kv_kwargs
+        # (harness, fleet's build_replica_kvs **kv_kwargs
         # pass-through) selects the layout with one kwarg and no factory
         if cls is SlotKVCache and kv_layout == "paged":
             return super().__new__(PagedSlotKVCache)
@@ -1079,8 +1079,8 @@ class SlotKVCache:
         return s
 
     def reset_prefix_cache(self) -> None:
-        """Drop pooled blocks and zero the accounting (bench windows call
-        this so per-window hit rates are deterministic)."""
+        """Drop pooled blocks and zero the accounting, so that a window
+        starts on a cold pool and its hit rate is deterministic."""
         self._prefix_pool.clear()
         for k in self.prefix_stats:
             self.prefix_stats[k] = 0

@@ -75,7 +75,7 @@ attributed per emitted token (a round's batch-mates land at gap 0), so
 the SLO math stays honest.
 
 Clocks are injectable: ``WallClock`` (real time; idle waits sleep until
-the next arrival — the open-loop bench) or ``VirtualClock`` (time = decode
+the next arrival — open-loop serving) or ``VirtualClock`` (time = decode
 iterations; deterministic staggered-arrival tests).
 """
 
@@ -1172,7 +1172,7 @@ class ContinuousBatcher:
         self.kv.abandon_multi()
         if self.draft_kv is not None:
             self.draft_kv.abandon_multi()
-        # a failed window must not poison the slot table — bench windows
+        # a failed window must not poison the slot table — windows may
         # share ONE SlotKVCache, and a leaked active slot shrinks every
         # later window's capacity (zero free slots + zero live = a
         # busy-spin).  Free the in-flight slots (decoding AND
@@ -1241,7 +1241,7 @@ class ContinuousBatcher:
         prefill_before = self.kv.prefill_tokens_computed
         phases_before = self.kv.phase_times()
         # paged-pool counter snapshot (zero-copy/CoW are cumulative on the
-        # kv — bench windows share one pool — so the summary reports
+        # kv — windows may share one pool — so the summary reports
         # deltas over THIS run, like the prefix-pool ledger above)
         paged_before = (self.kv.paged_stats()
                         if hasattr(self.kv, "paged_stats") else None)
@@ -1291,7 +1291,7 @@ class ContinuousBatcher:
         depth_hist = self._registry.histogram("queue_depth")
         phases_after = self.kv.phase_times()
         # prefill/decode token split + prefix-pool accounting, as deltas
-        # over this run (bench windows share one SlotKVCache)
+        # over this run (windows may share one SlotKVCache)
         prefill_tokens = self.kv.prefill_tokens_computed - prefill_before
         prefix_after = self.kv.prefix_cache_stats()
         prefix_sec = hit_rate = None

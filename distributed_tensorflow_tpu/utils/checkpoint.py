@@ -311,7 +311,7 @@ class AsyncCheckpointManager(CheckpointManager):
     concurrently with training accumulate in ``overlapped_s`` (write
     wall time the trainer stood blocked on is counted once, in
     ``wait_s`` — never double-booked as overlap) — the split the run
-    report and ``bench.py --checkpoint-every`` surface.
+    report surfaces.
 
     ``tracer``, when set (the Trainer wires its own in), gets a
     ``ckpt_write`` span per background write, the overlapped twin of the

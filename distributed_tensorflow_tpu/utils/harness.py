@@ -436,8 +436,8 @@ class ExperimentConfig:
 def resolve_compile_cache() -> str | None:
     """Place XLA's persistent compilation cache and return its directory.
 
-    The entry points (``cli.main``, ``bench.main``, ``chip_smoke.py``, the
-    examples) call this before their first compile; package import and
+    The entry points (``cli.main``, ``chip_smoke.py``, the examples) call
+    this before their first compile; package import and
     ``run()`` do not, so a library caller gets a cache only if the
     environment asks for one.  Where ``JAX_COMPILATION_CACHE_DIR`` is set,
     JAX reads it itself and no directory is set in code.  Otherwise the
@@ -473,7 +473,7 @@ def resolve_compile_cache() -> str | None:
 # inert on CPU/GPU containers (an unknown flag in XLA_FLAGS would abort
 # backend init; LIBTPU_INIT_ARGS is the safe carrier).  The effective
 # values are recorded in the run report's `environment` section
-# (observability/report.runtime_environment) so bench trajectories stay
+# (observability/report.runtime_environment) so a run's numbers stay
 # attributable across containers.
 OVERLAP_XLA_TPU_FLAGS = (
     "--xla_tpu_enable_latency_hiding_scheduler=true",
@@ -489,7 +489,7 @@ def enable_overlap_flags(env=None) -> str:
     """Append the communication/compute-overlap XLA flags to
     ``LIBTPU_INIT_ARGS`` (idempotent: a flag whose key is already present
     — e.g. user-overridden to false — is left alone).  Must run BEFORE
-    backend initialization; ``run()`` and ``bench.py`` call it when
+    backend initialization; ``run()`` calls it when
     ``--grad-bucket-mb`` > 0.  Returns the resulting value, which the run
     report records for reproducibility."""
     env = os.environ if env is None else env

@@ -3,10 +3,43 @@ real training on the fake mesh (the analogue of the reference's only
 "test" — an end-to-end run, SURVEY.md §4)."""
 
 import json
+import re
+import shlex
+from pathlib import Path
 
 import pytest
 
 from distributed_tensorflow_tpu.cli import build_parser, main, select_engine, str2bool
+
+
+def _readme_commands():
+    """Every CLI command of README.md's fenced blocks, as argv: the lines
+    that start ``python -m distributed_tensorflow_tpu.cli`` or ``python
+    initializer.py`` (which hands its argv to the same parser), with
+    continuation lines joined, comments and redirections dropped, and the
+    ``...`` that stands for "the training flags" left out."""
+    text = (Path(__file__).resolve().parents[1] / "README.md").read_text()
+    found = []
+    for block in re.findall(r"```bash\n(.*?)```", text, re.S):
+        for line in block.replace("\\\n", " ").splitlines():
+            words = shlex.split(line, comments=True)
+            if words[:3] == ["python", "-m", "distributed_tensorflow_tpu.cli"]:
+                argv = words[3:]
+            elif words[:2] == ["python", "initializer.py"]:
+                argv = words[2:]
+            else:
+                continue
+            if ">" in argv:
+                argv = argv[:argv.index(">")]
+            found.append([w for w in argv if w != "..."])
+    return found
+
+
+@pytest.mark.parametrize("argv", _readme_commands(), ids=" ".join)
+def test_readme_command_is_accepted_by_the_parser(argv):
+    """Nothing is run: a flag the README names and the parser has lost (or
+    a value it no longer takes) exits here with argparse's message."""
+    build_parser().parse_args(argv)
 
 
 def test_str2bool_parity():

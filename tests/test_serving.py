@@ -1,16 +1,12 @@
 """Continuous-batching serving engine (ISSUE 7): slot KV cache semantics,
 scheduler equivalence against the sequential ``generate`` oracle, the
 continuous-vs-static decode-iteration claim, the serve observability
-vocabulary (`analyze diff` directions, run-report section), and the bench
+vocabulary (`analyze diff` directions, run-report section), and the harness
 surface.  Everything here runs on this container — the slot cache and the
 scheduler are plain GSPMD jit + host Python, no shard_map anywhere.
 """
 
 import json
-import os
-import subprocess
-import sys
-from pathlib import Path
 
 import numpy as np
 import pytest
@@ -1204,7 +1200,7 @@ def test_harness_serve_kv_dtype_e2e():
     assert summary["serve"]["completed"] == 4
 
 
-# --------------------------------------------------------- harness + bench
+# ----------------------------------------------- harness (run() in process)
 
 
 def test_harness_serve_validation_pre_train():
@@ -1313,76 +1309,6 @@ def test_harness_serve_validation_round10_flags():
         run(ExperimentConfig(**base, serve_prefix_block=0))
     with pytest.raises(ValueError, match="max_len"):
         run(ExperimentConfig(**base, serve_shared_prefix=1024))
-
-
-# round 20 fast-lane repair: this is the ONE bench-subprocess smoke
-# kept fast repo-wide (cheapest of the three); the --stream and sweep
-# smokes ride the slow lane
-@pytest.mark.parametrize("stream", [
-    False, pytest.param(True, marks=pytest.mark.slow)])
-def test_bench_serve_smoke_emits_json(stream):
-    """`bench.py --serve` must emit ONE parsable JSON line with real
-    serve keys — the serving bench harness cannot silently rot.  The
-    --stream variant additionally counts per-token streaming deliveries,
-    PER WINDOW (regression: the counter once aggregated across both modes
-    and every repeat)."""
-    repo = Path(__file__).resolve().parents[1]
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
-               BENCH_SERVE_HIDDEN="32", BENCH_SERVE_LAYERS="1",
-               BENCH_SERVE_HEADS="2", BENCH_SERVE_FFN="64",
-               BENCH_SERVE_VOCAB="64", BENCH_SERVE_PROMPT_LEN="6",
-               # arrivals ~0.2 s apart: the subprocess may see an 8-way
-               # CPU platform (slots round 2→8), and a simultaneous burst
-               # into ≥N slots admits cold — pool hits need later
-               # requests to ARRIVE after an earlier prefill pooled the
-               # shared blocks
-               BENCH_SERVE_MAX_NEW="6", BENCH_SERVE_SLOTS="2",
-               BENCH_SERVE_REQUESTS="4", BENCH_SERVE_RATE="5",
-               BENCH_SERVE_REPEATS="1",
-               BENCH_SERVE_PREFILL_CHUNK="2",
-               BENCH_SERVE_PREFIX_CACHE="8",
-               BENCH_SERVE_PREFIX_BLOCK="2",
-               BENCH_SERVE_SHARED_PREFIX="4",
-               BENCH_SERVE_LONG_EVERY="2")
-    cmd = [sys.executable, str(repo / "bench.py"), "--serve"]
-    if stream:
-        cmd.append("--stream")
-    proc = subprocess.run(
-        cmd, capture_output=True, text=True, timeout=540, env=env,
-        cwd=str(repo))
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    payload = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert payload["metric"] == "gpt_serve_requests_per_sec_per_chip"
-    for key in ("serve_requests_per_sec_per_chip", "serve_ttft_p50_s",
-                "serve_ttft_p95_s", "serve_itl_p50_s", "serve_itl_p95_s",
-                "serve_prefill_tokens_per_sec",
-                "serve_decode_tokens_per_sec"):
-        assert payload[key] is not None and payload[key] >= 0, key
-    assert payload["value"] == pytest.approx(
-        payload["serve_requests_per_sec_per_chip"], rel=1e-3)
-    # round 10: the shared-prefix workload hits the pool, and the
-    # monolithic same-trace comparison rode the line
-    assert payload["serve_prefix_cache_hit_rate"] > 0
-    assert payload["monolithic_itl_p95_s"] is not None
-    assert payload["monolithic_ttft_p50_s"] is not None
-    assert payload["config"]["prefill_chunk"] == 2
-    assert payload["config"]["shared_prefix"] == 4
-    # the static baseline rode the same arrival trace; the iteration
-    # invariant is program-for-program (monolithic continuous vs static
-    # — the chunked window legitimately runs MORE, smaller iterations)
-    assert payload["static_decode_iterations"] >= \
-        payload["monolithic_decode_iterations"]
-    assert payload["continuous_vs_static"] is not None
-    assert payload["jax_version"]
-    assert payload["stream"] is stream
-    if stream:
-        # one window's deliveries (repeats=1): ≥ one token per request,
-        # not the both-modes × all-repeats aggregate
-        assert payload["tokens_delivered"] >= payload["serve_completed"]
-    # slots round up to a multiple of the data axis (the test harness env
-    # may expose a multi-device CPU platform to the subprocess)
-    assert payload["config"]["slots"] % payload["n_devices"] == 0
-    assert payload["config"]["slots"] >= 2
 
 
 def test_native_pipeline_rejects_lm_labels():

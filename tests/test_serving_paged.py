@@ -6,15 +6,9 @@ int8 composed, mesh-sharded variant), the zero-copy prefix ledger
 (pool stores each shared prefix exactly once), copy-on-write isolation,
 block-exhaustion admission (``can_admit`` deferral + the scheduler's
 ``serve_kv_block_deferrals``), honest ``kv_bytes_per_slot``, the
-round-16 ``analyze diff`` gates, and the harness/bench surface.
+round-16 ``analyze diff`` gates, and the harness surface.
 Everything runs on this container — Pallas interpret mode on CPU.
 """
-
-import json
-import os
-import subprocess
-import sys
-from pathlib import Path
 
 import numpy as np
 import pytest
@@ -535,7 +529,7 @@ def test_value_direction_round16_pins():
          "unit": "requests/sec/chip"}) == "higher"
 
 
-# ----------------------------------------------------------- harness + bench
+# ----------------------------------------------- harness (run() in process)
 
 
 def _lm_fn(batch_size, type="train", **kw):
@@ -592,38 +586,3 @@ def test_harness_round16_flag_validation():
         run(ExperimentConfig(**base, serve_kv_layout="paged",
                              serve_prefix_cache=8, serve_paged_block=8,
                              serve_prefix_block=4))
-
-
-@pytest.mark.slow
-def test_bench_serve_smoke_paged():
-    """`bench.py --serve` with BENCH_SERVE_KV_LAYOUT=paged: one parsable
-    JSON line carrying the paged-vs-monolithic same-trace ITL ratio,
-    the paged pool keys, and the zero-copy ledger."""
-    repo = Path(__file__).resolve().parents[1]
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
-               BENCH_SERVE_HIDDEN="32", BENCH_SERVE_LAYERS="1",
-               BENCH_SERVE_HEADS="2", BENCH_SERVE_FFN="64",
-               BENCH_SERVE_VOCAB="64", BENCH_SERVE_PROMPT_LEN="6",
-               BENCH_SERVE_MAX_NEW="6", BENCH_SERVE_SLOTS="2",
-               BENCH_SERVE_REQUESTS="4", BENCH_SERVE_RATE="5",
-               BENCH_SERVE_REPEATS="1",
-               BENCH_SERVE_PREFILL_CHUNK="2",
-               BENCH_SERVE_PREFIX_CACHE="8",
-               BENCH_SERVE_PREFIX_BLOCK="2",
-               BENCH_SERVE_SHARED_PREFIX="4",
-               BENCH_SERVE_LONG_EVERY="2",
-               BENCH_SERVE_KV_LAYOUT="paged")
-    proc = subprocess.run(
-        [sys.executable, str(repo / "bench.py"), "--serve"],
-        capture_output=True, text=True, timeout=540, env=env,
-        cwd=str(repo))
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    payload = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert payload["metric"] == "gpt_serve_requests_per_sec_per_chip"
-    assert payload["serve_kv_layout"] == "paged"
-    assert payload["config"]["kv_layout"] == "paged"
-    assert payload["paged_vs_monolithic_itl_p95"] > 0
-    assert payload["serve_kv_blocks_in_use"] is not None
-    assert payload["serve_kv_block_utilization"] is not None
-    assert payload["paged"]["zero_copy_hits"] >= 0
-    assert payload["serve_prefix_zero_copy_hit_rate"] is not None

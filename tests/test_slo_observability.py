@@ -11,10 +11,7 @@ import json
 import math
 import os
 import signal
-import subprocess
-import sys
 import threading
-from pathlib import Path
 
 import numpy as np
 import pytest
@@ -630,51 +627,7 @@ def test_load_report_flattens_goodput_keys(tmp_path):
     assert flat["serve_shed_rate"] == 0.0
 
 
-# ------------------------------------------------------------- bench sweep
-
-@pytest.mark.slow    # round 20 fast-lane repair: the sweep ladder is
-# a multi-window subprocess; CI's overload smoke covers the surface
-def test_bench_serve_sweep_smoke_emits_json(tmp_path):
-    """bench --serve --sweep smoke: the arrival-rate ladder runs, the
-    line carries serve_max_goodput_under_slo + the knee + the overload
-    window's accounting, and the artifact self-diffs exit 0 with the new
-    gates compared."""
-    env = dict(os.environ)
-    env.update(JAX_PLATFORMS="cpu",
-               BENCH_SERVE_HIDDEN="32", BENCH_SERVE_LAYERS="1",
-               BENCH_SERVE_HEADS="2", BENCH_SERVE_FFN="64",
-               BENCH_SERVE_VOCAB="128", BENCH_SERVE_PROMPT_LEN="8",
-               BENCH_SERVE_MAX_NEW="4", BENCH_SERVE_SLOTS="2",
-               BENCH_SERVE_REQUESTS="6", BENCH_SERVE_RATE="20",
-               BENCH_SERVE_SWEEP_POINTS="2",
-               BENCH_SERVE_PREFILL_CHUNK="4",
-               BENCH_SERVE_PREFIX_CACHE="16",
-               BENCH_SERVE_PREFIX_BLOCK="4")
-    root = Path(__file__).resolve().parents[1]
-    r = subprocess.run(
-        [sys.executable, str(root / "bench.py"), "--serve", "--sweep"],
-        capture_output=True, text=True, env=env, timeout=900)
-    assert r.returncode == 0, r.stderr[-2000:]
-    line = json.loads(r.stdout.strip().splitlines()[-1])
-    assert line["metric"] == "gpt_serve_max_goodput_under_slo"
-    assert line["serve_max_goodput_under_slo"] > 0
-    assert line["serve_knee_rate_per_s"] > 0
-    assert len(line["sweep"]) >= 1
-    ov = line["overload"]
-    assert ov is not None
-    assert (ov["admitted"] + ov["shed_requests"]
-            + ov["unserved_requests"] == ov["offered"])
-    assert line["serve_overload_queue_wait_p99_s"] is not None
-    # self-diff exit 0 with the sweep gates among the compared metrics
-    from distributed_tensorflow_tpu.observability.analyze import (
-        diff_reports, load_report)
-
-    art = tmp_path / "sweep.json"
-    art.write_text(json.dumps(line))
-    d = diff_reports(load_report(art), load_report(art))
-    compared = {r["metric"] for r in d["unchanged"]}
-    assert "serve_max_goodput_under_slo" in compared
-    assert "serve_knee_rate_per_s" in compared
+# -------------------------------------------------------- shared percentile
 
 
 def test_exact_percentile_matches_scheduler_percentile():

@@ -12,10 +12,6 @@ plain GSPMD jit + host Python, like tests/test_serving.py.
 """
 
 import json
-import os
-import subprocess
-import sys
-from pathlib import Path
 
 import numpy as np
 import pytest
@@ -646,7 +642,7 @@ def test_load_report_flattens_round14_keys(tmp_path):
         ["serve_accept_rate"]
 
 
-# ----------------------------------------------------------- harness + bench
+# ----------------------------------------------- harness (run() in process)
 
 
 def _lm_fn(batch_size, type="train", **kw):
@@ -758,52 +754,3 @@ def test_harness_round14_flag_validation():
         parse_draft_config("vocab_size=8")
     with pytest.raises(ValueError, match="int"):
         parse_draft_config("hidden=big")
-
-
-@pytest.mark.slow
-def test_bench_serve_smoke_int8_and_draft():
-    """`bench.py --serve` with BENCH_SERVE_KV_DTYPE=int8 + a self draft:
-    one parsable JSON line carrying serve_kv_dtype /
-    serve_kv_bytes_per_slot, the same-trace model-dtype baseline with
-    the bytes ratio + greedy agreement, and the speculative ledger."""
-    repo = Path(__file__).resolve().parents[1]
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
-               BENCH_SERVE_HIDDEN="32", BENCH_SERVE_LAYERS="1",
-               BENCH_SERVE_HEADS="2", BENCH_SERVE_FFN="64",
-               BENCH_SERVE_VOCAB="64", BENCH_SERVE_PROMPT_LEN="6",
-               BENCH_SERVE_MAX_NEW="6", BENCH_SERVE_SLOTS="2",
-               BENCH_SERVE_REQUESTS="4", BENCH_SERVE_RATE="5",
-               BENCH_SERVE_REPEATS="1",
-               BENCH_SERVE_PREFILL_CHUNK="2",
-               BENCH_SERVE_PREFIX_CACHE="8",
-               BENCH_SERVE_PREFIX_BLOCK="2",
-               BENCH_SERVE_SHARED_PREFIX="4",
-               BENCH_SERVE_LONG_EVERY="2",
-               BENCH_SERVE_KV_DTYPE="int8",
-               BENCH_SERVE_DRAFT="self", BENCH_SERVE_DRAFT_K="2")
-    proc = subprocess.run(
-        [sys.executable, str(repo / "bench.py"), "--serve"],
-        capture_output=True, text=True, timeout=540, env=env,
-        cwd=str(repo))
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    payload = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert payload["metric"] == "gpt_serve_requests_per_sec_per_chip"
-    assert payload["serve_kv_dtype"] == "int8"
-    assert payload["serve_kv_bytes_per_slot"] > 0
-    assert payload["config"]["kv_dtype"] == "int8"
-    assert payload["config"]["draft"] == "self"
-    spec = payload["speculative"]
-    assert (spec["accepted_tokens"] + spec["rejected_tokens"]
-            == spec["proposed_tokens"])
-    # draft == target → acceptance is near-total; not asserted exactly
-    # 1.0 because the target verifies over the INT8 table while the
-    # draft proposes from its full-precision view (tolerance-based)
-    assert payload["serve_accept_rate"] > 0
-    cmp_line = payload["kv_baseline"]
-    assert cmp_line is not None
-    assert cmp_line["kv_dtype"] == "bfloat16"
-    # int8 payload + scales vs the bf16 table on the SAME trace: the
-    # bytes must shrink, and the greedy streams must agree (head_dim 16
-    # → ratio (1 + 4/16)/2 = 0.625)
-    assert cmp_line["kv_bytes_ratio"] == pytest.approx(0.625, rel=1e-3)
-    assert cmp_line["greedy_token_match"] == 1.0

@@ -17,10 +17,6 @@ availability and runs wherever the engine layer itself runs.
 """
 
 import json
-import os
-import subprocess
-import sys
-from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -511,40 +507,3 @@ def test_mnist_cnn_sync_parity_steps_per_call(mesh8):
     assert r1["steps"] == r8["steps"] == 12
     assert r8["steps_per_call"] == 8  # metrics sink never downshifts
     assert traj1 == traj8
-
-
-# ------------------------------------------------------- bench harness smoke
-
-# round 20 fast-lane repair: heaviest bench-subprocess smoke (~33s)
-# rides the slow lane; test_serving's bench --serve smoke keeps the
-# one fast bench-subprocess representative
-@pytest.mark.slow
-def test_bench_stream_smoke_emits_json():
-    """`bench.py --stream` must emit ONE parsable JSON line — the bench
-    harness cannot silently rot."""
-    repo = Path(__file__).resolve().parents[1]
-    env = dict(os.environ, JAX_PLATFORMS="cpu", BENCH_PER_CHIP_BATCH="8")
-    proc = subprocess.run(
-        [sys.executable, str(repo / "bench.py"), "--stream", "--steps", "2",
-         "--health", "on", "--checkpoint-every", "1"],
-        capture_output=True, text=True, timeout=540, env=env, cwd=str(repo))
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    payload = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert payload["metric"] == "mnist_cnn_stream_examples_per_sec"
-    # telemetry riders: steady-state step-time percentiles (compile
-    # chunk excluded) and the prefetch starvation counter of the
-    # shipped Trainer.fit path
-    assert payload["step_time_p50"] > 0
-    assert payload["step_time_p95"] >= payload["step_time_p50"]
-    assert payload["prefetch_starvation"] >= 0
-    assert payload["trainer_examples_per_sec"] > 0
-    # --health on riders: the fit result's health summary surfaces on
-    # the bench line (max update ratio + anomaly steps)
-    assert payload["health_max_update_ratio"] > 0
-    assert payload["health_anomaly_steps"] == []
-    # --checkpoint-every riders: the blocked-vs-overlapped checkpoint
-    # seconds split of the async-checkpointed Trainer window
-    assert payload["checkpoint_every"] == 1
-    assert payload["checkpoint_async"] is True
-    assert payload["checkpoint_wait_s"] >= 0
-    assert payload["checkpoint_overlapped_s"] >= 0
