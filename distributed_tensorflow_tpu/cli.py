@@ -227,8 +227,8 @@ def build_parser() -> argparse.ArgumentParser:
                         "tokens, at most one chunk per decode iteration, "
                         "so a long prompt cannot stall live slots for "
                         "more than one chunk per token.  0 (default) = "
-                        "monolithic prefill (the pre-round-10 programs, "
-                        "byte-identical).  Greedy tokens are identical "
+                        "monolithic prefill (one block program a "
+                        "bucket).  Greedy tokens are identical "
                         "either way; TTFT stays arrival→first-token")
     p.add_argument("--serve-prefix-cache", type=int, default=0,
                    metavar="BLOCKS",

@@ -161,7 +161,8 @@ class CausalSelfAttention(nn.Module):
                                # kernels cannot be GSPMD-partitioned)
 
     @nn.compact
-    def __call__(self, x, pos=None, block_tables=None):
+    def __call__(self, x, pos=None, block_tables=None,
+                 prefill: bool = False):
         head_dim = self.hidden // self.heads
         tp = self.partition_model
         if self.rope and pos is None:
@@ -241,8 +242,9 @@ class CausalSelfAttention(nn.Module):
                 # prefill) or start PAST zero over externally-written KV
                 # (a prefix-cache hit restores blocks 0..p-1 and resumes
                 # at p) — the per-token math is identical either way,
-                # which is what makes chunked admission bitwise equal to
-                # monolithic admission (tests/test_serving.py).
+                # which is what makes chunked admission bitwise equal at
+                # every chunk budget, and equal in tokens to the block
+                # prefill below (tests/test_serving.py).
                 # TOKEN-BLOCK CONTRACT (speculative verify): the same
                 # mode also accepts a (B, L) block of L consecutive
                 # tokens per slot — all L K/V vectors are written into the
@@ -272,6 +274,14 @@ class CausalSelfAttention(nn.Module):
                 # the fused program identical to k calls of the
                 # single-step program (the bitwise-parity pin in
                 # tests/test_serving_multistep.py).
+                # BLOCK-PREFILL CONTRACT (``prefill=True``;
+                # serving/kv_cache.py ``insert``): the (1, L) block is a
+                # padded prompt from position 0, so nothing valid lies in
+                # the table below it and the table is not read: the block
+                # attends causally to its own q/k/v, and its K/V (+ scales)
+                # land in the slot's rows [0, L) as one contiguous piece.
+                # Pad tokens sit after the prompt: no real query sees one,
+                # and their rows are beyond the slot's length.
                 if pos is None:
                     raise ValueError(
                         "decode_slots=True needs per-slot positions "
@@ -320,6 +330,21 @@ class CausalSelfAttention(nn.Module):
                         "cache", "value_scale", jnp.zeros,
                         (b, self.max_len, kvh), jnp.float32)
                 if not ready:
+                    out = dense_attention(q, widen(k), widen(v),
+                                          causal=True)
+                elif prefill:
+                    # L <= max_len always, so no drop rule is needed; not
+                    # ``write_slot_rows``, which on the v5e is a loop of
+                    # one dynamic-update-slice a row
+                    rows = [(ck, k), (cv, v)]
+                    if self.kv_quant:
+                        qk, sk = compression.int8_channel_encode(k)
+                        qv, sv = compression.int8_channel_encode(v)
+                        rows = [(ck, qk), (cv, qv), (ks, sk), (vs, sv)]
+                    for table, new in rows:
+                        table.value = lax.dynamic_update_slice_in_dim(
+                            table.value, new.astype(table.value.dtype), 0,
+                            axis=1)
                     out = dense_attention(q, widen(k), widen(v),
                                           causal=True)
                 elif x.shape[1] == 1 and not self.kv_quant:
@@ -442,8 +467,8 @@ class CausalSelfAttention(nn.Module):
         K/V vector into ``pool[bt[row, pos // blk], :, pos % blk]``; reads
         go fused (Pallas kernel) or unfused (gather + dense — bitwise
         the monolithic token-block branch's math over the gathered
-        table, which is what keeps paged prefill exactly equal to
-        monolithic prefill)."""
+        table, which is what keeps the paged chunk scan exactly equal to
+        the monolithic one)."""
         blk = self.paged_block
         if self.max_len % blk:
             raise ValueError(
@@ -561,7 +586,8 @@ class GPTBlock(nn.Module):
     paged_mesh: Any = None       # serving mesh for the fused read
 
     @nn.compact
-    def __call__(self, x, train: bool = False, pos=None, block_tables=None):
+    def __call__(self, x, train: bool = False, pos=None, block_tables=None,
+                 prefill: bool = False):
         tp = self.partition_model
         y = CausalSelfAttention(self.hidden, self.heads, self.attention_impl,
                                 self.seq_axis, tp, self.decode, self.max_len,
@@ -573,7 +599,7 @@ class GPTBlock(nn.Module):
                                 paged_fused=self.paged_fused,
                                 paged_mesh=self.paged_mesh)(
                                     nn.LayerNorm(dtype=self.dtype)(x), pos,
-                                    block_tables)
+                                    block_tables, prefill)
         y = nn.Dropout(self.dropout_rate, deterministic=not train)(y)
         x = x + y
         y = nn.LayerNorm(dtype=self.dtype)(x)
@@ -670,8 +696,10 @@ class GPTLM(nn.Module):
                                  # 'data' axis shards the slots)
 
     causal_lm = True  # read by engines/harness to select the LM data layout
-    prefill_form = "scan"   # SlotKVCache prefills by a lax.scan of the
-                            # one-token slot-decode step
+    resumable_step = True   # the slot-decode step takes any start position
+                            # over per-head K/V rows: what SlotKVCache's
+                            # chunk, pool, paged, int8, multi-step, verify
+                            # and handoff programs are built from
 
     def slot_decode_clone(self, *, partition_model: bool = False,
                           kv_quant: bool = False, **paged) -> "GPTLM":
@@ -684,10 +712,19 @@ class GPTLM(nn.Module):
 
     @nn.compact
     def __call__(self, token_ids, train: bool = False, positions=None,
-                 block_tables=None):
+                 block_tables=None, prompt_len=None):
         seq_parallel = self.attention_impl in ("ring", "ring_flash",
                                                "ulysses", "ulysses_flash")
         lq = token_ids.shape[1]
+        # a slot prefill (serving/kv_cache.py ``insert``): the (1, L) block
+        # is a padded prompt from position 0 whose first ``prompt_len``
+        # tokens are real — the attention layers' block-prefill contract,
+        # and logits for the last real position alone
+        prefill = prompt_len is not None
+        if prefill and (not self.decode_slots or self.paged_blocks):
+            raise ValueError(
+                "prompt_len marks a slot prefill from position 0: it "
+                "requires decode_slots=True and the monolithic table")
         if self.decode_slots and not self.decode:
             raise ValueError("decode_slots=True requires decode=True "
                              "(slot serving is a KV-cache decode mode)")
@@ -774,7 +811,8 @@ class GPTLM(nn.Module):
             x = x + nn.Embed(self.max_len, self.hidden, dtype=self.dtype,
                              name="pos_embed")(pos)
         x = nn.Dropout(self.dropout_rate, deterministic=not train)(x)
-        # remat: train (arg 2) is a static python bool; x and pos trace.
+        # remat: train (arg 2) and prefill (arg 5) are static python bools;
+        # x and pos trace.
         # The wrapped class is instantiated with an explicit name pinned to
         # the unwrapped auto-name ("GPTBlock_{i}") — nn.remat renames the
         # class, and flax derives both the param-tree path AND the init RNG
@@ -788,8 +826,8 @@ class GPTLM(nn.Module):
                 "intermediates (aux_loss/z_loss/overflow) would be re-sown "
                 "during backward recompute, double-counting the balance "
                 "losses; train MoE blocks without --remat")
-        block_cls = (nn.remat(GPTBlock, static_argnums=(2,)) if self.remat
-                     else GPTBlock)
+        block_cls = (nn.remat(GPTBlock, static_argnums=(2, 5))
+                     if self.remat else GPTBlock)
         for i in range(self.layers):
             # slot decode threads pos regardless of rope: the attention
             # layer needs the per-slot write index, not just the rotation
@@ -808,7 +846,10 @@ class GPTLM(nn.Module):
                           name=f"GPTBlock_{i}")(
                               x, train,
                               pos if (rope or self.decode_slots) else None,
-                              block_tables)
+                              block_tables, prefill)
+        if prefill:     # the one position whose logits sample a token
+            x = jnp.take_along_axis(
+                x, (prompt_len - 1)[:, None, None].astype(jnp.int32), axis=1)
         x = nn.LayerNorm(dtype=self.dtype)(x)
         if self.tie_embeddings:
             # tied head: contraction against the (possibly vocab-sharded)

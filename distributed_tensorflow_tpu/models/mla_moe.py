@@ -305,7 +305,10 @@ class LatentMoELM(nn.Module):
                                  # caller's
 
     causal_lm = True
-    prefill_form = "batched"     # SlotKVCache: one expanded call a bucket
+    resumable_step = False       # the one-token step reads every expert a
+                                 # token and the table holds latents:
+                                 # SlotKVCache builds no program that
+                                 # resumes a prompt or moves per-head K/V
 
     @property
     def expert_layers(self) -> int:

@@ -23,18 +23,21 @@ Device-side contract (everything else lives in serving/scheduler.py):
   vectors in, next tokens out, cache donated through;
 * ``insert`` is a jitted prefill against only that slot's cache slice
   (batch 1), written back once — compiled once per padded length bucket
-  (powers of two), so steady-state admission never triggers XLA.  Its
-  form is the model class's (``prefill_form``): ``"scan"`` feeds the
-  prompt through the SAME per-token decode math inside a ``lax.scan``
-  over the padded prompt (models/gpt.py); ``"batched"`` is ONE call of
-  the model over the whole padded prompt (models/mla_moe.py, whose
-  one-token step would read every expert a prompt token);
+  (powers of two), so steady-state admission never triggers XLA.  It is
+  ONE call of the served module over the whole padded prompt from
+  position 0 (``prompt_len`` marks it: the block attends within itself,
+  writes the slot's rows in one piece and returns logits for the last
+  real position only), for models/gpt.py and models/mla_moe.py alike: a
+  prompt token then costs its FLOPs, where a scan of the one-token step
+  read every weight (and, for mla_moe, every expert) once a token;
 * ``begin_insert``/``prefill_chunk`` split that admission into fixed
-  token-budget chunks (Sarathi-Serve, arXiv:2403.02310): each chunk resumes
-  at the slot's fill position (the chunk program takes a traced ``start``,
-  so ONE compile per power-of-two chunk-length bucket serves every resume
-  point), and the scheduler interleaves at most one chunk per decode
-  iteration — live slots keep emitting tokens while a long prompt fills;
+  token-budget chunks (Sarathi-Serve, arXiv:2403.02310): each chunk feeds
+  its tokens through the SAME per-token decode math inside a ``lax.scan``
+  and resumes at the slot's fill position (the chunk program takes a
+  traced ``start``, so ONE compile per power-of-two chunk-length bucket
+  serves every resume point), and the scheduler interleaves at most one
+  chunk per decode iteration — live slots keep emitting tokens while a
+  long prompt fills;
 * the optional **prefix pool** (vLLM PagedAttention's block-granular KV
   reuse, arXiv:2309.06180) caches block-aligned prompt-prefix KV keyed by
   the exact token bytes of the prefix: on admission the longest cached
@@ -42,13 +45,17 @@ Device-side contract (everything else lives in serving/scheduler.py):
   block, with hit/miss/evict accounting and bounded LRU eviction.
 
 Greedy slot decode is token-identical to the sequential ``generate``
-sampler per request (tests/test_serving.py): prefill-at-position-t and
-decode-at-cursor-t run the same dense cache attention with the same
-length-driven validity mask.  Chunked prefill is bitwise-identical to
-monolithic prefill (each token's forward depends only on cache positions
-below its own, all written by earlier chunks), and a prefix-cache hit is
-bitwise-identical to recomputation (the pooled KV is a byte copy of what
-the cold prefill would write).
+sampler per request (tests/test_serving.py): the chunk scan at position t
+and decode-at-cursor-t run the same dense cache attention with the same
+length-driven validity mask, and the block prefill computes the same
+causal attention over the prompt in one piece.  Chunked prefill is
+bitwise-identical across chunk budgets (each token's forward depends only
+on cache positions below its own, all written by earlier chunks), and a
+prefix-cache hit is bitwise-identical to recomputation (the pooled KV is
+a byte copy of what the cold chunk scan would write).  Against the block
+prefill of ``insert`` the chunk scan agrees in TOKENS; the table rows the
+two write agree to float32 rounding, not bitwise (one matrix product over
+L rows where the scan made L products over one).
 
 Round 14 adds the two raw-decode-speed levers (ROADMAP item 3):
 ``verify_block``/``commit_block``/``rewind`` — the speculative-decode
@@ -116,11 +123,13 @@ class SlotKVCache:
     exactly like ``generate`` clones into cursor-decode mode: dense cache
     attention, dropout off, Megatron TP layout kept when ``mesh`` has a
     'model' axis and the model was partitioned.  The table's leaves come
-    from that module's own abstract init.  What is built for the scan
-    prefill only — the paged layout, chunk resume, the prefix pool, int8
-    storage, multi-step dispatch, speculative verify, KV handoff — raises
-    ``NotImplementedError`` by name for a model whose prefill is batched.  ``params`` may be a TP engine's committed TrainState
-    params (used in place) or host/single-device params (replicated).
+    from that module's own abstract init.  What is built from a resumable
+    one-token step over per-head K/V rows — the paged layout, chunk
+    resume, the prefix pool, int8 storage, multi-step dispatch,
+    speculative verify, KV handoff — raises ``NotImplementedError`` by
+    name for a model without one (``resumable_step`` on the model class).
+    ``params`` may be a TP engine's committed TrainState params (used in
+    place) or host/single-device params (replicated).
 
     Host-side bookkeeping (`lengths`, `active`, `tokens`) lives on numpy:
     the scheduler owns admission/eviction and the decode step receives the
@@ -184,7 +193,10 @@ class SlotKVCache:
         keep_tp = (mesh is not None
                    and getattr(model, "partition_model", False)
                    and meshlib.MODEL_AXIS in mesh.axis_names)
-        self.prefill_form = model.prefill_form
+        self.resumable_step = model.resumable_step
+        # what ``insert`` runs (the ``prefill`` span's ``form``): one call
+        # over the padded prompt, or the chunk scan the prefix pool needs
+        self.prefill_form = "scan" if prefix_cache_blocks else "batched"
         if prefix_cache_blocks:
             self._scan_model_only("the prefix pool")
         self.dm = model.slot_decode_clone(partition_model=keep_tp,
@@ -257,8 +269,8 @@ class SlotKVCache:
         # scheduler reads deltas of this for the prefill/decode token
         # split and the VirtualClock interference model
         self.prefill_tokens_computed = 0
-        # scan steps the prefill programs ran for them: each call's
-        # bucket, pad steps included (the ``prefill`` span's padded_len)
+        # positions the prefill programs ran for them: each call's
+        # bucket, pad tokens included (the ``prefill`` span's padded_len)
         self.prefill_tokens_padded = 0
 
         # host-observed seconds inside the compiled programs, per phase
@@ -309,12 +321,13 @@ class SlotKVCache:
         self._inflight = np.zeros(self.slots, np.int32)
 
     def _scan_model_only(self, feature: str) -> None:
-        """What rests on the one-token scan prefill and per-head K/V
-        leaves is refused by name for a model whose prefill is batched."""
-        if self.prefill_form != "scan":
+        """What rests on a scan of the one-token step from any start
+        position and on per-head K/V leaves is refused by name for a model
+        that has no such step."""
+        if not self.resumable_step:
             raise NotImplementedError(
-                f"{feature} is not implemented for a model with a "
-                f"{self.prefill_form} prefill (models/mla_moe.py): the "
+                f"{feature} is not implemented for a model without a "
+                f"resumable one-token step (models/mla_moe.py): the "
                 f"monolithic table with insert/advance/evict is")
 
     def _place_params(self, params):
@@ -451,22 +464,21 @@ class SlotKVCache:
     def _prefill(self, lpad: int):
         """Compiled prefill-insert for one padded prompt length.
 
-        Slices slot ``slot`` out of every cache leaf, scans the padded
-        prompt through the single-token slot-decode step (batch 1,
-        positions 0..lpad-1), writes the slice back, and samples the FIRST
-        generated token from the logits at the last REAL prompt position.
-        Steps past ``prompt_len`` write garbage K/V beyond the slot's
-        length — invisible under the length mask and overwritten as
-        decoding advances (the same argument that makes free-slot
-        writes safe).  The decode step is untouched: admission never
-        recompiles it."""
+        Slices slot ``slot`` out of every cache leaf and makes ONE call of
+        the served module over the padded prompt from position 0 (batch 1;
+        ``prompt_len`` marks the call: the block attends within itself and
+        writes the slot's rows ``[0, lpad)`` in one piece), writes the
+        slice back, and samples the FIRST generated token from the logits
+        the module returns, which are those of the last REAL prompt
+        position alone.  Pad positions past ``prompt_len`` write garbage
+        K/V beyond the slot's length — invisible under the length mask
+        and overwritten as decoding advances (the same argument that makes
+        free-slot writes safe); rows at or past ``lpad`` are left as they
+        were.  The decode step is untouched: admission never recompiles
+        it."""
         dm = self.dm
 
         def batched(params, cache, slot, tokens, prompt_len, rng):
-            """``prefill_form == "batched"``: ONE call of the model over
-            the padded prompt from position 0 (it attends within the block
-            and writes the slot's sub-table in one piece); logits come
-            back for the last real position only."""
             sub = jax.tree.map(
                 lambda t: lax.dynamic_slice_in_dim(t, slot, 1, 0), cache)
             logits, upd = dm.apply(
@@ -480,41 +492,19 @@ class SlotKVCache:
                     full, s, slot, 0), cache, upd["cache"])
             return cache, first.astype(tokens.dtype)
 
-        if self.prefill_form == "batched":
-            return self._jit(batched, f"kv_prefill_batched_l{lpad}",
-                             donate_argnums=1)
-
-        def prefill(params, cache, slot, tokens, prompt_len, rng):
-            sub = jax.tree.map(
-                lambda t: lax.dynamic_slice_in_dim(t, slot, 1, 0), cache)
-
-            def body(c, xs):
-                tok, t = xs
-                logits, upd = dm.apply(
-                    {"params": params, "cache": c}, tok[None, None],
-                    train=False, positions=t[None, None],
-                    mutable=["cache"])
-                return upd["cache"], logits[0, -1]
-
-            sub, all_logits = lax.scan(
-                body, sub, (tokens, jnp.arange(lpad, dtype=jnp.int32)))
-            last = jnp.take(all_logits, prompt_len - 1, axis=0)
-            first = self._sample(last[None, :], rng)[0]
-            cache = jax.tree.map(
-                lambda full, s: lax.dynamic_update_slice_in_dim(
-                    full, s, slot, 0), cache, sub)
-            return cache, first.astype(tokens.dtype)
-
-        return self._jit(prefill, f"kv_prefill_l{lpad}", donate_argnums=1)
+        return self._jit(batched, f"kv_prefill_batched_l{lpad}",
+                         donate_argnums=1)
 
     def _chunk(self, lpad: int):
         """Compiled chunk-resumable prefill for one padded CHUNK length.
 
-        Like ``_prefill`` but resumes at a traced ``start`` position
-        (positions ``start .. start+lpad-1``), so one compile per
-        power-of-two chunk bucket serves every resume point — a long
-        prompt's admission becomes several short scans the scheduler can
-        interleave with decode iterations.  ``n_valid`` is the chunk's
+        Where ``_prefill`` makes one call over a block from position 0,
+        this scans the chunk through the single-token slot-decode step
+        (batch 1) from a traced ``start`` position (positions
+        ``start .. start+lpad-1``), so one compile per power-of-two chunk
+        bucket serves every resume point — a long prompt's admission
+        becomes several short scans the scheduler can interleave with
+        decode iterations.  ``n_valid`` is the chunk's
         real token count; the sampled token (logits at the last valid
         position) only matters on the FINAL chunk — it is the request's
         first generated token, exactly as in the monolithic prefill.
@@ -757,7 +747,8 @@ class SlotKVCache:
         With the prefix pool enabled, admission routes through the
         chunk-resumable program (``begin_insert`` + one full-remainder
         ``prefill_chunk``) so prefill can start at the first uncached
-        block; with the pool off, this is the byte-identical PR 7 path.
+        block; with the pool off, it is the one-call block program of
+        ``_prefill``.
         """
         if self.prefix_cache_blocks:
             slot, _ = self.begin_insert(prompt, slot)
@@ -1606,7 +1597,8 @@ class PagedSlotKVCache(SlotKVCache):
         keep_tp = (mesh is not None
                    and getattr(model, "partition_model", False)
                    and meshlib.MODEL_AXIS in mesh.axis_names)
-        self.prefill_form = model.prefill_form
+        self.resumable_step = model.resumable_step
+        self.prefill_form = "scan"      # insert goes through _chunk
         self._scan_model_only("the paged layout")
         # fused clone for the decode/verify hot ops, gather clone for the
         # prefill scan (bitwise-monolithic math) — same params, same

@@ -789,3 +789,83 @@ def test_harness_sample_validation():
                              **{**base, "model_args": {
                                  "hidden": 32, "layers": 1, "heads": 2,
                                  "ffn": 64, "max_len": 128}}))
+
+
+# ------------------------------------------- slot prefill from position 0
+
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+@pytest.mark.parametrize("kv_quant", [False, True], ids=["float", "int8"])
+def test_slot_prefill_block_is_the_training_forward_at_one_row(remat,
+                                                               kv_quant):
+    """``prompt_len`` in slot-decode mode (the block prefill of
+    serving/kv_cache.py ``insert``): ONE call over a padded prompt from
+    position 0 returns logits for the last real position alone, equal to
+    the training-mode forward's row there (pad tokens sit after it), and
+    leaves every layer's K/V of the whole block in the table's rows
+    ``[0, L)`` — the rows the one-token step writes one call a token —
+    with the rows past the block as they were."""
+    import jax.numpy as jnp
+
+    lpad, lp, max_len = 8, 5, 16
+    model = create_model("gpt", num_classes=64, hidden=32, layers=2, heads=2,
+                         ffn=64, max_len=max_len, dropout_rate=0.0,
+                         remat=remat)
+    toks = jnp.asarray(np.random.default_rng(30).integers(0, 64, (1, lpad)),
+                       jnp.int32)
+    params = model.init(jax.random.key(0), toks, train=False)["params"]
+    dm = model.slot_decode_clone(kv_quant=kv_quant)
+    pos = jnp.arange(lpad, dtype=jnp.int32)[None, :]
+    shapes = jax.eval_shape(
+        lambda: dm.init(jax.random.key(0), toks[:, :1], train=False,
+                        positions=pos[:, :1]))["cache"]
+    before = jax.tree.map(lambda s: jnp.full(s.shape, 3, s.dtype), shapes)
+
+    logits, upd = dm.apply({"params": params, "cache": before}, toks,
+                           train=False, positions=pos,
+                           prompt_len=jnp.asarray([lp], jnp.int32),
+                           mutable=["cache"])
+    assert logits.shape == (1, 1, 64)
+    full = model.apply({"params": params}, toks, train=False)
+    np.testing.assert_allclose(logits[0, 0], full[0, lp - 1],
+                               atol=1e-5, rtol=1e-5)
+
+    # the one-token step over the same tokens, a call a position
+    cache = before
+    for t in range(lpad):
+        _, step = dm.apply({"params": params, "cache": cache},
+                           toks[:, t:t + 1], train=False,
+                           positions=pos[:, t:t + 1], mutable=["cache"])
+        cache = step["cache"]
+    for b, got, want in zip(*map(jax.tree.leaves,
+                                 (before, upd["cache"], cache))):
+        assert got.dtype == b.dtype and got.shape == b.shape
+        got, want = np.asarray(got), np.asarray(want)
+        if got.dtype == np.int8:
+            # past the first layer the step attended to DEQUANTIZED keys
+            # where the block sees its own unquantized ones: a few codes
+            # of 127, not rounding
+            assert np.abs(got[:, :lpad].astype(int)
+                          - want[:, :lpad].astype(int)).max() <= 4
+        else:
+            tol = 0.03 if kv_quant else 1e-5        # int8: the scales
+            np.testing.assert_allclose(got[:, :lpad], want[:, :lpad],
+                                       atol=tol, rtol=tol)
+        np.testing.assert_array_equal(got[:, lpad:], np.asarray(b)[:, lpad:])
+
+
+@pytest.mark.parametrize("clone, match", [
+    (dict(), "decode_slots"),
+    (dict(decode=True), "decode_slots"),
+    (dict(decode=True, decode_slots=True, paged_blocks=5, paged_block=8),
+     "monolithic"),
+], ids=["training", "cursor_decode", "paged"])
+def test_prompt_len_is_refused_outside_the_monolithic_slot_mode(clone, match):
+    import jax.numpy as jnp
+
+    model = tiny_gpt(max_len=16).clone(**clone)
+    toks = jnp.zeros((1, 8), jnp.int32)
+    with pytest.raises(ValueError, match=match):
+        jax.eval_shape(lambda: model.init(
+            jax.random.key(0), toks, train=False,
+            positions=toks if model.decode_slots else None,
+            prompt_len=jnp.asarray([3], jnp.int32)))

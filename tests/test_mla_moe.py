@@ -293,7 +293,7 @@ def test_analyze_serve_prints_the_tables_counters(model, weights, tmp_path):
         in render_waterfall_text(wf)
 
 
-def test_a_gpt_window_reports_a_scan_prefill_and_no_routing():
+def test_a_gpt_window_reports_a_batched_prefill_and_no_routing():
     gpt = create_model("gpt", vocab_size=64, hidden=32, layers=1, heads=2,
                        ffn=64, max_len=32)
     params = gpt.init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32),
@@ -304,7 +304,7 @@ def test_a_gpt_window_reports_a_scan_prefill_and_no_routing():
         arrival_s=0.0)])
     window = recorder().records(root="serve_run")
     assert [r["attrs"]["form"] for r in window
-            if r["name"] == "prefill"] == ["scan"]
+            if r["name"] == "prefill"] == ["batched"]
     assert all("experts_touched" not in r["attrs"] for r in window)
     assert window[0]["attrs"]["expert_assignments"] == 0
     assert kv.last_routing is None
@@ -435,13 +435,17 @@ KEPT = ("while", "scatter", "dot_general", "dynamic_update_slice",
 
 @pytest.mark.parametrize("program, total, kept", [
     ("kv_decode_step", 407, (0, 4, 17, 0, 1, 2)),
-    ("kv_prefill_l8", 489, (1, 4, 17, 5, 7, 3)),
+    ("kv_prefill_batched_l8", 499, (0, 0, 17, 8, 5, 3)),
 ])
 def test_gpt_slot_programs_lower_to_what_they_did(program, total, kept):
     """The operation counts of the lowered programs of a tiny ``GPTLM``
-    (2 layers, 4 slots x 32), read off commit 27a7b5f before the table
-    learnt a second model: the step has no ``while``, the prefill is still
-    ONE ``while`` over the one-token step, neither returns anything new."""
+    (2 layers, 4 slots x 32).  The step's were read off commit 27a7b5f,
+    before the table learnt a second model, and have not moved since: no
+    ``while``, nothing new returned.  The prefill's are PR 30's block
+    program: no ``while`` over the prompt and no scatter (the scan of the
+    one-token step had one ``while``, 4 scatters and 489 operations), the
+    same 17 matrix products, and the block's K and V in 4 of the 8
+    ``dynamic_update_slice`` (the other 4 put the slot's leaves back)."""
     gpt = create_model("gpt", vocab_size=64, hidden=32, layers=2, heads=2,
                        ffn=64, max_len=32)
     params = gpt.init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32),
@@ -450,12 +454,13 @@ def test_gpt_slot_programs_lower_to_what_they_did(program, total, kept):
     kv._prefill(8)
     vec, key = jnp.zeros((4,), jnp.int32), jax.random.key(0)
     args = {"kv_decode_step": (vec, vec, vec.astype(bool), key),
-            "kv_prefill_l8": (jnp.int32(0), jnp.zeros((8,), jnp.int32),
-                              jnp.int32(3), key)}[program]
+            "kv_prefill_batched_l8": (jnp.int32(0),
+                                      jnp.zeros((8,), jnp.int32),
+                                      jnp.int32(3), key)}[program]
     ops = lowered_ops(kv, program, params, kv.cache, *args)
     assert sum(ops.values()) == total
     assert tuple(ops[f"stablehlo.{k}"] for k in KEPT) == kept
-    assert set(kv.programs) == {"kv_decode_step", "kv_prefill_l8"}
+    assert set(kv.programs) == {"kv_decode_step", "kv_prefill_batched_l8"}
 
 
 def test_the_new_models_programs_have_their_own_names(model, weights):

@@ -181,6 +181,135 @@ def test_chunked_prefill_programs_bucketed(model_params):
     assert kv.compiled_programs()["prefill_chunk_buckets"] == 2
 
 
+# ------------------------------------------------ block prefill (insert)
+
+# (max_len, prompt length, its bucket at the default floor of 8); a
+# max_len of 24 caps the 32 bucket, so that lpad == max_len
+BLOCK_CASES = {"bucket_edge": (32, 8, 8), "one_under": (32, 7, 8),
+               "one_token": (32, 1, 8), "capped_bucket": (24, 20, 24)}
+block_cases = pytest.mark.parametrize("max_len, lp, lpad",
+                                      BLOCK_CASES.values(), ids=BLOCK_CASES)
+
+
+def _block_case(max_len, lp):
+    """A model of its own ``max_len``, its params, a prompt of ``lp``."""
+    model = tiny_gpt(max_len=max_len)
+    params = model.init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32),
+                        train=False)["params"]
+    prompt = np.random.default_rng(lp).integers(0, 64, lp).astype(np.int32)
+    return model, params, prompt
+
+
+@block_cases
+def test_block_prefill_serves_the_oracles_tokens(max_len, lp, lpad):
+    """``insert`` is one call over the padded prompt: its first token and
+    the greedy continuation the decode step reads off the rows it wrote
+    are the sequential sampler's, at a bucket's edge, one under it, for a
+    one-token prompt and in the bucket that ``max_len`` caps."""
+    model, params, prompt = _block_case(max_len, lp)
+    kv = SlotKVCache(model, params, slots=2)
+    slot, first = kv.insert(prompt, slot=1)
+    assert kv.prefill_tokens_padded == lpad and kv.prefill_form == "batched"
+    toks = [first] + [int(kv.advance()[slot]) for _ in range(3)]
+    np.testing.assert_array_equal(_oracle(model, params, prompt, 4), toks)
+
+
+@block_cases
+def test_block_prefill_writes_the_rows_the_scan_writes(max_len, lp, lpad):
+    """Rows ``[0, lp)`` of every table leaf agree with what the scan of
+    the one-token step (the chunk program) writes, to float32 rounding;
+    rows at or past the bucket, and the other slots, are untouched."""
+    model, params, prompt = _block_case(max_len, lp)
+    tables = {}
+    for form in ("block", "scan"):
+        kv = SlotKVCache(model, params, slots=2)
+        kv.cache = jax.tree.map(lambda t: jnp.full_like(t, 7.0), kv.cache)
+        if form == "block":
+            _, first = kv.insert(prompt, slot=1)
+        else:
+            kv.begin_insert(prompt, slot=1)
+            first = kv.prefill_chunk(1)
+        tables[form] = (first, jax.tree.leaves(kv.cache))
+    assert tables["block"][0] == tables["scan"][0]
+    for block, scan in zip(tables["block"][1], tables["scan"][1]):
+        block, scan = np.asarray(block), np.asarray(scan)
+        np.testing.assert_allclose(block[1, :lp], scan[1, :lp],
+                                   atol=1e-5, rtol=1e-5)
+        assert (block[1, :lp] != 7.0).any()
+        assert (block[1, lpad:] == 7.0).all() and (block[0] == 7.0).all()
+
+
+def test_block_prefill_into_a_slot_that_held_a_longer_sequence(model_params):
+    """Stale rows past the new prompt stay invisible: the block writes
+    ``[0, lpad)`` only, and validity is length-driven."""
+    model, params = model_params
+    kv = SlotKVCache(model, params, slots=2)
+    long, short = _prompts(1, seed=5, lo=20, hi=21)[0], \
+        _prompts(1, seed=6, lo=5, hi=6)[0]
+    slot, _ = kv.insert(long, slot=0)
+    for _ in range(3):
+        kv.advance()
+    kv.evict(slot)
+    slot, first = kv.insert(short, slot=0)
+    toks = [first] + [int(kv.advance()[slot]) for _ in range(7)]
+    np.testing.assert_array_equal(_oracle(model, params, short, 8), toks)
+
+
+@pytest.mark.parametrize("kv_dtype", [None, "int8"], ids=["float", "int8"])
+def test_block_prefill_over_a_model_axis(kv_dtype):
+    """A tensor-parallel served model (Megatron-annotated params on a
+    data x model mesh, the table's kv heads over ``model``): the block
+    branch shares its projections with every other mode, so the
+    annotations hold, and the oracle's tokens are served."""
+    import flax.linen as nn
+    from jax.sharding import NamedSharding
+    from distributed_tensorflow_tpu.parallel import mesh as meshlib
+
+    mesh = meshlib.create_mesh(8, axis_names=("data", "model"), shape=(4, 2))
+    model = tiny_gpt(partition_model=True)
+    boxed = model.init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32),
+                       train=False)["params"]
+    params = jax.tree.map(
+        lambda p, spec: jax.device_put(p, NamedSharding(mesh, spec)),
+        nn.meta.unbox(boxed), nn.get_partition_spec(boxed))
+    kv = SlotKVCache(model, params, slots=4, mesh=mesh, kv_dtype=kv_dtype)
+    assert kv.dm.partition_model
+    assert jax.tree.leaves(kv.cache)[0].sharding.spec[2] == "model"
+    host = jax.device_get(params)
+    for lp in (1, 8, 13):
+        prompt = np.random.default_rng(lp).integers(0, 64, lp).astype(
+            np.int32)
+        slot, first = kv.insert(prompt)
+        toks = [first] + [int(kv.advance()[slot]) for _ in range(4)]
+        np.testing.assert_array_equal(
+            _oracle(tiny_gpt(), host, prompt, 5), toks, str(lp))
+        kv.evict(slot)
+
+
+def test_block_prefill_applies_the_head_to_one_row():
+    """The lowered ``kv_prefill_batched_l8`` holds logits for ONE position
+    (``(1, 1, vocab)``; the row is gathered ahead of the final LayerNorm
+    and the tied head), none for the bucket's 8, and no ``while``."""
+    model = tiny_gpt(vocab_size=80)     # a width no other tensor has
+    params = model.init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32),
+                        train=False)["params"]
+    programs = {}
+
+    class Probe(SlotKVCache):
+        def _jit(self, fn, name, **jit_kwargs):
+            programs[name] = (fn, jit_kwargs)
+            return super()._jit(fn, name, **jit_kwargs)
+
+    kv = Probe(model, params, slots=2)
+    kv._prefill(8)
+    fn, jit_kwargs = programs["kv_prefill_batched_l8"]
+    text = jax.jit(fn, **jit_kwargs).lower(
+        params, kv.cache, jnp.int32(0), jnp.zeros((8,), jnp.int32),
+        jnp.int32(3), jax.random.key(0)).as_text()
+    assert "tensor<1x1x80xf32>" in text
+    assert "x8x80x" not in text and "stablehlo.while" not in text
+
+
 def test_slot_overflow_guard(model_params):
     """Advancing an at-capacity slot raises instead of silently clamping
     (the serving twin of the decode cache's sticky overflow flag)."""
@@ -455,7 +584,7 @@ def test_default_run_records_every_prefill_with_its_bucket(recorded_window):
     recs, kv = recorded_window["records"], recorded_window["kv"]
     prefills = [r for r in recs if r["name"] == "prefill"]
     assert sorted(r["rid"] for r in prefills) == [10, 11, 12]
-    # the cache counts the scan steps its programs ran; the scheduler
+    # the cache counts the positions its programs ran; the scheduler
     # does not recompute the bucket rule
     assert sum(r["attrs"]["padded_len"] for r in prefills) \
         == kv.prefill_tokens_padded
@@ -469,8 +598,8 @@ def test_default_run_records_every_prefill_with_its_bucket(recorded_window):
     builds = {r["attrs"]["program"]: r for r in recs
               if r["name"] == "program_build"}
     assert "kv_decode_step" in builds
-    assert {f"kv_prefill_l{r['attrs']['padded_len']}" for r in prefills} \
-        <= set(builds)
+    assert {f"kv_prefill_batched_l{r['attrs']['padded_len']}"
+            for r in prefills} <= set(builds)
     by_id = {r["id"]: r for r in recs}
     assert by_id[builds["kv_decode_step"]["parent"]]["name"] == "decode_step"
 

@@ -103,6 +103,57 @@ def test_decode_step_writes_the_slot_table_in_place(one_chip):
         memory.temp_size_in_bytes, leaf_bytes)
 
 
+def test_the_block_prefill_is_one_forward_that_writes_the_slot_in_place(
+        one_chip):
+    """The serve cell's ``kv_prefill_batched_l512`` at the widths and depth
+    of ``benchmarks/configs/gpt2-large.json`` (32 slots x 1,024, bf16
+    table, float32 parameters, greedy, the table donated).
+
+    Until PR 30 the program was a ``while`` of 512 one-token steps, each
+    reading all 3.1 GB of weights and computing a 50,257-wide logits row:
+    1.38 s on the chip and 2.24 GB of temporaries.  It is now one forward
+    over the block: (i) no ``while`` is left, (ii) the block's K and V
+    reach the donated table without a ``copy`` of a whole
+    ``bf16[32,1024,20,64]`` leaf (the relayout PR 26 took out of the
+    step: 84 MB a leaf, 72 leaves), and (iii) the temporaries (0.26 GB:
+    the bf16 copy of the tied embedding, 129 MB, and the block's
+    activations) stay under 0.5 GB."""
+    config = json.loads((Path(__file__).resolve().parent.parent / "benchmarks"
+                         / "configs" / "gpt2-large.json").read_text())
+    slots, lpad = 32, 512
+    max_len, heads = config["n_positions"], config["n_head"]
+    hidden = config["n_embd"]
+    head_dim = hidden // heads
+    model = create_model("gpt", dtype="bfloat16",
+                         vocab_size=config["vocab_size"], max_len=max_len,
+                         hidden=hidden, layers=config["n_layer"], heads=heads,
+                         ffn=4 * hidden)
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32),
+                           train=False))["params"]
+    kv = _ProgramProbe(model, params, slots, greedy=True,
+                       kv_dtype=jnp.bfloat16)
+    kv._prefill(lpad)
+    prefill, jit_kwargs = kv.programs[f"kv_prefill_batched_l{lpad}"]
+
+    on_chip, like = _shapes_on(one_chip)
+
+    compiled = jax.jit(prefill, **jit_kwargs).lower(
+        like(params), like(kv.cache), on_chip((), jnp.int32),
+        on_chip((lpad,), jnp.int32), on_chip((), jnp.int32),
+        like(jax.random.key(0))).compile()
+
+    text = compiled.as_text()
+    assert not re.findall(r" while\(", text)
+    leaf_bytes = slots * max_len * heads * head_dim * 2
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= 72 * leaf_bytes    # donated
+    leaf = rf"bf16\[{slots},{max_len},{heads},{head_dim}\]"
+    copies = re.findall(rf"= {leaf}\{{[^}}]*\}} copy\(", text)
+    assert not copies, f"{len(copies)} relayout copies of a table leaf"
+    assert memory.temp_size_in_bytes < 0.5e9, memory.temp_size_in_bytes
+
+
 def test_the_long_prefill_holds_no_score_tile_wider_than_the_key_block(
         one_chip):
     """The long-document cell's ``kv_prefill_batched_l8192`` (the published

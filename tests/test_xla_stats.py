@@ -210,7 +210,8 @@ def test_kv_cache_ledger_observes_decode(tmp_path):
     # under the kv_ component prefix
     assert all(name.startswith("kv_") for name in m["programs"])
     assert "kv_decode_step" in m["programs"], sorted(m["programs"])
-    assert any(name.startswith("kv_prefill_l") for name in m["programs"])
+    assert any(name.startswith("kv_prefill_batched_l")
+               for name in m["programs"])
     # flag-off parity at the program level: identical inventories
     assert set(kv_plain.compiled_programs()) == \
         set(kv_led.compiled_programs())
