@@ -100,9 +100,17 @@ def create_model(name: str, num_classes: int = 10, **kw) -> nn.Module:
             kw["param_dtype"] = resolve_dtype(kw["param_dtype"])
         kw.setdefault("vocab_size", num_classes)
         return LatentMoELM(**kw)
+    if name == "hybrid_ssm":
+        from distributed_tensorflow_tpu.models.hybrid_ssm import HybridSSMLM
+
+        if "param_dtype" in kw:
+            kw["param_dtype"] = resolve_dtype(kw["param_dtype"])
+        kw.setdefault("vocab_size", num_classes)
+        return HybridSSMLM(**kw)
     if name not in _REGISTRY:
         raise KeyError(f"unknown model '{name}'; known: {sorted(_REGISTRY)} "
-                       f"+ resnet20, bert_tiny, moe, gpt, mla_moe")
+                       f"+ resnet20, bert_tiny, moe, gpt, mla_moe, "
+                       f"hybrid_ssm")
     return _REGISTRY[name](num_classes=num_classes, **kw)
 
 

@@ -194,27 +194,38 @@ class MoELayer(nn.Module):
 
 
 class DroplessMoE(nn.Module):
-    """Dropless top-k routed SwiGLU experts with shared experts, over
-    tokens (leading axis of x).
+    """Dropless top-k routed experts with a shared expert, over tokens
+    (leading axis of x).
 
     ``s = sigmoid(x W_g)`` in float32 over all ``num_experts``; the
     ``top_k`` experts of a token are the largest of ``s + b`` (``b`` the
     choice bias: it moves the choice and never the weight); their weights
     are ``s`` without ``b``, divided by their sum when ``norm_topk`` and
-    times ``routed_scale``.  ``y = sum_i w_i E_i(x) + S(x)``: each ``E_i``
-    a SwiGLU of width ``hidden``, ``S`` one SwiGLU of width
-    ``shared_hidden`` (0 = none).
+    times ``routed_scale``.  ``y = sum_i w_i E_i(x) + S(x)``, with
+    ``expert_act`` the form of every ``E_i`` and of ``S``:
+
+    * ``"swiglu"``: ``W_down(silu(W_gate x) * (W_up x))``, three matrices;
+    * ``"relu2"``: ``W_down relu(W_up x)^2``, two matrices and no gate.
+
+    ``E_i`` has width ``hidden``, ``S`` width ``shared_hidden`` (0 = none).
+    With ``latent`` the routed experts work in a latent of that width: the
+    router and the shared expert read ``x``, the experts read ``u = x
+    W_dn`` and write latents, and their weighted sum goes through ``W_up``
+    back to the width of ``x``: ``y = (sum_i w_i E_i(u)) W_up + S(x)`` (no
+    norm or bias on the two projections).  With ``expert_act="swiglu"``
+    and ``latent=0`` the layer is what it was before it knew either.
 
     ``held = (first, count)`` names the experts whose weights live here
     (None = all).  The router keeps its full width; a (token, choice)
     pair whose expert is not held contributes nothing, so the layer
     returns its own experts' part of the result plus the shared expert —
     what one chip of an expert-parallel deployment computes before the
-    exchange (tests/test_mla_moe.py adds the shares up).
+    exchange (tests/test_mla_moe.py and tests/test_hybrid_ssm.py add the
+    shares up).
 
     Dispatch: the ``tokens * top_k`` pairs are sorted by expert (pairs
     that are not held, or whose token is not ``valid``, sort last and lie
-    past the last group), the tokens gathered in that order, and the three
+    past the last group), the tokens gathered in that order, and the
     expert matrices applied as grouped products over the group sizes.  No
     capacity: every pair is computed.  Each token's expert choice is sown
     as ``expert_choice`` into ``intermediates`` (the serving engine counts
@@ -227,6 +238,8 @@ class DroplessMoE(nn.Module):
     routed_scale: float = 1.0
     norm_topk: bool = True
     held: tuple[int, int] | None = None
+    expert_act: str = "swiglu"
+    latent: int = 0
     dtype: jnp.dtype = jnp.float32
     param_dtype: jnp.dtype = jnp.float32
 
@@ -238,6 +251,9 @@ class DroplessMoE(nn.Module):
         if not (0 <= first and n >= 1 and first + n <= e and 1 <= k <= e):
             raise ValueError(
                 f"held={self.held} / top_k={k} do not fit {e} experts")
+        if self.expert_act not in ("swiglu", "relu2"):
+            raise ValueError(f"expert_act {self.expert_act!r}: swiglu or "
+                             f"relu2")
         init = nn.initializers.lecun_normal()
 
         # --- router (f32, true f32 products: a choice is a comparison) ---
@@ -262,28 +278,61 @@ class DroplessMoE(nn.Module):
         local = jnp.where(here, local, n).reshape(-1)           # [T*k]
         order = jnp.argsort(local, stable=True)
         sizes = jnp.bincount(local, length=n + 1)[:n].astype(jnp.int32)
-        xs = x.astype(self.dtype)[order // k]                   # [T*k, d]
 
-        def experts(name, shape):
-            return self.param(name, init, (n,) + shape,
-                              self.param_dtype).astype(self.dtype)
+        def project(width, name):
+            return nn.Dense(width, use_bias=False, dtype=self.dtype,
+                            param_dtype=self.param_dtype, name=name)
 
-        gate = jax.lax.ragged_dot(xs, experts("w_gate", (d, self.hidden)),
-                                  sizes)
-        up = jax.lax.ragged_dot(xs, experts("w_up", (d, self.hidden)), sizes)
-        ys = jax.lax.ragged_dot(jax.nn.silu(gate) * up,
-                                experts("w_down", (self.hidden, d)), sizes)
+        # what the routed experts read: x, or its latent
+        u = project(self.latent, "latent_down")(x) if self.latent \
+            else x.astype(self.dtype)
+        w = u.shape[-1]
+        xs = u[order // k]                                      # [T*k, w]
+
+        def grouped(rows, name, shape):
+            weights = self.param(name, init, (n,) + shape,
+                                 self.param_dtype).astype(self.dtype)
+            return jax.lax.ragged_dot(rows, weights, sizes)
+
+        if self.expert_act == "swiglu":
+            gate = grouped(xs, "w_gate", (w, self.hidden))
+            up = grouped(xs, "w_up", (w, self.hidden))
+            act = jax.nn.silu(gate) * up
+        else:
+            act = jnp.square(jax.nn.relu(
+                grouped(xs, "w_up", (w, self.hidden))))
+        ys = grouped(act, "w_down", (self.hidden, w))
         # rows past the last group belong to no expert here: whatever the
         # grouped product left there is not a result
         ys = jnp.where((jnp.arange(t * k) < sizes.sum())[:, None], ys, 0)
-        ys = ys[jnp.argsort(order)].reshape(t, k, d)            # unsort
+        ys = ys[jnp.argsort(order)].reshape(t, k, w)            # unsort
         y = jnp.einsum("tkd,tk->td", ys.astype(jnp.float32),
                        jnp.where(here, weight, 0.0))
         y = y.astype(self.dtype)
+        if self.latent:
+            y = project(d, "latent_up")(y)
         if self.shared_hidden:
-            y = y + SwiGLU(self.shared_hidden, self.dtype, self.param_dtype,
+            shared = SwiGLU if self.expert_act == "swiglu" else ReLU2MLP
+            y = y + shared(self.shared_hidden, self.dtype, self.param_dtype,
                            name="shared")(x)
         return y
+
+
+class ReLU2MLP(nn.Module):
+    """``W_down relu(W_up x)^2``, no gate and no biases."""
+
+    hidden: int
+    dtype: jnp.dtype = jnp.float32
+    param_dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        def dense(width, name):
+            return nn.Dense(width, use_bias=False, dtype=self.dtype,
+                            param_dtype=self.param_dtype, name=name)
+
+        return dense(x.shape[-1], "down")(
+            jnp.square(jax.nn.relu(dense(self.hidden, "up")(x))))
 
 
 class SwiGLU(nn.Module):

@@ -12,10 +12,35 @@ decode program never recompiles.
 
 Device-side contract (everything else lives in serving/scheduler.py):
 
-* the cache is a pytree of ``(slots, max_len, ...)`` leaves, whatever the
-  model's own slot-decode mode keeps a token: per-head keys and values
-  ``(slots, max_len, kv_heads, head_dim)`` for models/gpt.py, a latent and
-  one rotated key head ``(slots, max_len, rank)`` for models/mla_moe.py.
+* the cache is a pytree with the slot dim first on every leaf, and TWO
+  kinds of leaf, which the served model tells apart (its ``slot_state``
+  names the second kind; a model without that attribute keeps the first
+  kind only):
+
+  - per-position ROWS ``(slots, max_len, ...)``: per-head keys and values
+    ``(slots, max_len, kv_heads, head_dim)`` for models/gpt.py and for the
+    attention layers of models/hybrid_ssm.py, a latent and one rotated
+    key head ``(slots, max_len, rank)`` for models/mla_moe.py.  Validity
+    is LENGTH-DRIVEN: a row at or past the slot's length is never
+    attended, so a stale row, a pad row of a prefill bucket and the row a
+    free or excluded slot writes at its own length are all invisible, and
+    the next real write lands over them;
+  - per-slot STATE ``(slots, ...)`` with no position axis: the recurrent
+    state ``(slots, heads, head_dim, state)`` and the convolution tail
+    ``(slots, taps - 1, width)`` of a state-space layer
+    (models/hybrid_ssm.py).  Nothing about it is length-driven: the whole
+    leaf is valid at every moment, and a second write advances it twice.
+    So a PREFILL starts the slot's state from zero whatever the last
+    occupant left and makes the bucket's pad rows inert (the state written
+    is the state after ``prompt_len`` tokens, the tail its last real
+    rows), the STEP is handed ``active`` and keeps the state of a slot
+    that is not active bit for bit, and validity cannot be moved by
+    bookkeeping: ``rewind``, ``commit_block`` / ``verify_block``, the
+    prefix pool, chunk resume, the paged layout, int8 storage, multi-step
+    dispatch, KV handoff and a tensor-parallel table raise
+    ``NotImplementedError`` by name for a model with state (no state
+    snapshot is built).
+
   Deliberately no scalar cursors, so every leaf shards the slot dim over
   the mesh's ``data`` axis and, for tensor-parallel models, the kv-head
   dim over ``model`` (parallel/mesh.py ``kv_slot_sharding``);
@@ -27,9 +52,10 @@ Device-side contract (everything else lives in serving/scheduler.py):
   ONE call of the served module over the whole padded prompt from
   position 0 (``prompt_len`` marks it: the block attends within itself,
   writes the slot's rows in one piece and returns logits for the last
-  real position only), for models/gpt.py and models/mla_moe.py alike: a
-  prompt token then costs its FLOPs, where a scan of the one-token step
-  read every weight (and, for mla_moe, every expert) once a token;
+  real position only), for models/gpt.py, models/mla_moe.py and
+  models/hybrid_ssm.py alike: a prompt token then costs its FLOPs, where
+  a scan of the one-token step read every weight (and every expert) once
+  a token;
 * ``begin_insert``/``prefill_chunk`` split that admission into fixed
   token-budget chunks (Sarathi-Serve, arXiv:2403.02310): each chunk feeds
   its tokens through the SAME per-token decode math inside a ``lax.scan``
@@ -116,7 +142,7 @@ class BlockPoolExhausted(RuntimeError):
 class SlotKVCache:
     """Fixed slot table + compiled prefill/decode programs for one model
     with a slot-decode mode (``models/gpt.GPTLM``,
-    ``models/mla_moe.LatentMoELM``).
+    ``models/mla_moe.LatentMoELM``, ``models/hybrid_ssm.HybridSSMLM``).
 
     ``model`` is the TRAINING-mode module (any attention impl); its
     ``slot_decode_clone`` gives the module served from — for ``GPTLM``
@@ -127,7 +153,9 @@ class SlotKVCache:
     one-token step over per-head K/V rows — the paged layout, chunk
     resume, the prefix pool, int8 storage, multi-step dispatch,
     speculative verify, KV handoff — raises ``NotImplementedError`` by
-    name for a model without one (``resumable_step`` on the model class).
+    name for a model without one (``resumable_step`` on the model class),
+    and so does what moves validity by bookkeeping for a model that keeps
+    per-slot state (``slot_state`` on the model class).
     ``params`` may be a TP engine's committed TrainState params (used in
     place) or host/single-device params (replicated).
 
@@ -194,6 +222,10 @@ class SlotKVCache:
                    and getattr(model, "partition_model", False)
                    and meshlib.MODEL_AXIS in mesh.axis_names)
         self.resumable_step = model.resumable_step
+        # the leaves that are per-slot state and not per-position rows,
+        # by name, and whether ``kv_dtype`` may narrow each (module
+        # docstring: the two kinds)
+        self.state_leaves = dict(getattr(model, "slot_state", {}))
         # what ``insert`` runs (the ``prefill`` span's ``form``): one call
         # over the padded prompt, or the chunk scan the prefix pool needs
         self.prefill_form = "scan" if prefix_cache_blocks else "batched"
@@ -218,15 +250,20 @@ class SlotKVCache:
             # promotes back, so the decode program stays the one compiled
             # step.  (int8 needs no cast here — the kv_quant model
             # already initializes int8 payload + f32 scale leaves.)
-            cache = jax.tree.map(
-                lambda t: t.astype(kv_dtype)
-                if jnp.issubdtype(t.dtype, jnp.floating) else t, cache)
+            # A state leaf the model pins (a recurrent state: an error in
+            # it is carried through every later token) stays as made.
+            cache = jax.tree_util.tree_map_with_path(
+                lambda path, t: t.astype(kv_dtype)
+                if jnp.issubdtype(t.dtype, jnp.floating)
+                and self.state_leaves.get(path[-1].key, True) else t, cache)
         # the table's actual storage dtype, surfaced in the serve report
         # section (for int8 the first FLOAT leaf is a scale, so the name
-        # is pinned explicitly; otherwise it is the K/V buffer dtype)
+        # is pinned explicitly; otherwise it is the rows' dtype)
         self.kv_dtype = "int8" if self.quantized else next(
-            (str(leaf.dtype) for leaf in jax.tree.leaves(cache)
-             if jnp.issubdtype(leaf.dtype, jnp.floating)), "float32")
+            (str(leaf.dtype) for path, leaf in
+             jax.tree_util.tree_leaves_with_path(cache)
+             if jnp.issubdtype(leaf.dtype, jnp.floating)
+             and path[-1].key not in self.state_leaves), "float32")
 
         self._vec_sharding = None
         self._blk_sharding = None
@@ -324,11 +361,24 @@ class SlotKVCache:
         """What rests on a scan of the one-token step from any start
         position and on per-head K/V leaves is refused by name for a model
         that has no such step."""
+        self._rows_only(feature)
         if not self.resumable_step:
             raise NotImplementedError(
                 f"{feature} is not implemented for a model without a "
                 f"resumable one-token step (models/mla_moe.py): the "
                 f"monolithic table with insert/advance/evict is")
+
+    def _rows_only(self, feature: str) -> None:
+        """What moves a slot's validity by bookkeeping, or copies a slot
+        by its rows, is refused by name for a model that keeps per-slot
+        state: a recurrent state cannot be taken back or resumed from a
+        position, and no snapshot of it is built."""
+        if self.state_leaves:
+            raise NotImplementedError(
+                f"{feature} is not implemented for a model that keeps "
+                f"per-slot state beside its rows (models/hybrid_ssm.py: "
+                f"{sorted(self.state_leaves)}): the monolithic table with "
+                f"insert/advance/evict is")
 
     def _place_params(self, params):
         """Param placement rule (shared by __init__ and ``swap_params``):
@@ -419,40 +469,53 @@ class SlotKVCache:
     def _build_step(self):
         dm = self.dm
 
+        def hold(active) -> dict:
+            # a model with per-slot state is handed ``active``: it keeps
+            # the state of a slot that is not active bit for bit
+            return {"active": active} if self.state_leaves else {}
+
         def step(params, cache, tokens, lengths, active, rng):
-            # write index = current length, written by models/gpt.py
+            # ROWS: write index = current length, written by models/gpt.py
             # ``write_slot_rows``: each slot's row in place in the donated
             # table (no copy of a table leaf: tests/test_tpu_compile.py).
             # Inactive (free) slots write garbage into their own rows
             # only, which the next insert's prefill overwrites — validity
             # is length-driven, so stale positions are never attended —
             # and a slot freed at length max_len writes nothing: the
-            # helper DROPS a position past the table.  The advanced token
-            # AND length vectors are program outputs so the next
-            # iteration can consume them on device (`_dev_learn`) instead
-            # of re-uploading host mirrors.
+            # helper DROPS a position past the table.  STATE has no such
+            # argument (a write IS an advance): the model keeps it where
+            # ``active`` is false.  The advanced token AND length vectors
+            # are program outputs so the next iteration can consume them
+            # on device (`_dev_learn`) instead of re-uploading host
+            # mirrors.
             logits, upd = dm.apply(
                 {"params": params, "cache": cache}, tokens[:, None],
-                train=False, positions=lengths[:, None], mutable=["cache"])
+                train=False, positions=lengths[:, None], mutable=["cache"],
+                **hold(active))
             nxt = self._sample(logits[:, -1], rng).astype(tokens.dtype)
             return (upd["cache"], jnp.where(active, nxt, tokens),
                     jnp.where(active, lengths + 1, lengths))
 
         def routed_step(params, cache, tokens, lengths, active, rng):
             """The step above for a model with routed experts, which also
-            returns ``[experts touched, summed over expert layers; largest
-            count any expert received]`` over the ACTIVE slots' choices:
-            two integers beside the tokens."""
+            returns, over the ACTIVE slots' choices among the experts HELD
+            here, ``[experts touched, summed over expert layers; largest
+            count any expert received; (token, expert) pairs]``: three
+            integers beside the tokens."""
             logits, upd = dm.apply(
                 {"params": params, "cache": cache}, tokens[:, None],
                 train=False, positions=lengths[:, None],
-                mutable=["cache", "intermediates"])
+                mutable=["cache", "intermediates"], **hold(active))
             nxt = self._sample(logits[:, -1], rng).astype(tokens.dtype)
             choice = jnp.stack(jax.tree.leaves(upd["intermediates"]))
             counts = jnp.sum(
                 jax.nn.one_hot(choice, dm.num_experts, dtype=jnp.int32)
                 * active[None, :, None, None].astype(jnp.int32), axis=(1, 2))
-            routing = jnp.stack([jnp.sum(counts > 0), jnp.max(counts)])
+            first, n = getattr(dm, "experts_held", None) \
+                or (0, dm.num_experts)
+            counts = counts[:, first:first + n]
+            routing = jnp.stack([jnp.sum(counts > 0), jnp.max(counts),
+                                 jnp.sum(counts)])
             return (upd["cache"], jnp.where(active, nxt, tokens),
                     jnp.where(active, lengths + 1, lengths), routing)
 
@@ -470,13 +533,20 @@ class SlotKVCache:
         writes the slot's rows ``[0, lpad)`` in one piece), writes the
         slice back, and samples the FIRST generated token from the logits
         the module returns, which are those of the last REAL prompt
-        position alone.  Pad positions past ``prompt_len`` write garbage
-        K/V beyond the slot's length — invisible under the length mask
-        and overwritten as decoding advances (the same argument that makes
-        free-slot writes safe); rows at or past ``lpad`` are left as they
-        were.  The decode step is untouched: admission never recompiles
-        it."""
+        position alone.  ROWS: pad positions past ``prompt_len`` write
+        garbage K/V beyond the slot's length — invisible under the length
+        mask and overwritten as decoding advances (the same argument that
+        makes free-slot writes safe); rows at or past ``lpad`` are left as
+        they were.  STATE: the model starts it from zero and makes the
+        pads inert (module docstring), so the slice written back is the
+        state after ``prompt_len`` tokens whatever the slot held.  The
+        decode step is untouched: admission never recompiles it.
+
+        A model that holds a SHARE of its experts (``experts_held``) also
+        returns the (token, expert) pairs of the prompt's real tokens that
+        fell to experts held here: what was computed."""
         dm = self.dm
+        share = self.expert_layers and getattr(dm, "experts_held", None)
 
         def batched(params, cache, slot, tokens, prompt_len, rng):
             sub = jax.tree.map(
@@ -485,12 +555,19 @@ class SlotKVCache:
                 {"params": params, "cache": sub}, tokens[None, :],
                 train=False,
                 positions=jnp.arange(lpad, dtype=jnp.int32)[None, :],
-                prompt_len=prompt_len[None], mutable=["cache"])
+                prompt_len=prompt_len[None],
+                mutable=["cache", "intermediates"] if share else ["cache"])
             first = self._sample(logits[:, -1], rng)[0]
             cache = jax.tree.map(
                 lambda full, s: lax.dynamic_update_slice_in_dim(
                     full, s, slot, 0), cache, upd["cache"])
-            return cache, first.astype(tokens.dtype)
+            if not share:
+                return cache, first.astype(tokens.dtype)
+            choice = jnp.stack(jax.tree.leaves(upd["intermediates"]))
+            lo, n = share
+            held = ((choice >= lo) & (choice < lo + n)
+                    & (jnp.arange(lpad) < prompt_len)[None, :, None])
+            return cache, first.astype(tokens.dtype), jnp.sum(held)
 
         return self._jit(batched, f"kv_prefill_batched_l{lpad}",
                          donate_argnums=1)
@@ -776,7 +853,7 @@ class SlotKVCache:
             self._prefills[lpad] = self._prefill(lpad)
         fn = self._prefills[lpad]
         t0 = time.perf_counter()
-        self.cache, first = fn(
+        self.cache, first, *held = fn(
             self.params, self.cache, jnp.int32(slot),
             self._put_repl(padded), jnp.int32(lp), self._next_rng())
         # the host reads the token here, inside the timed region:
@@ -785,16 +862,15 @@ class SlotKVCache:
         self._phase_s["prefill_s"] += time.perf_counter() - t0
         self.prefill_tokens_computed += lp
         self.prefill_tokens_padded += lpad
-        self._count_assignments(lp)
+        if held:        # a share of the experts: the program counted
+            self.expert_assignments += int(held[0])
+        elif self.expert_layers:    # every expert held: every choice
+            self.expert_assignments += (
+                lp * self.expert_layers * self.dm.experts_per_token)
         self.active[slot] = True
         self.lengths[slot] = lp
         self.tokens[slot] = first
         return slot, first
-
-    def _count_assignments(self, tokens: int) -> None:
-        if self.expert_layers:
-            self.expert_assignments += (
-                tokens * self.expert_layers * self.dm.experts_per_token)
 
     # ------------------------------------------- chunked (resumable) prefill
     def begin_insert(self, prompt,
@@ -1085,10 +1161,13 @@ class SlotKVCache:
         ``only`` restricts the iteration to a (slots,) bool subset of the
         active slots (the speculative draft's catch-up step: after a
         fully-accepted round only those slots must consume one more
-        committed token).  Excluded rows keep their token and length —
-        their row still receives a scatter write at its current length,
+        committed token).  Excluded slots keep their token and length.
+        Their ROWS still receive a scatter write at their current length,
         which is invisible (length-driven validity) and overwritten by
-        that slot's next real write, the free-slot-scatter argument."""
+        that slot's next real write: the free-slot-scatter argument, which
+        holds for rows and for nothing else.  Their STATE is not written:
+        the step is handed the mask and keeps it bit for bit, for an
+        excluded live slot as for a free one."""
         mask = self.active if only is None else np.asarray(only, np.bool_)
         live = self.lengths[mask]
         if live.size and int(live.max()) >= self.max_len:
@@ -1108,11 +1187,12 @@ class SlotKVCache:
             self._dev_cached("mask", mask), self._next_rng())
         nxt = np.asarray(d_nxt)
         if routing:
-            touched, load_max = (int(v) for v in np.asarray(routing[0]))
+            touched, load_max, pairs = (int(v) for v in
+                                        np.asarray(routing[0]))
             self.last_routing = {
                 "experts_touched": touched / self.expert_layers,
                 "expert_load_max": load_max}
-            self._count_assignments(int(mask.sum()))
+            self.expert_assignments += pairs
         self._phase_s["decode_s"] += time.perf_counter() - t0
         self.lengths[mask] += 1
         self.tokens = nxt.astype(np.int32)
@@ -1368,6 +1448,7 @@ class SlotKVCache:
         is length-driven, so advancing by only the accepted count
         invalidates the rejected tail with no KV rewrite — the slot's
         next write simply lands over it."""
+        self._rows_only("commit_block")
         if not self.active[slot]:
             raise RuntimeError(f"slot {slot} is not active")
         if n < 1:
@@ -1384,6 +1465,7 @@ class SlotKVCache:
         token — the DRAFT table's resync after a verify round: positions
         past ``length`` were speculative writes, invalidated here by
         length bookkeeping alone.  A rewind can never extend validity."""
+        self._rows_only("rewind")
         if not self.active[slot]:
             raise RuntimeError(f"slot {slot} is not active")
         if length > int(self.lengths[slot]):
@@ -1397,9 +1479,12 @@ class SlotKVCache:
         self.halted[slot] = False
 
     def evict(self, slot: int) -> None:
-        """Free a slot.  Pure host bookkeeping: stale K/V stays in the
-        buffer but is unreachable (validity is length-driven) and the next
-        insert's prefill overwrites it from position 0."""
+        """Free a slot.  Pure host bookkeeping.  Stale ROWS stay in the
+        buffer but are unreachable (validity is length-driven) and the
+        next insert's prefill overwrites them from position 0.  Stale
+        STATE stays too, frozen (the step keeps it while the slot is not
+        active), and is never read: the next insert's prefill starts from
+        zero and writes the whole leaf."""
         if not self.active[slot]:
             raise RuntimeError(f"slot {slot} is not active")
         self.active[slot] = False
@@ -1418,26 +1503,43 @@ class SlotKVCache:
     def counters(self) -> dict[str, int]:
         """Cumulative counts beside ``phase_times``: prompt tokens fed
         through a prefill program and the positions those programs ran
-        (pads included), the (token, expert) routing assignments made
-        (0 for a model without experts), and what one token of one slot
-        keeps in the table, all layers together."""
+        (pads included), the (token, expert) routing assignments made to
+        experts held here (0 for a model without experts), what one token
+        of one slot keeps in the table's per-position rows, all layers
+        together, and what one slot keeps as per-slot state whatever its
+        length (0 for a model without state)."""
+        state = self._table_bytes()[1]
         return {"prefill_tokens_computed": self.prefill_tokens_computed,
                 "prefill_tokens_padded": self.prefill_tokens_padded,
                 "expert_assignments": self.expert_assignments,
+                # (the paged layout counts the blocks that back live slots)
                 "cache_bytes_per_token":
-                    self.kv_bytes_per_slot() // self.max_len}
+                    (self.kv_bytes_per_slot() - state // self.slots)
+                    // self.max_len,
+                "state_bytes_per_slot": state // self.slots}
+
+    def _table_bytes(self) -> tuple[int, int]:
+        """Stored bytes of the whole table by kind of leaf: ``(per-position
+        rows, per-slot state)``."""
+        rows = state = 0
+        for path, leaf in jax.tree_util.tree_leaves_with_path(self.cache):
+            size = int(leaf.size) * jnp.dtype(leaf.dtype).itemsize
+            if path[-1].key in self.state_leaves:
+                state += size
+            else:
+                rows += size
+        return rows, state
 
     def kv_bytes_per_slot(self) -> int:
-        """Stored KV-table bytes per serving slot: every cache leaf —
-        K/V payload plus, under int8 storage, its f32 scale leaves —
-        divided by the slot count.  THE capacity number behind
-        ``--serve-kv-dtype``: bf16 halves f32; int8 halves bf16's payload
-        again, plus a per-written-vector scale overhead of 4/head_dim
-        (the serve section carries it as ``serve_kv_bytes_per_slot``,
-        gated lower-is-better by `analyze diff`)."""
-        total = sum(int(leaf.size) * jnp.dtype(leaf.dtype).itemsize
-                    for leaf in jax.tree.leaves(self.cache))
-        return total // self.slots
+        """Stored table bytes per serving slot: every cache leaf — K/V
+        payload plus, under int8 storage, its f32 scale leaves, plus a
+        model's per-slot state — divided by the slot count.  THE capacity
+        number behind ``--serve-kv-dtype``: bf16 halves f32; int8 halves
+        bf16's payload again, plus a per-written-vector scale overhead of
+        4/head_dim (the serve section carries it as
+        ``serve_kv_bytes_per_slot``, gated lower-is-better by `analyze
+        diff`)."""
+        return sum(self._table_bytes()) // self.slots
 
     def compiled_programs(self) -> dict[str, int]:
         """The recompile-freedom invariant the tests pin down: one decode
@@ -1472,10 +1574,8 @@ class SlotKVCache:
         stored bytes: tokens actually valid × stored bytes per token."""
         per_tok = getattr(self, "_tl_token_bytes", None)
         if per_tok is None:
-            total = sum(int(leaf.size) * jnp.dtype(leaf.dtype).itemsize
-                        for leaf in jax.tree.leaves(self.cache))
             per_tok = self._tl_token_bytes = \
-                total / (self.slots * self.max_len)
+                self._table_bytes()[0] / (self.slots * self.max_len)
         live_tokens = int(self.lengths.sum())
         return {
             "kv_active_slots": int(self.active.sum()),
@@ -1598,6 +1698,7 @@ class PagedSlotKVCache(SlotKVCache):
                    and getattr(model, "partition_model", False)
                    and meshlib.MODEL_AXIS in mesh.axis_names)
         self.resumable_step = model.resumable_step
+        self.state_leaves = dict(getattr(model, "slot_state", {}))
         self.prefill_form = "scan"      # insert goes through _chunk
         self._scan_model_only("the paged layout")
         # fused clone for the decode/verify hot ops, gather clone for the

@@ -518,6 +518,7 @@ class ContinuousBatcher:
                              form=kv.prefill_form) as sp:
                 slot, first = kv.insert(req.prompt)
                 sp["padded_len"] = kv.prefill_tokens_padded - padded
+                sp["slot"] = slot
             self.clock.on_prefill(kv.prefill_tokens_computed - before)
             if self._rf_cost is not None:
                 # credit only positions actually computed: a prefix-cache
@@ -1270,6 +1271,7 @@ class ContinuousBatcher:
                 # own record (`analyze serve` prints them)
                 counts = self.kv.counters()
                 sp["cache_bytes_per_token"] = counts["cache_bytes_per_token"]
+                sp["state_bytes_per_slot"] = counts["state_bytes_per_slot"]
                 sp["expert_assignments"] = (counts["expert_assignments"]
                                             - counts_before["expert_assignments"])
             wall_elapsed = time.perf_counter() - wall0

@@ -21,6 +21,8 @@ CELLS = [w["name"] for w in BENCH["workloads"]]
     ("tiny-train", "train-gpt2m-1k"),
     ("tiny-serve", "serve-gpt2l-chat"),
     ("tiny-serve-mla-moe", "serve-kanana2-longdoc"),
+    ("tiny-serve-hybrid-ssm", "serve-nemotron3s-chat"),
+    ("tiny-serve", "serve-gpt2l-gen"),
 ])
 def test_tiny_cell_runs_through_the_committed_driver(tiny_cell, stands_for):
     result = run_tiny(tiny_cell, stands_for)

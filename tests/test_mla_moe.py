@@ -288,9 +288,10 @@ def test_analyze_serve_prints_the_tables_counters(model, weights, tmp_path):
     wf = serve_waterfall(read_jsonl(str(path)))
     assert wf["windows"] == [{**wf["windows"][0], "offered": 2, "slots": 2,
                               "cache_bytes_per_token": 480,
+                              "state_bytes_per_slot": 0,
                               "expert_assignments": (22 + 7) * 2 * TOP_K}]
-    assert "480 bytes a token, 232 expert assignments" \
-        in render_waterfall_text(wf)
+    assert ("480 bytes a token, 0 bytes of state a slot, 232 expert "
+            "assignments") in render_waterfall_text(wf)
 
 
 def test_a_gpt_window_reports_a_batched_prefill_and_no_routing():
