@@ -62,7 +62,7 @@ def _part(init, spec, enabled: bool):
 
 def write_slot_rows(table, update, pos):
     """``table[b, pos[b, j]] = update[b, j]`` for every slot row ``b`` and
-    block position ``j``: how the monolithic slot table takes a step's new
+    block position ``j``: how a monolithic slot table takes a block of new
     K/V rows (and, under int8 storage, their scales).
 
     ``table`` is ``(slots, max_len, ...)``, ``update`` ``(slots, L, ...)``
@@ -71,10 +71,21 @@ def write_slot_rows(table, update, pos):
     axis is a batching dimension and the update window is one whole
     position in the table's own shape, nothing collapsed.  The TPU
     compiler turns that form into a loop of in-place
-    ``dynamic-update-slice`` in whatever layout the table has; the
-    two-index ``.at[rows, pos].set`` it served by re-laying the whole
-    table out and back (on the v5e two copies of every leaf a step, 3.2x
-    padded: tests/test_tpu_compile.py holds that they stay away).
+    ``dynamic-update-slice``, one a slot, in whatever layout the table
+    has; the two-index ``.at[rows, pos].set`` it served by re-laying the
+    whole table out and back (on the v5e two copies of every leaf a step,
+    3.2x padded: tests/test_tpu_compile.py holds that they stay away).
+
+    WHO CALLS IT: the token-block / int8 branch of the slot-decode
+    attention below (speculative verify, ``kv_dtype="int8"``: L
+    positions, or four leaves of two dtypes), ``models/mla_moe.py`` and
+    ``models/hybrid_ssm.py`` (latent and few-head tables whose one row is
+    contiguous on the chip: the loop is a twentieth of their round or
+    less, where a pass that rewrites the table would cost more).  The
+    one-token branch of GPT-2's ``(slots, max_len, heads, head_dim)``
+    table does NOT: there the v5e keeps ``max_len`` minor, one row is
+    ``heads * head_dim`` single lanes in as many tiles, and the loop was
+    nearly half of the round (``select_slot_row``; PERF.md section 6).
 
     DROP RULE: a position outside ``[0, max_len)`` leaves the table as it
     was — pad rows of a chunk bucket that run past the table, and a freed
@@ -88,6 +99,30 @@ def write_slot_rows(table, update, pos):
         scatter_indices_batching_dims=(0,))
     return lax.scatter(table, pos[..., None], update[:, :, None], dnums,
                        mode=lax.GatherScatterMode.FILL_OR_DROP)
+
+
+def select_slot_row(table, update, pos):
+    """``table[b, p] = update[b, 0] if p == pos[b, 0] else table[b, p]``:
+    how the one-token decode step puts a slot's new K or V row into the
+    monolithic table.
+
+    ``table`` is ``(slots, max_len, ...)``, ``update`` ``(slots, 1, ...)``
+    (cast here to the table's dtype), ``pos`` ``(slots, 1)``.  A select
+    over the position axis and no scatter: it names every cell of the
+    table, and the compiler fuses it into the pass the attention that
+    follows makes over the table anyway (the scores' fusion emits the
+    updated key leaf as a second output, the context's the value leaf),
+    so the donated table is read once, as before, and written once, in
+    the layout it arrived in.  ``write_slot_rows`` in its place is a
+    serial loop of ``slots`` row writes a leaf
+    (tests/test_tpu_compile.py holds that none is left).
+
+    DROP RULE: a position outside ``[0, max_len)`` equals no index, so the
+    table is left as it was: a freed slot frozen at ``max_len``, a pad
+    row of a chunk bucket past the table, a negative position."""
+    hit = jnp.arange(table.shape[1])[None, :] == pos
+    hit = hit.reshape(hit.shape + (1,) * (table.ndim - 2))
+    return jnp.where(hit, update.astype(table.dtype), table)
 
 
 def apply_rope(x, pos, base: float = 10000.0):
@@ -130,7 +165,8 @@ class CausalSelfAttention(nn.Module):
     decode_slots: bool = False   # serving mode: the batch dim is a SLOT
                                # table (serving/kv_cache.py) — the caller
                                # passes per-slot write positions, cache
-                               # writes go row by row (write_slot_rows),
+                               # writes go row by row (select_slot_row,
+                               # write_slot_rows),
                                # and validity is length-driven, so one
                                # compiled decode step advances slots of
                                # any age
@@ -225,13 +261,13 @@ class CausalSelfAttention(nn.Module):
                 # SLOT decode (serving/kv_cache.py): each batch row is an
                 # independent slot with its own age.  The write index is
                 # the caller-supplied per-slot position (= the slot's
-                # current length), the write ``write_slot_rows`` (each
-                # slot's row updated in place, in the table's own layout;
-                # a position at or past max_len is DROPPED there, not
-                # clamped), and the validity mask length-driven — so the
-                # SAME compiled step advances a slot mid-prefill-history
-                # and a slot hundreds of tokens deep at once.  No
-                # cursor/overflow variables:
+                # current length), the write ``select_slot_row`` for one
+                # token and ``write_slot_rows`` for a block or int8 (both
+                # leave the table in its own layout; a position at or
+                # past max_len is DROPPED there, not clamped), and the
+                # validity mask length-driven — so the SAME compiled step
+                # advances a slot mid-prefill-history and a slot hundreds
+                # of tokens deep at once.  No cursor/overflow variables:
                 # positions are external state owned by the serving
                 # engine, which guards capacity at admission time
                 # (prompt + max_new_tokens ≤ max_len — the host-side
@@ -349,15 +385,15 @@ class CausalSelfAttention(nn.Module):
                                           causal=True)
                 elif x.shape[1] == 1 and not self.kv_quant:
                     idx = pos[:, 0]
-                    # cast to the table's dtype: the serving engine may
-                    # store the KV table narrower than the compute dtype
-                    # (SlotKVCache kv_dtype — bf16 halves KV memory); a
-                    # same-dtype astype is the identity, so the default
-                    # program is untouched
-                    ck.value = write_slot_rows(
-                        ck.value, k.astype(ck.value.dtype), pos)
-                    cv.value = write_slot_rows(
-                        cv.value, v.astype(cv.value.dtype), pos)
+                    # the one-token step (kv_decode_step, and the scans of
+                    # advance_multi and the chunked prefill), one form for
+                    # all three: a position outside the table is dropped
+                    # by the helper, which also casts the row to the
+                    # table's dtype (the serving engine may store the KV
+                    # table narrower than the compute dtype: SlotKVCache
+                    # kv_dtype — bf16 halves KV memory)
+                    ck.value = select_slot_row(ck.value, k, pos)
+                    cv.value = select_slot_row(cv.value, v, pos)
                     valid = (jnp.arange(self.max_len)[None, :]
                              <= idx[:, None]).astype(self.dtype)
                     out = dense_attention(
@@ -366,10 +402,12 @@ class CausalSelfAttention(nn.Module):
                 else:
                     # token-block write (speculative verify) and/or int8
                     # storage: write every position's K/V (+ scale) with
-                    # the same ``write_slot_rows`` as the branch above,
-                    # then attend each query against the table under its
-                    # own position mask — the L == 1 case of this path is
-                    # the same math as that branch
+                    # ``write_slot_rows`` (no cell serves this branch; a
+                    # select over L positions or four leaves is not the
+                    # branch above's one fused pass), then attend each
+                    # query against the table under its own position mask
+                    # — the L == 1 case of this path is the same math as
+                    # that branch and leaves the same table
                     idx = pos                       # (B, L)
                     if self.kv_quant:
                         qk, sk = compression.int8_channel_encode(k)

@@ -475,13 +475,15 @@ class SlotKVCache:
             return {"active": active} if self.state_leaves else {}
 
         def step(params, cache, tokens, lengths, active, rng):
-            # ROWS: write index = current length, written by models/gpt.py
-            # ``write_slot_rows``: each slot's row in place in the donated
-            # table (no copy of a table leaf: tests/test_tpu_compile.py).
+            # ROWS: write index = current length, written by the model:
+            # models/gpt.py ``select_slot_row`` (a select fused into the
+            # attention's pass over the donated table: no loop of row
+            # writes, no copy of a table leaf: tests/test_tpu_compile.py),
+            # the latent and hybrid models ``write_slot_rows``.
             # Inactive (free) slots write garbage into their own rows
             # only, which the next insert's prefill overwrites — validity
             # is length-driven, so stale positions are never attended —
-            # and a slot freed at length max_len writes nothing: the
+            # and a slot freed at length max_len writes nothing: either
             # helper DROPS a position past the table.  STATE has no such
             # argument (a write IS an advance): the model keeps it where
             # ``active`` is false.  The advanced token AND length vectors
@@ -587,11 +589,12 @@ class SlotKVCache:
         first generated token, exactly as in the monolithic prefill.
         Padding past ``n_valid`` writes garbage K/V that the next chunk
         (which starts at ``start+n_valid``) or decode overwrites, and
-        pad rows whose position runs past ``max_len`` are dropped — the
-        drop rule lives in models/gpt.py ``write_slot_rows`` (the
-        scatter's own out-of-bounds rule; tests/test_serving.py holds it
-        against a clamping write, which would overwrite the real token at
-        ``max_len - 1``)."""
+        pad rows whose position runs past ``max_len`` are dropped — for
+        this one-token path the drop rule lives in models/gpt.py
+        ``select_slot_row`` (a position past the table equals no index of
+        the ``(1, max_len, ...)`` sub-table; tests/test_serving.py holds
+        it against a clamping write, which would overwrite the real token
+        at ``max_len - 1``), for int8 storage in ``write_slot_rows``."""
         dm = self.dm
 
         def chunk(params, cache, slot, tokens, start, n_valid, rng):

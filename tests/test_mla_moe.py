@@ -435,15 +435,18 @@ KEPT = ("while", "scatter", "dot_general", "dynamic_update_slice",
 
 
 @pytest.mark.parametrize("program, total, kept", [
-    ("kv_decode_step", 407, (0, 4, 17, 0, 1, 2)),
+    ("kv_decode_step", 422, (0, 0, 17, 0, 1, 2)),
     ("kv_prefill_batched_l8", 499, (0, 0, 17, 8, 5, 3)),
 ])
 def test_gpt_slot_programs_lower_to_what_they_did(program, total, kept):
     """The operation counts of the lowered programs of a tiny ``GPTLM``
-    (2 layers, 4 slots x 32).  The step's were read off commit 27a7b5f,
-    before the table learnt a second model, and have not moved since: no
-    ``while``, nothing new returned.  The prefill's are PR 30's block
-    program: no ``while`` over the prompt and no scatter (the scan of the
+    (2 layers, 4 slots x 32).  The step's are PR 32's: the 4 scatters
+    that wrote the new K/V rows are 4 selects over the position axis
+    (``models/gpt.select_slot_row``; 407 operations with the scatters,
+    unmoved since commit 27a7b5f, before the table learnt a second
+    model), the same 17 matrix products, no ``while``, nothing new
+    returned.  The prefill's are PR 30's block program: no ``while``
+    over the prompt and no scatter (the scan of the
     one-token step had one ``while``, 4 scatters and 489 operations), the
     same 17 matrix products, and the block's K and V in 4 of the 8
     ``dynamic_update_slice`` (the other 4 put the slot's leaves back)."""

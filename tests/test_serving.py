@@ -1238,26 +1238,50 @@ def test_kv_dtype_surfaces_in_serve_summary(model_params):
 
 
 def _two_index_scatter(table, update, pos):
-    """The slot table's write before ``write_slot_rows``: the oracle."""
+    """The slot table's write as plain indexing, the oracle for
+    ``select_slot_row`` and ``write_slot_rows``: ``table[b, pos[b, j]] =
+    update[b, j]``, a position past ``max_len`` dropped.  Indexing would
+    wrap a negative position; the table's rule drops it like one past the
+    end, so it is sent there."""
     rows = jnp.arange(table.shape[0])[:, None]
-    return table.at[rows, pos].set(update)
+    pos = jnp.where(pos < 0, table.shape[1], pos)
+    return table.at[rows, pos].set(update, mode="drop")
 
 
-@pytest.mark.parametrize("past_end", [False, True],
-                         ids=["in_range", "past_max_len"])
+def _write_case_starts(case, max_len, width):
+    """First position of each slot's block of ``width`` tokens."""
+    return {
+        "in_range": [0, 5, max_len - width, 17],
+        # slot 0 ends on the table's last cell, slots 1 and 2 lie past it
+        "past_max_len": [max_len - 1, max_len, max_len + 5, 7],
+        # a wrap would hit max_len - 1, a clamp position 0
+        "negative": [-1, -max_len, 9, -width - 3],
+        # ``_chunk`` hands the scan one slot's (1, max_len, ...) sub-table,
+        # and its bucket's pad rows run past the table
+        "sub_table": [11],
+        "sub_table_past_max_len": [max_len],
+    }[case]
+
+
+@pytest.mark.parametrize("case", ["in_range", "past_max_len", "negative",
+                                  "sub_table", "sub_table_past_max_len"])
 @pytest.mark.parametrize("width", [1, 3], ids=["one_token", "token_block"])
 @pytest.mark.parametrize("kv_dtype", ["float32", "bfloat16", "int8"])
-def test_slot_table_write_matches_two_index_scatter(monkeypatch, kv_dtype,
-                                                    width, past_end):
-    """``models/gpt.write_slot_rows`` through both monolithic branches of
-    the slot-decode attention (one token; token block, which int8 always
-    takes) leaves the table bitwise as the old ``.at[rows, pos].set`` did,
-    and keeps its DROP RULE: a position at or past ``max_len`` changes
-    nothing — position ``max_len - 1``, where ``dynamic_update_slice``
-    would clamp to, keeps the real token it held."""
-    from distributed_tensorflow_tpu.models import gpt as gpt_mod
+def test_slot_table_write_matches_two_index_scatter(kv_dtype, width, case):
+    """Both monolithic branches of the slot-decode attention (one token:
+    ``models/gpt.select_slot_row``; token block, which int8 always takes:
+    ``write_slot_rows``) leave the table bitwise as ``.at[rows, pos].set``
+    of the same K/V rows leaves it, and keep the DROP RULE: a position
+    outside ``[0, max_len)`` changes nothing — position ``max_len - 1``,
+    where ``dynamic_update_slice`` would clamp to and a negative index
+    wrap to, keeps the real token it held.  The oracle is applied to the
+    rows the attention's own ``key`` / ``value`` projections gave in the
+    same call, not to a second run of the model."""
+    from distributed_tensorflow_tpu.parallel import compression
 
-    slots, max_len = 4, 32
+    max_len = 32
+    start = np.asarray(_write_case_starts(case, max_len, width))
+    slots = len(start)
     dm = tiny_gpt(max_len=max_len).clone(
         decode=True, decode_slots=True, attention_impl="dense",
         kv_quant=kv_dtype == "int8")
@@ -1273,34 +1297,43 @@ def test_slot_table_write_matches_two_index_scatter(monkeypatch, kv_dtype,
         return jnp.asarray(rng.normal(size=leaf.shape), dtype)
 
     before = jax.tree.map(filled, variables["cache"])
-    start = (np.array([max_len - 1, max_len, max_len + 5, 7]) if past_end
-             else np.array([0, 5, max_len - width, 17]))
     pos = jnp.asarray(start[:, None] + np.arange(width)[None, :], jnp.int32)
     toks = jnp.asarray(rng.integers(0, 64, (slots, width)), jnp.int32)
+    _, upd = dm.apply(
+        {"params": variables["params"], "cache": before}, toks, train=False,
+        positions=pos, mutable=["cache", "intermediates"],
+        capture_intermediates=lambda m, _: m.name in ("key", "value"))
+    got = upd["cache"]
 
-    def run():
-        _, upd = dm.apply({"params": variables["params"], "cache": before},
-                          toks, train=False, positions=pos,
-                          mutable=["cache"])
-        return upd["cache"]
+    def want(path, table):
+        """The oracle on one leaf: its layer's projected rows, stored the
+        way the leaf stores them."""
+        *layer, name = [k.key for k in path]
+        rows = upd["intermediates"]
+        for k in layer + ["key" if "key" in name else "value", "__call__"]:
+            rows = rows[k]
+        rows = rows[0].reshape(slots, width, dm.heads, -1)
+        if kv_dtype == "int8":
+            q, scale = compression.int8_channel_encode(rows)
+            rows = scale if name.endswith("_scale") else q
+        return _two_index_scatter(table, rows.astype(table.dtype), pos)
 
-    got = run()
-    monkeypatch.setattr(gpt_mod, "write_slot_rows", _two_index_scatter)
-    want = run()
+    wanted = jax.tree_util.tree_map_with_path(want, before)
     changed = 0
-    for b, g, w in zip(*map(jax.tree.leaves, (before, got, want))):
+    for b, g, w in zip(*map(jax.tree.leaves, (before, got, wanted))):
         assert g.dtype == b.dtype and g.shape == b.shape
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
         b, g = np.asarray(b), np.asarray(g)
         for s in range(slots):
-            live = [int(p) for p in np.asarray(pos[s]) if p < max_len]
+            live = [int(p) for p in np.asarray(pos[s]) if 0 <= p < max_len]
             rest = np.setdiff1d(np.arange(max_len), live)
             np.testing.assert_array_equal(g[s, rest], b[s, rest])
             changed += int((g[s, live] != b[s, live]).any())
     # the in-range rows did take their tokens (the check above is not
-    # vacuous), and with past_end two slots were dropped whole
-    leaves = len(jax.tree.leaves(before))
-    assert changed == leaves * (2 if past_end else slots)
+    # vacuous), and a slot whose block lies outside the table was dropped
+    # whole
+    in_table = ((np.asarray(pos) >= 0) & (np.asarray(pos) < max_len)).any(1)
+    assert changed == len(jax.tree.leaves(before)) * int(in_table.sum())
 
 
 @pytest.mark.slow    # round 20 fast-lane repair: kv-dtype threading

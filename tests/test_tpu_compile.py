@@ -65,14 +65,20 @@ def _shapes_on(sharding):
 def test_decode_step_writes_the_slot_table_in_place(one_chip):
     """The serve cell's decode step (gpt2-large widths, 32 slots x 1024,
     bf16 table, float32 parameters, greedy, the table donated) at 2 layers:
-    the v5e compiler keeps every table leaf in the layout it arrived in.
+    the v5e compiler keeps every table leaf in the layout it arrived in,
+    and the new K/V row reaches it inside the attention's own two passes.
 
     The table is ``bf16[32,1024,20,64]`` with ``max_len`` as the minor
-    dimension on the chip.  A write the compiler cannot do in that layout
-    costs two ``copy`` of the leaf (84 MB, 268 MB when re-laid with heads
-    and head_dim minor) and holds both as temporaries: 144 copies, 7.45 GB
-    of temporaries and 50 GB moved a step at the cell's 36 layers, which
-    is what ``.at[rows, pos].set`` did (PERF.md section 5).  The vocabulary
+    dimension on the chip.  (i) A write the compiler cannot do in that
+    layout costs two ``copy`` of the leaf (84 MB, 268 MB when re-laid with
+    heads and head_dim minor) and holds both as temporaries: 144 copies,
+    7.45 GB of temporaries and 50 GB moved a step at the cell's 36 layers,
+    which is what ``.at[rows, pos].set`` did.  (ii) A scatter it can do in
+    that layout (``write_slot_rows``, until PR 32) is a ``while`` of 32
+    ``dynamic-update-slice`` a leaf, one position's 1,280 values in 1,280
+    tiles: 2,304 serial iterations, nearly half of a 35 ms round on the
+    chip (PERF.md section 6).  ``select_slot_row`` leaves neither: no ``copy``
+    of a leaf, no ``while``, no ``dynamic-update-slice``.  The vocabulary
     is cut to 8,192: the bf16 copy of the tied embedding is a temporary of
     its own, 129 MB at 50,257, and not what this test is about."""
     slots, max_len, heads, head_dim = 32, 1024, 20, 64
@@ -96,9 +102,12 @@ def test_decode_step_writes_the_slot_table_in_place(one_chip):
     leaf_bytes = slots * max_len * heads * head_dim * 2
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes >= 4 * leaf_bytes     # donated
+    text = compiled.as_text()
     leaf = rf"bf16\[{slots},{max_len},{heads},{head_dim}\]"
-    copies = re.findall(rf"= {leaf}\{{[^}}]*\}} copy\(", compiled.as_text())
+    copies = re.findall(rf"= {leaf}\{{[^}}]*\}} copy\(", text)
     assert not copies, f"{len(copies)} relayout copies of a table leaf"
+    assert not re.findall(r" while\(", text)
+    assert "dynamic-update-slice" not in text
     assert memory.temp_size_in_bytes < leaf_bytes, (
         memory.temp_size_in_bytes, leaf_bytes)
 
