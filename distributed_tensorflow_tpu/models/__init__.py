@@ -70,7 +70,14 @@ def _fashion_mlp(num_classes: int = 10, **kw) -> nn.Module:
 
 
 def create_model(name: str, num_classes: int = 10, **kw) -> nn.Module:
-    """Instantiate a registered model (lazy imports keep startup light)."""
+    """Instantiate a registered model (lazy imports keep startup light).
+
+    Beside the registry's classifiers: ``resnet20``, ``bert_tiny``, ``moe``
+    and the language models ``gpt`` (``models/gpt.GPTLM``), ``mla_moe``
+    (latent attention, sparse experts), ``hybrid_ssm`` (state-space,
+    attention and latent-expert mixers by a pattern) and ``window_moe``
+    (window and full attention by a pattern, a parallel block, averaged
+    shared experts); the four serve through ``serving.SlotKVCache``."""
     if "dtype" in kw:
         kw["dtype"] = resolve_dtype(kw["dtype"])
     if name in ("resnet20", "resnet"):
@@ -107,10 +114,17 @@ def create_model(name: str, num_classes: int = 10, **kw) -> nn.Module:
             kw["param_dtype"] = resolve_dtype(kw["param_dtype"])
         kw.setdefault("vocab_size", num_classes)
         return HybridSSMLM(**kw)
+    if name == "window_moe":
+        from distributed_tensorflow_tpu.models.window_moe import WindowMoELM
+
+        if "param_dtype" in kw:
+            kw["param_dtype"] = resolve_dtype(kw["param_dtype"])
+        kw.setdefault("vocab_size", num_classes)
+        return WindowMoELM(**kw)
     if name not in _REGISTRY:
         raise KeyError(f"unknown model '{name}'; known: {sorted(_REGISTRY)} "
                        f"+ resnet20, bert_tiny, moe, gpt, mla_moe, "
-                       f"hybrid_ssm")
+                       f"hybrid_ssm, window_moe")
     return _REGISTRY[name](num_classes=num_classes, **kw)
 
 

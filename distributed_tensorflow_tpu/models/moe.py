@@ -208,6 +208,11 @@ class DroplessMoE(nn.Module):
     * ``"relu2"``: ``W_down relu(W_up x)^2``, two matrices and no gate.
 
     ``E_i`` has width ``hidden``, ``S`` width ``shared_hidden`` (0 = none).
+    With ``shared_experts = m > 1`` the shared part is the MEAN of ``m``
+    shared experts of width ``shared_hidden / m`` each, ``(1/m) sum_j
+    S_j(x)``, held as one product of width ``shared_hidden`` (the ``m``
+    gate / up matrices side by side, the ``m`` down matrices stacked: the
+    same sum) whose output is divided by ``m``.
     With ``latent`` the routed experts work in a latent of that width: the
     router and the shared expert read ``x``, the experts read ``u = x
     W_dn`` and write latents, and their weighted sum goes through ``W_up``
@@ -229,7 +234,16 @@ class DroplessMoE(nn.Module):
     expert matrices applied as grouped products over the group sizes.  No
     capacity: every pair is computed.  Each token's expert choice is sown
     as ``expert_choice`` into ``intermediates`` (the serving engine counts
-    routing load from it)."""
+    routing load from it).
+
+    ``token_block`` bounds the temporaries of a long block of tokens (a
+    prefill bucket): the router scores all ``t`` tokens at once, and where
+    ``t`` exceeds ``token_block`` everything after it (sort, gather,
+    grouped products, shared expert) runs over ``token_block`` tokens at a
+    time, so that the ``token_block * top_k`` gathered rows and their four
+    companions are all that is live.  The result is the same sum.  With
+    ``shared_experts`` 1 and ``token_block`` 0 the layer is what it was
+    before it knew either."""
 
     num_experts: int = 8
     top_k: int = 2
@@ -240,6 +254,8 @@ class DroplessMoE(nn.Module):
     held: tuple[int, int] | None = None
     expert_act: str = "swiglu"
     latent: int = 0
+    shared_experts: int = 1
+    token_block: int = 0
     dtype: jnp.dtype = jnp.float32
     param_dtype: jnp.dtype = jnp.float32
 
@@ -270,52 +286,78 @@ class DroplessMoE(nn.Module):
         weight = weight * self.routed_scale
         self.sow("intermediates", "expert_choice", choice)
 
-        # --- sort the pairs by held expert; the rest sort last -----------
         local = choice - first
         here = (local >= 0) & (local < n)
         if valid is not None:
             here = here & valid[:, None]
-        local = jnp.where(here, local, n).reshape(-1)           # [T*k]
-        order = jnp.argsort(local, stable=True)
-        sizes = jnp.bincount(local, length=n + 1)[:n].astype(jnp.int32)
+
+        # modules and expert weights are made once, where first used, and
+        # shared by every token block
+        made = {}
+
+        def once(name, make):
+            if name not in made:
+                made[name] = make()
+            return made[name]
 
         def project(width, name):
-            return nn.Dense(width, use_bias=False, dtype=self.dtype,
-                            param_dtype=self.param_dtype, name=name)
+            return once(name, lambda: nn.Dense(
+                width, use_bias=False, dtype=self.dtype,
+                param_dtype=self.param_dtype, name=name))
 
-        # what the routed experts read: x, or its latent
-        u = project(self.latent, "latent_down")(x) if self.latent \
-            else x.astype(self.dtype)
-        w = u.shape[-1]
-        xs = u[order // k]                                      # [T*k, w]
+        def tail(x, local, here, weight):
+            """Everything after the router, over one block of tokens."""
+            t = x.shape[0]
+            # --- sort the pairs by held expert; the rest sort last -------
+            local = jnp.where(here, local, n).reshape(-1)       # [T*k]
+            order = jnp.argsort(local, stable=True)
+            sizes = jnp.bincount(local, length=n + 1)[:n].astype(jnp.int32)
 
-        def grouped(rows, name, shape):
-            weights = self.param(name, init, (n,) + shape,
-                                 self.param_dtype).astype(self.dtype)
-            return jax.lax.ragged_dot(rows, weights, sizes)
+            # what the routed experts read: x, or its latent
+            u = project(self.latent, "latent_down")(x) if self.latent \
+                else x.astype(self.dtype)
+            w = u.shape[-1]
+            xs = u[order // k]                                  # [T*k, w]
 
-        if self.expert_act == "swiglu":
-            gate = grouped(xs, "w_gate", (w, self.hidden))
-            up = grouped(xs, "w_up", (w, self.hidden))
-            act = jax.nn.silu(gate) * up
-        else:
-            act = jnp.square(jax.nn.relu(
-                grouped(xs, "w_up", (w, self.hidden))))
-        ys = grouped(act, "w_down", (self.hidden, w))
-        # rows past the last group belong to no expert here: whatever the
-        # grouped product left there is not a result
-        ys = jnp.where((jnp.arange(t * k) < sizes.sum())[:, None], ys, 0)
-        ys = ys[jnp.argsort(order)].reshape(t, k, w)            # unsort
-        y = jnp.einsum("tkd,tk->td", ys.astype(jnp.float32),
-                       jnp.where(here, weight, 0.0))
-        y = y.astype(self.dtype)
-        if self.latent:
-            y = project(d, "latent_up")(y)
-        if self.shared_hidden:
-            shared = SwiGLU if self.expert_act == "swiglu" else ReLU2MLP
-            y = y + shared(self.shared_hidden, self.dtype, self.param_dtype,
-                           name="shared")(x)
-        return y
+            def grouped(rows, name, shape):
+                weights = once(name, lambda: self.param(
+                    name, init, (n,) + shape, self.param_dtype))
+                return jax.lax.ragged_dot(rows, weights.astype(self.dtype),
+                                          sizes)
+
+            if self.expert_act == "swiglu":
+                gate = grouped(xs, "w_gate", (w, self.hidden))
+                up = grouped(xs, "w_up", (w, self.hidden))
+                act = jax.nn.silu(gate) * up
+            else:
+                act = jnp.square(jax.nn.relu(
+                    grouped(xs, "w_up", (w, self.hidden))))
+            ys = grouped(act, "w_down", (self.hidden, w))
+            # rows past the last group belong to no expert here: whatever
+            # the grouped product left there is not a result
+            ys = jnp.where((jnp.arange(t * k) < sizes.sum())[:, None], ys, 0)
+            ys = ys[jnp.argsort(order)].reshape(t, k, w)        # unsort
+            y = jnp.einsum("tkd,tk->td", ys.astype(jnp.float32),
+                           jnp.where(here, weight, 0.0))
+            y = y.astype(self.dtype)
+            if self.latent:
+                y = project(d, "latent_up")(y)
+            if self.shared_hidden:
+                kind = SwiGLU if self.expert_act == "swiglu" else ReLU2MLP
+                shared = once("shared", lambda: kind(
+                    self.shared_hidden, self.dtype, self.param_dtype,
+                    name="shared"))(x)
+                if self.shared_experts > 1:
+                    shared = shared / self.shared_experts
+                y = y + shared
+            return y
+
+        size = self.token_block
+        if not size or t <= size:
+            return tail(x, local, here, weight)
+        return jnp.concatenate(
+            [tail(*(a[lo:lo + size] for a in (x, local, here, weight)))
+             for lo in range(0, t, size)], axis=0)
 
 
 class ReLU2MLP(nn.Module):

@@ -353,7 +353,8 @@ def serve_waterfall(records: list[dict]) -> dict[str, Any]:
 
     ``windows`` holds one entry per ``serve_run`` span with the table's
     counters over that window (``cache_bytes_per_token``,
-    ``state_bytes_per_slot``, ``expert_assignments``)."""
+    ``state_bytes_per_slot``, ``window_bytes_per_slot``,
+    ``expert_assignments``)."""
     rows: list[dict[str, Any]] = []
     windows: list[dict[str, Any]] = []
     chunk_recs: list[dict[str, Any]] = []
@@ -365,7 +366,8 @@ def serve_waterfall(records: list[dict]) -> dict[str, Any]:
         if kind == "span" and rec.get("name") == "serve_run":
             windows.append({k: rec.get(k) for k in (
                 "t", "dur_s", "offered", "slots", "cache_bytes_per_token",
-                "state_bytes_per_slot", "expert_assignments")})
+                "state_bytes_per_slot", "window_bytes_per_slot",
+                "expert_assignments")})
         if rid is None:
             continue
         if kind == "event" and rec.get("name") == "requeue":
@@ -522,11 +524,13 @@ def render_waterfall_text(wf: dict[str, Any], width: int = 60) -> str:
                    f"| shed (429) at depth {s.get('queue_depth')}")
     for w in wf.get("windows", ()):
         if w.get("cache_bytes_per_token") is not None:
+            rings = f"{w['window_bytes_per_slot']} of rings, " \
+                if w.get("window_bytes_per_slot") else ""
             out.append(f"window of {w.get('offered')} offered on "
                        f"{w.get('slots')} slots: table "
                        f"{w['cache_bytes_per_token']} bytes a token, "
                        f"{w.get('state_bytes_per_slot') or 0} bytes of "
-                       f"state a slot, "
+                       f"state a slot, {rings}"
                        f"{w.get('expert_assignments')} expert assignments")
     out.append(f"legend: .=queue =prefill #=decode x=shed >=requeue; "
                f"{wf['requests_n']} served, {wf['shed_n']} shed, "

@@ -12,19 +12,45 @@ decode program never recompiles.
 
 Device-side contract (everything else lives in serving/scheduler.py):
 
-* the cache is a pytree with the slot dim first on every leaf, and TWO
-  kinds of leaf, which the served model tells apart (its ``slot_state``
-  names the second kind; a model without that attribute keeps the first
-  kind only):
+* the cache is a pytree with the slot dim first on every leaf, and THREE
+  kinds of leaf, which the served model tells apart (its ``slot_rings``
+  names the second kind and its ``slot_state`` the third; a model with
+  neither attribute keeps the first kind only):
 
   - per-position ROWS ``(slots, max_len, ...)``: per-head keys and values
-    ``(slots, max_len, kv_heads, head_dim)`` for models/gpt.py and for the
-    attention layers of models/hybrid_ssm.py, a latent and one rotated
-    key head ``(slots, max_len, rank)`` for models/mla_moe.py.  Validity
-    is LENGTH-DRIVEN: a row at or past the slot's length is never
-    attended, so a stale row, a pad row of a prefill bucket and the row a
-    free or excluded slot writes at its own length are all invisible, and
-    the next real write lands over them;
+    ``(slots, max_len, kv_heads, head_dim)`` for models/gpt.py, for the
+    attention layers of models/hybrid_ssm.py and for the full-attention
+    layers of models/window_moe.py, a latent and one rotated key head
+    ``(slots, max_len, rank)`` for models/mla_moe.py.  Validity is
+    LENGTH-DRIVEN: a row at or past the slot's length is never attended,
+    so a stale row, a pad row of a prefill bucket and the row a free or
+    excluded slot writes at its own length are all invisible, and the
+    next real write lands over them;
+  - per-position RINGS ``(slots, ring, ...)`` with ``ring`` rows a slot
+    whatever ``max_len`` is: the keys and values of a layer whose queries
+    see the last ``ring`` positions only (the window layers of
+    models/window_moe.py; ``slot_rings`` gives each leaf's ``ring``).
+    Position ``p`` lives in row ``p mod ring``.  Validity is length-driven
+    by ANOTHER rule: rows ``0 .. min(length, ring) - 1`` are valid and
+    hold the slot's last ``min(length, ring)`` positions, in an order
+    that does not matter (keys are stored with their position term
+    applied).  A stale row past the length is invisible as above, and a
+    short request that takes the slot of a long one finds its rows stale
+    beyond its own length only.  But a row BELOW the length is not
+    protected by the length: a PREFILL must put exactly the positions
+    ``max(0, n - ring) .. n - 1`` of an ``n``-token prompt there and
+    nothing for its bucket's pads (pad position ``p`` would land on the
+    row of the real position ``p - ring``), and the STEP writes row
+    ``length mod ring``, the row of the one position that has just left
+    every later query's window; a free or excluded slot's write there is
+    covered by its own next real write before anything reads it.  What
+    moves validity backwards or copies a slot by rows of ``max_len``
+    cannot hold over a ring (the row a rewind would uncover is
+    overwritten): ``rewind``, ``commit_block`` / ``verify_block``, the
+    prefix pool, chunk resume, the paged layout, multi-step dispatch and
+    KV handoff raise ``NotImplementedError`` by name for a model with
+    rings (int8 storage and a tensor-parallel table are refused by the
+    model's own ``slot_decode_clone``);
   - per-slot STATE ``(slots, ...)`` with no position axis: the recurrent
     state ``(slots, heads, head_dim, state)`` and the convolution tail
     ``(slots, taps - 1, width)`` of a state-space layer
@@ -97,6 +123,7 @@ oracle is tolerance-based, the one serving feature with that caveat).
 from __future__ import annotations
 
 import hashlib
+import inspect
 import time
 from collections import OrderedDict
 
@@ -142,7 +169,8 @@ class BlockPoolExhausted(RuntimeError):
 class SlotKVCache:
     """Fixed slot table + compiled prefill/decode programs for one model
     with a slot-decode mode (``models/gpt.GPTLM``,
-    ``models/mla_moe.LatentMoELM``, ``models/hybrid_ssm.HybridSSMLM``).
+    ``models/mla_moe.LatentMoELM``, ``models/hybrid_ssm.HybridSSMLM``,
+    ``models/window_moe.WindowMoELM``).
 
     ``model`` is the TRAINING-mode module (any attention impl); its
     ``slot_decode_clone`` gives the module served from — for ``GPTLM``
@@ -155,7 +183,8 @@ class SlotKVCache:
     speculative verify, KV handoff — raises ``NotImplementedError`` by
     name for a model without one (``resumable_step`` on the model class),
     and so does what moves validity by bookkeeping for a model that keeps
-    per-slot state (``slot_state`` on the model class).
+    per-slot state (``slot_state`` on the model class) or rings
+    (``slot_rings``).
     ``params`` may be a TP engine's committed TrainState params (used in
     place) or host/single-device params (replicated).
 
@@ -223,9 +252,11 @@ class SlotKVCache:
                    and meshlib.MODEL_AXIS in mesh.axis_names)
         self.resumable_step = model.resumable_step
         # the leaves that are per-slot state and not per-position rows,
-        # by name, and whether ``kv_dtype`` may narrow each (module
-        # docstring: the two kinds)
+        # by name, and whether ``kv_dtype`` may narrow each; the leaves
+        # that are rings, by name, and their rows (module docstring: the
+        # three kinds)
         self.state_leaves = dict(getattr(model, "slot_state", {}))
+        self.ring_leaves = dict(getattr(model, "slot_rings", {}))
         # what ``insert`` runs (the ``prefill`` span's ``form``): one call
         # over the padded prompt, or the chunk scan the prefix pool needs
         self.prefill_form = "scan" if prefix_cache_blocks else "batched"
@@ -371,8 +402,16 @@ class SlotKVCache:
     def _rows_only(self, feature: str) -> None:
         """What moves a slot's validity by bookkeeping, or copies a slot
         by its rows, is refused by name for a model that keeps per-slot
-        state: a recurrent state cannot be taken back or resumed from a
-        position, and no snapshot of it is built."""
+        state (a recurrent state cannot be taken back or resumed from a
+        position, and no snapshot of it is built) and for a model that
+        keeps rings (the row a shorter length would uncover has been
+        overwritten, and a ring has no rows of ``max_len`` to copy)."""
+        if self.ring_leaves:
+            raise NotImplementedError(
+                f"{feature} is not implemented for a model that keeps "
+                f"rings of its last positions beside its full-length rows "
+                f"(models/window_moe.py: {sorted(self.ring_leaves)}): the "
+                f"monolithic table with insert/advance/evict is")
         if self.state_leaves:
             raise NotImplementedError(
                 f"{feature} is not implemented for a model that keeps "
@@ -469,10 +508,15 @@ class SlotKVCache:
     def _build_step(self):
         dm = self.dm
 
+        # a model whose call takes ``active`` is handed it: one with
+        # per-slot state keeps the state of a slot that is not active bit
+        # for bit, one with routed experts may keep such a slot's stale
+        # token from every expert
+        takes_active = "active" in inspect.signature(
+            type(dm).__call__).parameters
+
         def hold(active) -> dict:
-            # a model with per-slot state is handed ``active``: it keeps
-            # the state of a slot that is not active bit for bit
-            return {"active": active} if self.state_leaves else {}
+            return {"active": active} if takes_active else {}
 
         def step(params, cache, tokens, lengths, active, rng):
             # ROWS: write index = current length, written by the model:
@@ -1508,30 +1552,37 @@ class SlotKVCache:
         through a prefill program and the positions those programs ran
         (pads included), the (token, expert) routing assignments made to
         experts held here (0 for a model without experts), what one token
-        of one slot keeps in the table's per-position rows, all layers
-        together, and what one slot keeps as per-slot state whatever its
-        length (0 for a model without state)."""
-        state = self._table_bytes()[1]
+        of one slot keeps in the table's full-length per-position rows,
+        all layers together, what one slot keeps as per-slot state
+        whatever its length (0 for a model without state) and what it
+        keeps in ring rows whatever its length (0 for a model without
+        rings)."""
+        _, state, rings = self._table_bytes()
         return {"prefill_tokens_computed": self.prefill_tokens_computed,
                 "prefill_tokens_padded": self.prefill_tokens_padded,
                 "expert_assignments": self.expert_assignments,
                 # (the paged layout counts the blocks that back live slots)
                 "cache_bytes_per_token":
-                    (self.kv_bytes_per_slot() - state // self.slots)
-                    // self.max_len,
-                "state_bytes_per_slot": state // self.slots}
+                    (self.kv_bytes_per_slot()
+                     - (state + rings) // self.slots) // self.max_len,
+                "state_bytes_per_slot": state // self.slots,
+                "window_bytes_per_slot": rings // self.slots}
 
-    def _table_bytes(self) -> tuple[int, int]:
-        """Stored bytes of the whole table by kind of leaf: ``(per-position
-        rows, per-slot state)``."""
-        rows = state = 0
+    def _table_bytes(self) -> tuple[int, int, int]:
+        """Stored bytes of the whole table by kind of leaf: ``(full-length
+        per-position rows, per-slot state, rings)``."""
+        size = {"rows": 0, "state": 0, "rings": 0}
         for path, leaf in jax.tree_util.tree_leaves_with_path(self.cache):
-            size = int(leaf.size) * jnp.dtype(leaf.dtype).itemsize
-            if path[-1].key in self.state_leaves:
-                state += size
-            else:
-                rows += size
-        return rows, state
+            kind = "state" if path[-1].key in self.state_leaves else \
+                "rings" if path[-1].key in self.ring_leaves else "rows"
+            size[kind] += int(leaf.size) * jnp.dtype(leaf.dtype).itemsize
+        return size["rows"], size["state"], size["rings"]
+
+    def past_window(self) -> int:
+        """Active slots whose length exceeds the rings' rows: whose window
+        layers no longer hold every position."""
+        ring = min(self.ring_leaves.values())
+        return int(np.sum(self.lengths[self.active] > ring))
 
     def kv_bytes_per_slot(self) -> int:
         """Stored table bytes per serving slot: every cache leaf — K/V
@@ -1702,6 +1753,7 @@ class PagedSlotKVCache(SlotKVCache):
                    and meshlib.MODEL_AXIS in mesh.axis_names)
         self.resumable_step = model.resumable_step
         self.state_leaves = dict(getattr(model, "slot_state", {}))
+        self.ring_leaves = dict(getattr(model, "slot_rings", {}))
         self.prefill_form = "scan"      # insert goes through _chunk
         self._scan_model_only("the paged layout")
         # fused clone for the decode/verify hot ops, gather clone for the

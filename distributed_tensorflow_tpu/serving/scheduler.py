@@ -519,6 +519,8 @@ class ContinuousBatcher:
                 slot, first = kv.insert(req.prompt)
                 sp["padded_len"] = kv.prefill_tokens_padded - padded
                 sp["slot"] = slot
+                if kv.ring_leaves:      # rows written into each ring
+                    sp["ring_rows"] = min(lp, *kv.ring_leaves.values())
             self.clock.on_prefill(kv.prefill_tokens_computed - before)
             if self._rf_cost is not None:
                 # credit only positions actually computed: a prefix-cache
@@ -1064,6 +1066,8 @@ class ContinuousBatcher:
             # the decode step, and the gap to the next one is the stall
             with self.tracer.span("decode_step", active=len(live),
                                   slots=kv.slots) as sp:
+                if kv.ring_leaves:
+                    sp["past_window"] = kv.past_window()
                 toks = kv.advance()
                 if kv.last_routing is not None:     # a model with experts
                     sp.update(kv.last_routing)
@@ -1272,6 +1276,7 @@ class ContinuousBatcher:
                 counts = self.kv.counters()
                 sp["cache_bytes_per_token"] = counts["cache_bytes_per_token"]
                 sp["state_bytes_per_slot"] = counts["state_bytes_per_slot"]
+                sp["window_bytes_per_slot"] = counts["window_bytes_per_slot"]
                 sp["expert_assignments"] = (counts["expert_assignments"]
                                             - counts_before["expert_assignments"])
             wall_elapsed = time.perf_counter() - wall0

@@ -558,7 +558,8 @@ def test_default_run_records_one_root_and_one_request_each(recorded_window):
         "offered": 3, "slots": 2, "mode": "continuous",
         # the table's counters over the window, set as the root closes
         "cache_bytes_per_token": kv.kv_bytes_per_slot() // kv.max_len,
-        "state_bytes_per_slot": 0, "expert_assignments": 0}
+        "state_bytes_per_slot": 0, "window_bytes_per_slot": 0,
+        "expert_assignments": 0}
     assert sum(r["name"] == "serve_run" for r in recs) == 1
     requests = {r["rid"]: r for r in recs if r["name"] == "request"}
     assert sorted(requests) == [10, 11, 12]

@@ -206,3 +206,81 @@ def test_the_long_prefill_holds_no_score_tile_wider_than_the_key_block(
         compiled.as_text())}
     assert max(tiles) == mla_moe.ATTN_KEY_BLOCK, sorted(tiles)
     assert compiled.memory_analysis().temp_size_in_bytes < 1.2e9
+
+
+def _window_cell(one_chip, slots=32, max_len=16384):
+    """The window-and-global cell's table at its published widths
+    (``benchmarks/configs/command-a-plus-05-2026-4l-ep8.json``, 32 slots x
+    16,384, bfloat16): ``(probe, params, cache shapes on the chip)``.  The
+    probe is built at ONE slot (its table is real arrays here) and the
+    programs lowered at 32: they name no slot count."""
+    from benchmarks.drivers import window_moe_tree
+
+    config = json.loads((Path(__file__).resolve().parent.parent / "benchmarks"
+                         / "configs" / "command-a-plus-05-2026-4l-ep8.json"
+                         ).read_text())
+    model = create_model("window_moe", dtype="bfloat16",
+                         param_dtype="bfloat16",
+                         **window_moe_tree.model_kwargs(config, max_len))
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32),
+                           train=False))["params"]
+    kv = _ProgramProbe(model, params, 1, greedy=True, kv_dtype=jnp.bfloat16)
+    on_chip, like = _shapes_on(one_chip)
+    cache = jax.tree.map(
+        lambda t: on_chip((slots,) + t.shape[1:], t.dtype), kv.cache)
+    return kv, like(params), cache
+
+
+def test_rings_and_full_rows_fit_one_chip_and_the_step_copies_neither(
+        one_chip):
+    """``serve-commandaplus-mixedlen``'s decode step: 9.47 GB of weights and
+    3.76 GB of table (three layers of 4,096-row rings and one of 16,384
+    rows: four full-length tables would be 8.59 GB and would not fit beside
+    the weights) are the program's arguments, the table is donated, and the
+    temporaries stay under 0.5 GB: the eight new rows reach the table as
+    eight loops of in-place row writes, and no leaf is copied out of a
+    fusion (the attention re-lays its operand inside its own)."""
+    slots = 32
+    kv, params, cache = _window_cell(one_chip, slots)
+    step, jit_kwargs = kv.programs["kv_decode_step_routed"]
+    on_chip, like = _shapes_on(one_chip)
+    compiled = jax.jit(step, **jit_kwargs).lower(
+        params, cache, on_chip((slots,), jnp.int32),
+        on_chip((slots,), jnp.int32), on_chip((slots,), jnp.bool_),
+        like(jax.random.key(0))).compile()
+    memory = compiled.memory_analysis()
+    table = slots * (3 * 4096 + 16384) * 2 * 8 * 128 * 2
+    assert table == 3_758_096_384
+    assert memory.alias_size_in_bytes == table              # donated
+    assert 13.2e9 < memory.argument_size_in_bytes < 13.3e9
+    assert memory.temp_size_in_bytes < 0.5e9, memory.temp_size_in_bytes
+    entry = compiled.as_text().split("\nENTRY ")[1]
+    assert len(re.findall(r" while\(", entry)) == 8
+    assert not re.findall(
+        r"= bf16\[32,(?:4096|16384),8,128\]\{[^}]*\} copy\(", entry)
+
+
+def test_the_longest_bucket_fits_beside_weights_and_table(one_chip):
+    """``kv_prefill_batched_l16384`` of the same cell: with the expert
+    layer taken 2,048 tokens at a time and attention 512 queries against
+    512 keys at a time, the temporaries stay under 3 GB and the program
+    under the chip's 16 GiB (one 131,072-row gather of the sorted pairs
+    and its four companions alone would be 5.4 GB)."""
+    slots, lpad = 32, 16384
+    kv, params, cache = _window_cell(one_chip, slots)
+    kv._prefill(lpad)
+    prefill, jit_kwargs = kv.programs[f"kv_prefill_batched_l{lpad}"]
+    on_chip, like = _shapes_on(one_chip)
+    compiled = jax.jit(prefill, **jit_kwargs).lower(
+        params, cache, on_chip((), jnp.int32), on_chip((lpad,), jnp.int32),
+        on_chip((), jnp.int32), like(jax.random.key(0))).compile()
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == 3_758_096_384      # donated
+    assert memory.temp_size_in_bytes < 3.0e9, memory.temp_size_in_bytes
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < 16 * 2 ** 30)
+    # no score tile wider than the block, over all 128 heads at once
+    tiles = {int(keys) for keys in re.findall(
+        r"f32\[1,8,16,512,(\d+)\]", compiled.as_text())}
+    assert tiles and max(tiles) <= 512, sorted(tiles)
