@@ -284,3 +284,50 @@ def test_the_longest_bucket_fits_beside_weights_and_table(one_chip):
     tiles = {int(keys) for keys in re.findall(
         r"f32\[1,8,16,512,(\d+)\]", compiled.as_text())}
     assert tiles and max(tiles) <= 512, sorted(tiles)
+
+
+def test_flash_kernels_compile_at_the_training_cells_shape(one_chip):
+    """The three flash kernels at the training cells' call (batch 8, 16
+    heads, L 1,024, head 64, bfloat16, causal, no key mask: ``(128, 1024,
+    64)`` inside) compile for the v5e with the blocks `_choose_blocks`
+    picks, and the compiled text still holds the three custom calls by the
+    result signatures ``benchmarks/metrics/kernel.flash_fwd_roofline.json``
+    and ``kernel.flash_bwd_roofline.json`` find them by: one ``(bf16,
+    f32)`` (output and lse) and two that the backward pattern matches, one
+    ``bf16`` (dQ) and one ``(bf16, bf16)`` (dK, dV)."""
+    from distributed_tensorflow_tpu.ops import flash_attention
+
+    on_chip, _ = _shapes_on(one_chip)
+    x = on_chip((8, 1024, 16, 64), jnp.bfloat16)
+
+    def step(q, k, v, do):
+        # interpret=False: held to the CPU, the wrapper would hand the
+        # kernels to the interpreter
+        out, vjp = jax.vjp(lambda q, k, v: flash_attention(
+            q, k, v, causal=True, interpret=False), q, k, v)
+        return out, vjp(do)
+
+    text = jax.jit(step).lower(x, x, x, x).compile().as_text()
+    calls = [line.strip() for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 3, calls
+
+    def matching(metric):
+        spec = json.loads((Path(__file__).parent.parent / "benchmarks"
+                           / "metrics" / f"{metric}.json").read_text())
+        # the name is the flax module's in the training step and jvp's
+        # here; everything after it is the metric's own pattern
+        name, rest = spec["pattern"].split(" = ", 1)
+        assert name.startswith("^%CausalSelfAttention")
+        return [c for c in calls if re.search(r"^%[\w.\-]+ = " + rest, c)]
+
+    forward = matching("kernel.flash_fwd_roofline")
+    backward = matching("kernel.flash_bwd_roofline")
+    assert len(forward) == 1 and len(backward) == 2, (forward, backward)
+    assert not set(forward) & set(backward)
+    assert re.search(r"= \(bf16\[128,64,1024\]\{[^}]*\}, f32\[128,1,1024\]",
+                     forward[0])
+    dq, dkv = sorted(backward, key=lambda c: "= (" in c)
+    assert re.search(r"= bf16\[128,64,1024\]", dq)
+    assert re.search(
+        r"= \(bf16\[128,1024,64\]\{[^}]*\}, bf16\[128,1024,64\]", dkv)
