@@ -178,8 +178,8 @@ def build_parser() -> argparse.ArgumentParser:
                         "iterations, and the summary/run report gain a "
                         "'serve' section (requests/sec/chip, TTFT/ITL "
                         "p50/p95 — gated by `analyze diff` like the "
-                        "training metrics).  Per-request request/prefill/"
-                        "decode spans ride --trace")
+                        "training metrics).  Per-request request/prefill "
+                        "spans and a decode_step a round ride --trace")
     p.add_argument("--serve-slots", type=int, default=4,
                    help="--serve: KV slot table size (requests decoded "
                         "in flight at once; shards over the 'data' mesh "

@@ -3,7 +3,8 @@
 A span is one named region of host-side work — ``compile``,
 ``chunk_dispatch``, ``materialize``, ``checkpoint``, ``eval`` are the
 Trainer's vocabulary, ``serve_run``, ``request``, ``prefill``,
-``decode_step``, ``idle_wait`` the serve loop's.  Every finished span
+``decode_step``, ``idle_wait`` the serve loop's, ``step_dispatch`` and
+``token_fetch`` the slot table's inside a round.  Every finished span
 leaves one record in a bounded ring on the tracer:
 
     {"name": "decode_step", "start": 12.0391, "end": 12.1310, "id": 4711,
@@ -38,7 +39,7 @@ Two kinds of span:
   operations, and each can be matched with its record.  With no profile
   running that is one inactive ``TraceMe``.
 * ``begin(name, **attrs)`` / ``end(handle)`` is detached: a lifetime that
-  crosses loop iterations (``request``, ``decode``).  It is recorded like
+  crosses loop iterations (``request``).  It is recorded like
   any other, is nobody's parent and has no annotation — it is not host
   work.
 
@@ -65,8 +66,9 @@ from typing import Any
 
 from distributed_tensorflow_tpu.observability.sink import AsyncJsonlSink
 
-# the ring's size: a decode round leaves about three records and a request
-# four, so an hour of serving at ten rounds a second fits twice over
+# the ring's size: a decode round leaves three records (``decode_step``
+# and, inside it, ``step_dispatch`` and ``token_fetch``) and a request two,
+# so half an hour of serving at ten rounds a second fits
 RING_CAPACITY = 1 << 16
 
 

@@ -369,8 +369,9 @@ class SlotKVCache:
         ``halted`` mirrors slots the device stopped advancing that the
         scheduler has not yet evicted (occupancy ``active`` is separate);
         ``dispatch_count`` counts every compiled-program host call;
-        ``tracer`` takes the ``program_build`` spans (the batcher that
-        drives this table hands it its own)."""
+        ``tracer`` takes the table's own spans: ``program_build`` and, in
+        a single-step round, ``step_dispatch`` and ``token_fetch`` (the
+        batcher that drives this table hands it its own)."""
         self.tracer = recorder()
         # a model with routed experts: the decode step also returns the
         # round's routing load (advance -> last_routing)
@@ -1214,7 +1215,14 @@ class SlotKVCache:
         that slot's next real write: the free-slot-scatter argument, which
         holds for rows and for nothing else.  Their STATE is not written:
         the step is handed the mask and keeps it bit for bit, for an
-        excluded live slot as for a free one."""
+        excluded live slot as for a free one.
+
+        Two spans on ``self.tracer`` split the call where the device can
+        wait for the host: ``step_dispatch`` around the whole ``_step``
+        statement (the slot vectors' look-ups with their uploads, the
+        call's argument handling and enqueue) and ``token_fetch`` around
+        every ``np.asarray`` of its outputs (the tokens and, for a routed
+        step, the routing integers)."""
         mask = self.active if only is None else np.asarray(only, np.bool_)
         live = self.lengths[mask]
         if live.size and int(live.max()) >= self.max_len:
@@ -1227,19 +1235,21 @@ class SlotKVCache:
                 "a fused multi-step round is in flight — drain it before "
                 "a single-step advance (host mirrors lag the device)")
         t0 = time.perf_counter()
-        self.cache, d_nxt, d_len, *routing = self._step(
-            self.params, self.cache,
-            self._dev_cached("tokens", self.tokens),
-            self._dev_cached("lengths", self.lengths),
-            self._dev_cached("mask", mask), self._next_rng())
-        nxt = np.asarray(d_nxt)
-        if routing:
-            touched, load_max, pairs = (int(v) for v in
-                                        np.asarray(routing[0]))
-            self.last_routing = {
-                "experts_touched": touched / self.expert_layers,
-                "expert_load_max": load_max}
-            self.expert_assignments += pairs
+        with self.tracer.span("step_dispatch"):
+            self.cache, d_nxt, d_len, *routing = self._step(
+                self.params, self.cache,
+                self._dev_cached("tokens", self.tokens),
+                self._dev_cached("lengths", self.lengths),
+                self._dev_cached("mask", mask), self._next_rng())
+        with self.tracer.span("token_fetch"):
+            nxt = np.asarray(d_nxt)
+            if routing:
+                touched, load_max, pairs = (
+                    int(v) for v in np.asarray(routing[0]))
+                self.last_routing = {
+                    "experts_touched": touched / self.expert_layers,
+                    "expert_load_max": load_max}
+                self.expert_assignments += pairs
         self._phase_s["decode_s"] += time.perf_counter() - t0
         self.lengths[mask] += 1
         self.tokens = nxt.astype(np.int32)
@@ -2261,13 +2271,15 @@ class PagedSlotKVCache(SlotKVCache):
             pos = int(self.lengths[slot])
             self._ensure_writable(int(slot), pos, pos + 1)
         t0 = time.perf_counter()
-        self.cache, d_nxt, d_len = self._step(
-            self.params, self.cache,
-            self._dev_cached("tokens", self.tokens),
-            self._dev_cached("lengths", self.lengths),
-            self._dev_cached("mask", mask), self._masked_bt(mask),
-            self._next_rng())
-        nxt = np.asarray(d_nxt)
+        with self.tracer.span("step_dispatch"):
+            self.cache, d_nxt, d_len = self._step(
+                self.params, self.cache,
+                self._dev_cached("tokens", self.tokens),
+                self._dev_cached("lengths", self.lengths),
+                self._dev_cached("mask", mask), self._masked_bt(mask),
+                self._next_rng())
+        with self.tracer.span("token_fetch"):
+            nxt = np.asarray(d_nxt)
         self._phase_s["decode_s"] += time.perf_counter() - t0
         self.lengths[mask] += 1
         self.tokens = nxt.astype(np.int32)

@@ -32,8 +32,9 @@ al., arXiv:1910.01500 — latency percentiles as machine-checked numbers):
 TTFT is arrival→first-token (queue wait INCLUDED — an admitted-late
 request is a slow request), ITL is the gap between consecutive token
 deliveries, and both report p50/p95/p99 over the whole run.  Every request
-emits ``request``/``prefill``/``decode`` trace spans through the existing
-observability stack, so `analyze spans` and the Perfetto export read
+emits ``request``/``prefill`` trace spans, and every round a
+``decode_step`` (inside it the table's ``step_dispatch`` and
+``token_fetch``), through the existing observability stack, so `analyze spans` and the Perfetto export read
 serving timelines with no new machinery.
 
 Round 13 makes the batcher service-grade observable — all host-side, so
@@ -316,12 +317,11 @@ class _Live:
     """Host bookkeeping for one in-flight slot."""
 
     def __init__(self, req: Request, result: RequestResult,
-                 req_span, dec_span, last_t: float):
+                 req_span, last_t: float):
         self.req = req
         self.result = result
-        self.req_span = req_span     # detached spans (tracer.begin), ended
-        self.dec_span = dec_span     # on finish (per-request span contract)
-        self.last_t = last_t
+        self.req_span = req_span     # detached span (tracer.begin), ended
+        self.last_t = last_t         # on finish (per-request span contract)
 
 
 # stdlib-only linear-interpolated percentile (shared with the histogram
@@ -548,8 +548,7 @@ class ContinuousBatcher:
             arrival_s=req.arrival_s, admitted_s=now, first_token_s=now,
             queue_wait_s=t_claim - req.arrival_s,
             prefill_s=now - t_claim)
-        dec_span = tracer.begin("decode", rid=req.rid, slot=slot)
-        live[slot] = _Live(req, result, req_span, dec_span, now)
+        live[slot] = _Live(req, result, req_span, now)
         self._arm_multi(slot, live[slot])
         self._draft_admit(req.prompt, slot, first)
         if self._finished(live[slot]):
@@ -591,8 +590,7 @@ class ContinuousBatcher:
             first_token_s=now,
             queue_wait_s=pend["queue_wait_s"],
             prefill_s=now - pend["admitted_s"])
-        dec_span = self.tracer.begin("decode", rid=req.rid, slot=slot)
-        live[slot] = _Live(req, result, pend["span"], dec_span, now)
+        live[slot] = _Live(req, result, pend["span"], now)
         self._arm_multi(slot, live[slot])
         self._draft_admit(req.prompt, slot, first)
         if self._finished(live[slot]):
@@ -679,7 +677,6 @@ class ContinuousBatcher:
             queue_wait_s=r.queue_wait_s, prefill_s=r.prefill_s,
             decode_s=r.decode_s, ttft_s=r.ttft_s, tokens=len(r.tokens),
             **({} if r.slo_met is None else {"slo_met": r.slo_met}))
-        self.tracer.end(lv.dec_span)
         self.tracer.end(lv.req_span)
         self.kv.evict(slot)
         if self.draft_kv is not None and self.draft_kv.active[slot]:
@@ -1185,7 +1182,6 @@ class ContinuousBatcher:
         # survive into the partial-results artifact.
         for slot in sorted(live):
             lv = live.pop(slot)
-            self.tracer.end(lv.dec_span)
             self.tracer.end(lv.req_span)
             self.kv.evict(slot)
             if (self.draft_kv is not None

@@ -503,8 +503,9 @@ def test_scheduler_rejects_overcapacity_request(model_params):
 
 
 def test_scheduler_emits_request_spans(model_params, tmp_path):
-    """Per-request request/prefill/decode spans ride the existing tracer;
-    `analyze spans` reads them with no new machinery."""
+    """Per-request request/prefill spans, and a round's decode_step with
+    its two children, ride the existing tracer; `analyze spans` reads
+    them with no new machinery.  No record is named ``decode``."""
     from distributed_tensorflow_tpu.observability import Tracer
     from distributed_tensorflow_tpu.observability.analyze import (
         read_jsonl, trace_summary)
@@ -520,8 +521,11 @@ def test_scheduler_emits_request_spans(model_params, tmp_path):
     spans = trace_summary(read_jsonl(path))["spans"]
     assert spans["request"]["count"] == 3
     assert spans["prefill"]["count"] == 3
-    assert spans["decode"]["count"] == 3
-    assert spans["decode_step"]["count"] >= 1
+    assert "decode" not in spans
+    rounds = spans["decode_step"]["count"]
+    assert rounds >= 1
+    assert spans["step_dispatch"]["count"] == rounds
+    assert spans["token_fetch"]["count"] == rounds
 
 
 # ------------------------------------------- the serve loop's own records
@@ -572,9 +576,12 @@ def test_default_run_records_one_root_and_one_request_each(recorded_window):
         assert {"prefill_s", "decode_s"} <= set(attrs)
         # detached: recorded under the root, nobody's parent
         assert requests[res.rid]["parent"] == root["id"]
-    decodes = [r for r in recs if r["name"] == "decode"]
-    assert sorted(r["rid"] for r in decodes) == [10, 11, 12]
-    detached = {r["id"] for r in recs if r["name"] in ("request", "decode")}
+    assert not any(r["name"] == "decode" for r in recs)
+    # what is inside a round belongs to no request
+    inner = [r for r in recs
+             if r["name"] in ("step_dispatch", "token_fetch")]
+    assert inner and all(r["rid"] is None for r in inner)
+    detached = {r["id"] for r in recs if r["name"] == "request"}
     assert not detached & {r["parent"] for r in recs}
     # every record lies inside the root's interval
     assert all(root["start"] <= r["start"] <= r["end"] <= root["end"]
@@ -602,7 +609,10 @@ def test_default_run_records_every_prefill_with_its_bucket(recorded_window):
     assert {f"kv_prefill_batched_l{r['attrs']['padded_len']}"
             for r in prefills} <= set(builds)
     by_id = {r["id"]: r for r in recs}
-    assert by_id[builds["kv_decode_step"]["parent"]]["name"] == "decode_step"
+    # (the step's is the round's dispatch, inside the round)
+    dispatch = by_id[builds["kv_decode_step"]["parent"]]
+    assert dispatch["name"] == "step_dispatch"
+    assert by_id[dispatch["parent"]]["name"] == "decode_step"
 
 
 def test_default_run_records_every_decode_round_and_idle_wait(
