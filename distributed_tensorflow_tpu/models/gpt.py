@@ -748,6 +748,28 @@ class GPTLM(nn.Module):
                           partition_model=partition_model, dropout_rate=0.0,
                           kv_quant=kv_quant, **paged)
 
+    def step_param_dtype(self, path: tuple[str, ...]):
+        """The dtype in which a call first uses the parameter at ``path``
+        (the tree's keys from the root to the leaf), or None where it
+        uses the leaf as it is held.  ``serving/kv_cache.SlotKVCache``
+        holds each leaf in that dtype, so that a float32 checkpoint
+        served at bfloat16 is narrowed once and not in every program.
+
+        flax's ``Dense`` and ``Embed`` convert kernel, bias and embedding
+        to the module's ``dtype`` before the first use (``promote_dtype``),
+        so converting beforehand gives the same bits wherever the compiler
+        rounds where flax says (held by test on the CPU; the v5e's keeps a
+        float32 kernel's extra bits inside some prefill programs: PERF.md
+        section 6, PR 36).  ``LayerNorm``
+        multiplies by ``scale`` and adds ``bias`` in float32 whatever its
+        ``dtype`` and only then rounds: those leaves are not named, nor are
+        the routed experts' (``models/moe.py`` holds and uses its own)."""
+        leaf, module = path[-1], path[-2] if len(path) > 1 else ""
+        if leaf in ("kernel", "embedding") or (
+                leaf == "bias" and not module.startswith("LayerNorm")):
+            return self.dtype
+        return None
+
     @nn.compact
     def __call__(self, token_ids, train: bool = False, positions=None,
                  block_tables=None, prompt_len=None):

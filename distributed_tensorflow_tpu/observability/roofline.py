@@ -449,12 +449,7 @@ class Roofline:
                             or getattr(model, "paged_block", 16) or 16),
         ) if model is not None else None
         if cost is not None:
-            measured = getattr(kv, "param_leaf_bytes", None)
-            if callable(measured):
-                try:
-                    cost.param_bytes_override = int(measured())
-                except Exception:  # noqa: BLE001 — analytic fallback
-                    pass
+            cost.param_bytes_override = getattr(kv, "param_bytes", None)
         dtype = _dtype_key(getattr(model, "dtype", "float32"))
         return cls(device_peaks(device_kind), n_devices, cost, dtype)
 

@@ -1525,9 +1525,15 @@ class ReplicaSet:
             sw = self._swap
             if sw is None or sw["active"] != replica.id:
                 return
+            # the first replica to swap narrows the checkpoint
+            # (SlotKVCache._place_params); the others are handed its
+            # tree and use it in place: one set of buffers a device
             replica.kv.swap_params(sw["params"])
+            sw["params"] = replica.kv.params
             if self.draft_kvs is not None and sw["draft_params"] is not None:
-                self.draft_kvs[replica.id].swap_params(sw["draft_params"])
+                draft_kv = self.draft_kvs[replica.id]
+                draft_kv.swap_params(sw["draft_params"])
+                sw["draft_params"] = draft_kv.params
             replica.lease.reset_trigger()
             replica.generation += 1
             self.tracer.event("weight_swap", replica=replica.id,
@@ -1933,6 +1939,7 @@ class ReplicaSet:
             "serve_kv_dtype": self.replicas[0].kv.kv_dtype,
             "serve_kv_bytes_per_slot":
                 self.replicas[0].kv.kv_bytes_per_slot(),
+            "serve_param_bytes": self.replicas[0].kv.param_bytes,
             "serve_kv_layout": getattr(self.replicas[0].kv, "kv_layout",
                                        "monolithic"),
             "serve_kv_blocks_in_use": (paged_sec["blocks_in_use"]
@@ -2176,13 +2183,19 @@ class ReplicaSet:
 def build_replica_kvs(model, params, n_replicas: int, slots: int,
                       **kv_kwargs) -> list[SlotKVCache]:
     """N independent slot tables over shared params (replicated params
-    share device buffers; each replica owns its KV memory).  n == 0 is
-    legal and returns [] — callers extending an already-built first
-    table pass n_replicas - 1."""
+    share device buffers; each replica owns its KV memory).  The first
+    table narrows ``params`` to what the step uses
+    (``SlotKVCache._place_params``) and the others take ITS tree, which
+    they use in place.  n == 0 is legal and returns [] — callers
+    extending an already-built first table pass n_replicas - 1 and that
+    table's ``params``."""
     if n_replicas < 0:
         raise ValueError(f"n_replicas must be >= 0, got {n_replicas}")
-    return [SlotKVCache(model, params, slots, **kv_kwargs)
-            for _ in range(n_replicas)]
+    kvs: list[SlotKVCache] = []
+    for _ in range(n_replicas):
+        kvs.append(SlotKVCache(model, kvs[0].params if kvs else params,
+                               slots, **kv_kwargs))
+    return kvs
 
 
 __all__ = [

@@ -149,6 +149,34 @@ def _bucket(n: int, floor: int, cap: int) -> int:
     return min(b, cap)
 
 
+def _tree_bytes(tree) -> int:
+    """Bytes of a tree's leaves (arrays or ``jax.ShapeDtypeStruct``)."""
+    return sum(int(t.size) * jnp.dtype(t.dtype).itemsize
+               for t in jax.tree.leaves(tree))
+
+
+def _check_swap(served, params) -> None:
+    """A swapped-in tree has to be a cache hit of every compiled program:
+    the served tree's structure, shapes and dtypes."""
+    if (jax.tree_util.tree_structure(served)
+            != jax.tree_util.tree_structure(params)):
+        raise ValueError(
+            "swap_params needs the same param tree structure as the "
+            "served checkpoint (same model config) — a different "
+            "architecture cannot hot-swap into live slots")
+    mismatch = [
+        f"{jax.tree_util.keystr(path)}: {a.shape}/{a.dtype} vs "
+        f"{b.shape}/{b.dtype}"
+        for (path, a), b in zip(
+            jax.tree_util.tree_flatten_with_path(served)[0],
+            jax.tree.leaves(params))
+        if a.shape != b.shape or a.dtype != b.dtype]
+    if mismatch:
+        raise ValueError(
+            f"swap_params shape/dtype mismatch (a swap must be a "
+            f"compiled-program cache hit): {mismatch[:3]}")
+
+
 class SlotOverflow(RuntimeError):
     """An active slot was asked to write past its ``max_len`` capacity.
 
@@ -185,8 +213,20 @@ class SlotKVCache:
     and so does what moves validity by bookkeeping for a model that keeps
     per-slot state (``slot_state`` on the model class) or rings
     (``slot_rings``).
-    ``params`` may be a TP engine's committed TrainState params (used in
-    place) or host/single-device params (replicated).
+    ``params`` may be a TP engine's committed TrainState params or
+    host/single-device params (replicated over the mesh).  The table
+    HOLDS each leaf in the dtype in which the model's call first uses it
+    (``_place_params``; the model's ``step_param_dtype`` is the rule): a
+    float32 ``GPTLM`` checkpoint served at ``dtype=bfloat16`` is held as
+    bfloat16 kernels, biases and embeddings beside float32 ``LayerNorm``
+    leaves, converted once when the table takes it, and ``self.params``
+    is then not the caller's tree (which is not donated: it is freed
+    when the caller lets go of it).  A tree that is in those dtypes
+    already — a model that declares no rule, a float32 model, another
+    table's ``params`` — is used in place, leaf for leaf the caller's
+    arrays.  A tensor-parallel engine's committed params, once "used in
+    place", so get a bfloat16 twin with the same sharding where the model
+    computes in bfloat16.  ``param_bytes`` is the held tree's size.
 
     Host-side bookkeeping (`lengths`, `active`, `tokens`) lives on numpy:
     the scheduler owns admission/eviction and the decode step receives the
@@ -311,7 +351,6 @@ class SlotKVCache:
             self._vec_sharding = meshlib.kv_slot_sharding(mesh, 1)
             self._blk_sharding = meshlib.kv_slot_sharding(mesh, 2)
         self.cache = cache
-        self.params = self._place_params(params)
 
         # host-side slot table.  ``reserved`` marks slots claimed by an
         # in-progress chunked admission (begin_insert): not free, but not
@@ -322,6 +361,7 @@ class SlotKVCache:
         self.tokens = np.zeros(self.slots, np.int32)   # last token per slot
         self._pending: dict[int, dict] = {}            # slot → prefill state
         self._init_multi_state()
+        self.params = self._place_params(params)    # under self.tracer
 
         # block-aligned prefix pool (LRU over exact prefix-byte keys);
         # entries are the slot-slice KV of one block, stored at the table's
@@ -420,53 +460,87 @@ class SlotKVCache:
                 f"{sorted(self.state_leaves)}): the monolithic table with "
                 f"insert/advance/evict is")
 
-    def _place_params(self, params):
-        """Param placement rule (shared by __init__ and ``swap_params``):
-        params committed to this table's mesh are used in place; anything
-        else replicates (the `generate(mesh=...)` placement rule)."""
-        if self.mesh is None:
+    def _place_params(self, params, *, replacing=None):
+        """What the table does with a tree it is given: the one door of
+        ``__init__`` and ``swap_params``, under a ``params_place`` span
+        (``narrowed``: leaves converted; ``bytes_given``, ``bytes_held``:
+        the tree before and after).
+
+        *Narrowing* (``_narrow_params``, the class docstring): each leaf
+        in the dtype the model's call first uses it in; a leaf that needs
+        nothing is the very same array, and the caller's tree is not
+        donated.  *The check of a swap*: ``replacing`` is the served tree,
+        which the narrowed tree has to match in structure, shapes and
+        dtypes (``ValueError`` otherwise, and nothing is placed).
+        *Placement*: with a mesh, a leaf committed to it is used in place
+        and anything else replicates (the ``generate(mesh=...)`` rule)."""
+        with self.tracer.span("params_place") as attrs:
+            held = self._narrow_params(params)
+            attrs["bytes_given"] = _tree_bytes(params)
+            attrs["narrowed"] = sum(
+                a is not b for a, b in zip(jax.tree.leaves(held),
+                                           jax.tree.leaves(params)))
+            if replacing is not None:
+                _check_swap(replacing, held)
+            if self.mesh is not None:
+                mesh = self.mesh
+                repl = NamedSharding(mesh, P())
+                target = mesh.devices.tolist()
+
+                def place(t):
+                    sh = getattr(t, "sharding", None)
+                    if isinstance(sh, NamedSharding) and (
+                            sh.mesh is mesh
+                            or sh.mesh.devices.tolist() == target):
+                        return t
+                    return jax.device_put(t, repl)
+
+                held = jax.tree.map(place, held)
+            self.param_bytes = attrs["bytes_held"] = _tree_bytes(held)
+        return held
+
+    def _narrow_params(self, params):
+        """Each leaf in the dtype in which the served model's call first
+        uses it.  A leaf that the model says its call converts before the
+        first use (``step_param_dtype`` on the model: ``GPTLM``'s
+        ``Dense`` and ``Embed`` leaves go to ``model.dtype``, its
+        ``LayerNorm`` leaves do not), and that is wider than that dtype,
+        is converted here once: the bits the call would make, which every
+        program then reads at half the bytes and without a copy of its
+        own.  Any other leaf is returned as the very same array: a model
+        with no rule, a float32 model and a tree narrowed before get
+        their tree back.  A ``jax.ShapeDtypeStruct`` is narrowed
+        abstractly."""
+        use_dtype = getattr(self.dm, "step_param_dtype", None)
+        if use_dtype is None:
             return params
-        mesh = self.mesh
-        repl = NamedSharding(mesh, P())
-        target = mesh.devices.tolist()
 
-        def place(t):
-            sh = getattr(t, "sharding", None)
-            if isinstance(sh, NamedSharding) and (
-                    sh.mesh is mesh
-                    or sh.mesh.devices.tolist() == target):
+        def narrow(path, t):
+            want = use_dtype(tuple(
+                k.key for k in path
+                if isinstance(k, jax.tree_util.DictKey)))
+            if (want is None or not jnp.issubdtype(t.dtype, jnp.floating)
+                    or jnp.dtype(t.dtype).itemsize
+                    <= jnp.dtype(want).itemsize):
                 return t
-            return jax.device_put(t, repl)
+            if isinstance(t, jax.ShapeDtypeStruct):
+                return jax.ShapeDtypeStruct(t.shape, want,
+                                            sharding=t.sharding)
+            return t.astype(want)
 
-        return jax.tree.map(place, params)
+        return jax.tree_util.tree_map_with_path(narrow, params)
 
     def swap_params(self, params) -> None:
         """Zero-downtime weight hot-swap: replace the served params
         between compiled-program dispatches (serving/fleet.py drains a
         replica's in-flight slots first — KV written under the old params
-        must never be decoded under the new ones).  The new tree must
-        match the old one's structure/shapes/dtypes, so every compiled
+        must never be decoded under the new ones).  The tree is taken as
+        ``__init__`` takes one (a float32 checkpoint of a bfloat16 model
+        is narrowed by the same rule, ``_place_params``) and must THEN
+        match the served one's structure/shapes/dtypes, so every compiled
         program (decode step, prefill buckets, chunk buckets, verify
         widths) stays a cache hit — a swap never recompiles."""
-        old = jax.tree_util.tree_structure(self.params)
-        new = jax.tree_util.tree_structure(params)
-        if old != new:
-            raise ValueError(
-                "swap_params needs the same param tree structure as the "
-                "served checkpoint (same model config) — a different "
-                "architecture cannot hot-swap into live slots")
-        mismatch = [
-            f"{jax.tree_util.keystr(path)}: {a.shape}/{a.dtype} vs "
-            f"{b.shape}/{b.dtype}"
-            for (path, a), b in zip(
-                jax.tree_util.tree_flatten_with_path(self.params)[0],
-                jax.tree.leaves(params))
-            if a.shape != b.shape or a.dtype != b.dtype]
-        if mismatch:
-            raise ValueError(
-                f"swap_params shape/dtype mismatch (a swap must be a "
-                f"compiled-program cache hit): {mismatch[:3]}")
-        self.params = self._place_params(params)
+        self.params = self._place_params(params, replacing=self.params)
 
     # ------------------------------------------------------------- programs
     def _jit(self, fn, name: str, **jit_kwargs):
@@ -1807,7 +1881,6 @@ class PagedSlotKVCache(SlotKVCache):
             self._vec_sharding = meshlib.kv_slot_sharding(mesh, 1)
             self._blk_sharding = meshlib.kv_slot_sharding(mesh, 2)
         self.cache = cache
-        self.params = self._place_params(params)
 
         # host slot table (identical to monolithic) ...
         self.lengths = np.zeros(self.slots, np.int32)
@@ -1816,6 +1889,7 @@ class PagedSlotKVCache(SlotKVCache):
         self.tokens = np.zeros(self.slots, np.int32)
         self._pending: dict[int, dict] = {}
         self._init_multi_state()
+        self.params = self._place_params(params)    # under self.tracer
 
         # ... plus the paged substrate: refcounted physical blocks, a
         # free list, per-slot logical→physical tables (host numpy; the

@@ -1338,6 +1338,9 @@ class ContinuousBatcher:
             # shrink); both ride into the serve report section
             "serve_kv_dtype": getattr(self.kv, "kv_dtype", None),
             "serve_kv_bytes_per_slot": self.kv.kv_bytes_per_slot(),
+            # the served tree as the table holds it (each leaf in the
+            # dtype the step uses it in: SlotKVCache._place_params)
+            "serve_param_bytes": getattr(self.kv, "param_bytes", None),
             # --serve-kv-layout: paged pool accounting (None/0 under
             # monolithic — the keys are always present so `analyze diff`
             # gates them when both runs page).  blocks_in_use is gated

@@ -2574,7 +2574,7 @@ def _serve_from_state(config: ExperimentConfig, ex: _Experiment, state,
         overrides = parse_draft_config(config.serve_draft_config)
         model = ex.engine.model
         if overrides is None:
-            draft_model, draft_params = model, params
+            draft_model, draft_params = model, kv.params
         else:
             draft_model = modellib.create_model(
                 "gpt", num_classes=int(model.vocab_size),
@@ -2609,7 +2609,7 @@ def _serve_from_state(config: ExperimentConfig, ex: _Experiment, state,
 
         if roles is None:
             kvs = [kv] + build_replica_kvs(
-                ex.engine.model, params, n_replicas - 1,
+                ex.engine.model, kv.params, n_replicas - 1,
                 config.serve_slots, **kv_kwargs)
         else:
             # disaggregated fleets keep the prefix pool prefill-side
@@ -2624,12 +2624,12 @@ def _serve_from_state(config: ExperimentConfig, ex: _Experiment, state,
             kvs = [kv]
             for role in roles[1:]:
                 kvs += build_replica_kvs(
-                    ex.engine.model, params, 1, config.serve_slots,
+                    ex.engine.model, kv.params, 1, config.serve_slots,
                     **(kv_kwargs if role == "prefill" else decode_kwargs))
         draft_kvs = None
         if draft_kv is not None:
             draft_kvs = [draft_kv] + build_replica_kvs(
-                draft_model, draft_params, n_replicas - 1,
+                draft_model, draft_kv.params, n_replicas - 1,
                 config.serve_slots, mesh=mesh)
         injector = (FaultInjector(config.serve_fault_spec,
                                   seed=config.seed)

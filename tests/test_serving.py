@@ -28,7 +28,7 @@ def tiny_gpt(**kw):
     kw.setdefault("ffn", 64)
     kw.setdefault("max_len", 32)
     kw.setdefault("dropout_rate", 0.0)
-    return GPTLM(**kw)
+    return kw.pop("cls", GPTLM)(**kw)
 
 
 @pytest.fixture(scope="module")
@@ -1345,6 +1345,188 @@ def test_slot_table_write_matches_two_index_scatter(kv_dtype, width, case):
     # whole
     in_table = ((np.asarray(pos) >= 0) & (np.asarray(pos) < max_len)).any(1)
     assert changed == len(jax.tree.leaves(before)) * int(in_table.sum())
+
+
+# ------------------------------- the weights in the dtype the step uses
+
+
+class _HeldAsGiven(GPTLM):
+    """``GPTLM`` with no narrowing rule: its table holds the tree it is
+    given, as every table did before ``step_param_dtype``."""
+
+    def step_param_dtype(self, path):
+        return None
+
+
+@pytest.fixture(scope="module")
+def bf16_model_params():
+    """bfloat16 compute over float32 parameters, every leaf perturbed (a
+    fresh init has zero biases and unit gains, which round to
+    themselves)."""
+    model = tiny_gpt(dtype=jnp.bfloat16)
+    params = model.init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32),
+                        train=False)["params"]
+    leaves, tree = jax.tree.flatten(params)
+    keys = jax.random.split(jax.random.key(7), len(leaves))
+    return model, jax.tree.unflatten(tree, [
+        t + 0.1 * jax.random.normal(k, t.shape, t.dtype)
+        for t, k in zip(leaves, keys)])
+
+
+def _leaf_names(tree):
+    return {jax.tree_util.keystr(path): leaf for path, leaf in
+            jax.tree_util.tree_leaves_with_path(tree)}
+
+
+def _last_params_place():
+    """The attributes of the newest ``params_place`` span."""
+    from distributed_tensorflow_tpu.observability import recorder
+
+    return [r for r in recorder().records()
+            if r["name"] == "params_place"][-1]["attrs"]
+
+
+def test_table_holds_weights_in_the_dtype_the_step_uses(bf16_model_params):
+    """A float32 checkpoint served at bfloat16 is narrowed once, when the
+    table takes it: kernels, biases and embeddings bfloat16, the
+    ``LayerNorm`` leaves float32 (flax multiplies those in float32), and
+    the same bits come out as from the tree held as given: the step's
+    logits bit for bit, the served tokens (here, on the CPU; where the
+    v5e's compiler parts from this: PERF.md section 6, PR 36)."""
+    model, params = bf16_model_params
+    kv = SlotKVCache(model, params, slots=3)
+    given = SlotKVCache(tiny_gpt(cls=_HeldAsGiven, dtype=jnp.bfloat16),
+                        params, slots=3)
+    assert all(a is b for a, b in zip(jax.tree.leaves(given.params),
+                                      jax.tree.leaves(params)))
+    held = _leaf_names(kv.params)
+    assert set(held) == set(_leaf_names(params))
+    for name, leaf in held.items():
+        want = jnp.float32 if "LayerNorm" in name else jnp.bfloat16
+        assert leaf.dtype == want, (name, leaf.dtype)
+    assert any("LayerNorm" in name for name in held)
+    assert given.param_bytes == sum(t.nbytes for t in jax.tree.leaves(params))
+    assert kv.param_bytes == sum(t.nbytes for t in jax.tree.leaves(kv.params))
+    assert kv.param_bytes < 0.55 * given.param_bytes
+
+    def step_logits(table):
+        tokens = jnp.asarray([[3], [11], [40]], jnp.int32)
+        logits, _ = table.dm.apply(
+            {"params": table.params, "cache": table.cache}, tokens,
+            train=False, positions=jnp.zeros((3, 1), jnp.int32),
+            mutable=["cache"])
+        return np.asarray(logits)
+
+    np.testing.assert_array_equal(step_logits(kv), step_logits(given))
+
+    def served(table):
+        reqs = [Request(rid=i, prompt=p, max_new_tokens=10, arrival_s=0.0)
+                for i, p in enumerate(_prompts(5, seed=11, lo=3, hi=20))]
+        summary = ContinuousBatcher(table, clock=VirtualClock()).run(reqs)
+        assert summary["serve_param_bytes"] == table.param_bytes
+        return {r.rid: r.tokens for r in summary["results"]}
+
+    assert served(kv) == served(given)
+
+
+@pytest.mark.parametrize("name", ["gpt-float32", "mla_moe", "hybrid_ssm",
+                                  "window_moe"])
+def test_table_uses_in_place_a_tree_the_step_reads_as_held(name):
+    """A model that declares no rule, and a ``GPTLM`` that computes in
+    the dtype of its leaves: every leaf of ``kv.params`` IS the leaf
+    handed in, and the placement's span says that nothing was narrowed."""
+    from distributed_tensorflow_tpu.models import create_model
+
+    model = (tiny_gpt(dtype=jnp.float32) if name == "gpt-float32"
+             else create_model(name, vocab_size=64, max_len=64,
+                               dtype="bfloat16"))
+    params = model.init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32),
+                        train=False)["params"]
+    kv = SlotKVCache(model, params, slots=2)
+    assert all(a is b for a, b in zip(jax.tree.leaves(kv.params),
+                                      jax.tree.leaves(params)))
+    span = _last_params_place()
+    assert span["narrowed"] == 0
+    assert span["bytes_given"] == span["bytes_held"] == kv.param_bytes
+
+
+def test_committed_tensor_parallel_params_get_a_twin_of_the_same_sharding():
+    """A tensor-parallel engine's committed float32 params, served at
+    bfloat16: the narrowed twin keeps each leaf's sharding over the mesh,
+    and the leaves the rule leaves alone are used in place."""
+    import flax.linen as nn
+    from jax.sharding import NamedSharding
+    from distributed_tensorflow_tpu.parallel import mesh as meshlib
+
+    mesh = meshlib.create_mesh(8, axis_names=("data", "model"), shape=(4, 2))
+    model = tiny_gpt(partition_model=True, dtype=jnp.bfloat16)
+    boxed = model.init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32),
+                       train=False)["params"]
+    params = jax.tree.map(
+        lambda p, spec: jax.device_put(p, NamedSharding(mesh, spec)),
+        nn.meta.unbox(boxed), nn.get_partition_spec(boxed))
+    kv = SlotKVCache(model, params, slots=4, mesh=mesh)
+    given = _leaf_names(params)
+    for name, leaf in _leaf_names(kv.params).items():
+        assert leaf.sharding == given[name].sharding, name
+        if "LayerNorm" in name:
+            assert leaf is given[name]
+        else:
+            assert leaf.dtype == jnp.bfloat16, name
+    assert any(not t.sharding.is_fully_replicated
+               for t in jax.tree.leaves(kv.params))
+
+
+def test_swap_takes_a_float32_checkpoint_and_narrows_it_once(
+        bf16_model_params):
+    """``swap_params`` takes the tree ``__init__`` takes: a float32
+    checkpoint is narrowed by the same rule and only then compared with
+    what is served, a wrong shape is still refused, and a tree that was
+    narrowed before (another table's ``params``) is used in place."""
+    model, params = bf16_model_params
+    kv = SlotKVCache(model, params, slots=2)
+    before = _leaf_names(kv.params)
+    kv.swap_params(jax.tree.map(lambda t: t * 0.5, params))
+    span = _last_params_place()
+    after = _leaf_names(kv.params)
+    assert span["narrowed"] == sum(
+        leaf.dtype == jnp.bfloat16 for leaf in after.values()) > 0
+    assert span["bytes_given"] > span["bytes_held"] == kv.param_bytes
+    for name, leaf in _leaf_names(params).items():
+        assert after[name].dtype == before[name].dtype
+        np.testing.assert_array_equal(
+            np.asarray(after[name], np.float32),
+            np.asarray((leaf * 0.5).astype(after[name].dtype), np.float32),
+            name)
+    wrong = jax.tree.map(lambda t: jnp.zeros(t.shape + (2,), t.dtype),
+                         params)
+    with pytest.raises(ValueError, match="shape/dtype mismatch"):
+        kv.swap_params(wrong)
+    assert all(a is b for a, b in zip(jax.tree.leaves(kv.params),
+                                      after.values()))    # untouched
+    # narrowing a narrowed tree: the same arrays, through both doors
+    twin = SlotKVCache(model, kv.params, slots=1)
+    assert all(a is b for a, b in zip(jax.tree.leaves(twin.params),
+                                      jax.tree.leaves(kv.params)))
+    served = jax.tree.leaves(kv.params)
+    kv.swap_params(kv.params)
+    assert all(a is b for a, b in zip(jax.tree.leaves(kv.params), served))
+
+
+def test_abstract_tree_is_narrowed_abstractly(bf16_model_params):
+    """``tests/test_tpu_compile.py`` hands the table ``jax.eval_shape``'s
+    tree: ``jax.ShapeDtypeStruct`` has no ``astype``, and the held tree is
+    what the programs are lowered with."""
+    model, params = bf16_model_params
+    shapes = jax.eval_shape(lambda: params)
+    kv = SlotKVCache(model, shapes, slots=2)
+    real = SlotKVCache(model, params, slots=2)
+    for (path, a), b in zip(
+            jax.tree_util.tree_leaves_with_path(kv.params),
+            jax.tree.leaves(real.params)):
+        assert isinstance(a, jax.ShapeDtypeStruct)
+        assert (a.shape, a.dtype) == (b.shape, b.dtype), path
+    assert kv.param_bytes == real.param_bytes
 
 
 @pytest.mark.slow    # round 20 fast-lane repair: kv-dtype threading

@@ -536,6 +536,34 @@ def test_hot_swap_installs_new_params(model_params):
     assert old != new   # the perturbation must actually matter
 
 
+def test_replicas_share_one_narrowed_tree():
+    """A float32 checkpoint of a bfloat16 model is narrowed ONCE for the
+    fleet (``SlotKVCache._place_params``): ``build_replica_kvs`` hands
+    every replica the first table's tree and a hot swap hands every
+    replica the first swapped one's, so replicas on one device still
+    share one set of buffers."""
+    model = tiny_gpt(dtype=jnp.bfloat16)
+    params = model.init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32),
+                        train=False)["params"]
+    kvs = build_replica_kvs(model, params, 2, 2)
+
+    def shared():
+        first, second = (jax.tree.leaves(kv.params) for kv in kvs)
+        assert any(t.dtype == jnp.bfloat16 for t in first)
+        return all(a is b for a, b in zip(first, second))
+
+    assert shared()
+    built = jax.tree.leaves(kvs[0].params)
+    rs = ReplicaSet(kvs, clock=VirtualClock())
+    rs.schedule_swap(jax.tree.map(lambda t: t * 0.5, params),
+                     after_completions=2)
+    assert rs.run(_requests())["completed"] == 6
+    assert rs.swap_generations == 1
+    assert shared()
+    assert not any(a is b for a, b in
+                   zip(jax.tree.leaves(kvs[0].params), built))
+
+
 def test_swap_params_validation(model_params):
     """swap_params must be a compiled-program cache hit: a different
     tree structure or leaf shape is rejected, the table untouched."""

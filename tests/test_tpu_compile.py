@@ -64,7 +64,8 @@ def _shapes_on(sharding):
 
 def test_decode_step_writes_the_slot_table_in_place(one_chip):
     """The serve cell's decode step (gpt2-large widths, 32 slots x 1024,
-    bf16 table, float32 parameters, greedy, the table donated) at 2 layers:
+    bf16 table, a float32 checkpoint held as the step uses it, greedy, the
+    table donated) at 2 layers:
     the v5e compiler keeps every table leaf in the layout it arrived in,
     and the new K/V row reaches it inside the attention's own two passes.
 
@@ -79,8 +80,7 @@ def test_decode_step_writes_the_slot_table_in_place(one_chip):
     tiles: 2,304 serial iterations, nearly half of a 35 ms round on the
     chip (PERF.md section 6).  ``select_slot_row`` leaves neither: no ``copy``
     of a leaf, no ``while``, no ``dynamic-update-slice``.  The vocabulary
-    is cut to 8,192: the bf16 copy of the tied embedding is a temporary of
-    its own, 129 MB at 50,257, and not what this test is about."""
+    is cut to 8,192 (the whole one: the test after the next)."""
     slots, max_len, heads, head_dim = 32, 1024, 20, 64
     model = create_model("gpt", dtype="bfloat16", vocab_size=8192,
                          max_len=max_len, hidden=heads * head_dim, layers=2,
@@ -95,7 +95,7 @@ def test_decode_step_writes_the_slot_table_in_place(one_chip):
     on_chip, like = _shapes_on(one_chip)
 
     compiled = jax.jit(step, **jit_kwargs).lower(
-        like(params), like(kv.cache), on_chip((slots,), jnp.int32),
+        like(kv.params), like(kv.cache), on_chip((slots,), jnp.int32),
         on_chip((slots,), jnp.int32), on_chip((slots,), jnp.bool_),
         like(jax.random.key(0))).compile()
 
@@ -116,7 +116,8 @@ def test_the_block_prefill_is_one_forward_that_writes_the_slot_in_place(
         one_chip):
     """The serve cell's ``kv_prefill_batched_l512`` at the widths and depth
     of ``benchmarks/configs/gpt2-large.json`` (32 slots x 1,024, bf16
-    table, float32 parameters, greedy, the table donated).
+    table, a float32 checkpoint held as the step uses it, greedy, the table
+    donated).
 
     Until PR 30 the program was a ``while`` of 512 one-token steps, each
     reading all 3.1 GB of weights and computing a 50,257-wide logits row:
@@ -124,9 +125,9 @@ def test_the_block_prefill_is_one_forward_that_writes_the_slot_in_place(
     over the block: (i) no ``while`` is left, (ii) the block's K and V
     reach the donated table without a ``copy`` of a whole
     ``bf16[32,1024,20,64]`` leaf (the relayout PR 26 took out of the
-    step: 84 MB a leaf, 72 leaves), and (iii) the temporaries (0.26 GB:
-    the bf16 copy of the tied embedding, 129 MB, and the block's
-    activations) stay under 0.5 GB."""
+    step: 84 MB a leaf, 72 leaves), and (iii) the temporaries (0.26 GB
+    while the program made its own bf16 copy of the tied embedding, 129 MB,
+    beside the block's activations) stay under 0.5 GB."""
     config = json.loads((Path(__file__).resolve().parent.parent / "benchmarks"
                          / "configs" / "gpt2-large.json").read_text())
     slots, lpad = 32, 512
@@ -148,7 +149,7 @@ def test_the_block_prefill_is_one_forward_that_writes_the_slot_in_place(
     on_chip, like = _shapes_on(one_chip)
 
     compiled = jax.jit(prefill, **jit_kwargs).lower(
-        like(params), like(kv.cache), on_chip((), jnp.int32),
+        like(kv.params), like(kv.cache), on_chip((), jnp.int32),
         on_chip((lpad,), jnp.int32), on_chip((), jnp.int32),
         like(jax.random.key(0))).compile()
 
@@ -161,6 +162,67 @@ def test_the_block_prefill_is_one_forward_that_writes_the_slot_in_place(
     copies = re.findall(rf"= {leaf}\{{[^}}]*\}} copy\(", text)
     assert not copies, f"{len(copies)} relayout copies of a table leaf"
     assert memory.temp_size_in_bytes < 0.5e9, memory.temp_size_in_bytes
+
+
+def test_gpt2_programs_read_the_weights_as_bfloat16(one_chip):
+    """The GPT-2 serve cells' decode step and 512 block prefill at 2 layers
+    and the WHOLE vocabulary, lowered with the tree the table holds.
+
+    The cell's checkpoint is float32 and its model computes in bfloat16.
+    Lowered with the checkpoint as given, every program prefetched the
+    ``f32[1280,1280]`` / ``f32[1280,5120]`` / ``f32[5120,1280]`` kernels at
+    four bytes a weight, converted each inside its matmul fusion, and made
+    and read back a 129 MB bfloat16 copy of the tied ``f32[50257,1280]``
+    embedding: 134.6 MB of temporaries in the step, and on the chip 8 ms
+    of a 25.6 ms round and of every prefill bucket (PERF.md section 6,
+    PR 36).  The table narrows the tree once (``_place_params``): no
+    float32 weight operand is left in either program, no temporary the
+    size of the embedding, and the arguments are the table, the held tree
+    and a few vectors, the held tree half of the checkpoint."""
+    config = json.loads((Path(__file__).resolve().parent.parent / "benchmarks"
+                         / "configs" / "gpt2-large.json").read_text())
+    slots, lpad, layers = 32, 512, 2
+    max_len, heads = config["n_positions"], config["n_head"]
+    hidden, vocab = config["n_embd"], config["vocab_size"]
+    model = create_model("gpt", dtype="bfloat16", vocab_size=vocab,
+                         max_len=max_len, hidden=hidden, layers=layers,
+                         heads=heads, ffn=4 * hidden)
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32),
+                           train=False))["params"]
+    kv = _ProgramProbe(model, params, slots, greedy=True,
+                       kv_dtype=jnp.bfloat16)
+    kv._prefill(lpad)
+    on_chip, like = _shapes_on(one_chip)
+    slot_vec = on_chip((slots,), jnp.int32)
+    scalar = on_chip((), jnp.int32)
+    lowered_with = {
+        "kv_decode_step": (slot_vec, slot_vec, on_chip((slots,), jnp.bool_)),
+        f"kv_prefill_batched_l{lpad}": (scalar, on_chip((lpad,), jnp.int32),
+                                        scalar)}
+
+    given = sum(t.size * t.dtype.itemsize for t in jax.tree.leaves(params))
+    assert 0.5 * given < kv.param_bytes < 0.501 * given  # LayerNorm: float32
+    table = 2 * layers * slots * max_len * hidden * 2
+    embedding_copy = vocab * hidden * 2
+    # an operand: a parameter of the program or of one of its fusions (a
+    # fusion may still widen what it has read, in registers)
+    wide = re.compile(
+        rf"= (f32\[(?:{hidden},{hidden}|{hidden},{4 * hidden}|{4 * hidden},"
+        rf"{hidden}|{vocab},{hidden}|{max_len},{hidden})\])\S* parameter\(")
+    for name, vectors in lowered_with.items():
+        program, jit_kwargs = kv.programs[name]
+        compiled = jax.jit(program, **jit_kwargs).lower(
+            like(kv.params), like(kv.cache), *vectors,
+            like(jax.random.key(0))).compile()
+        left = sorted(set(wide.findall(compiled.as_text())))
+        assert not left, (name, left)
+        memory = compiled.memory_analysis()
+        assert memory.temp_size_in_bytes < embedding_copy, (
+            name, memory.temp_size_in_bytes)
+        assert (kv.param_bytes + table <= memory.argument_size_in_bytes
+                < kv.param_bytes + table + 1e6), (
+            name, memory.argument_size_in_bytes)
 
 
 def test_the_long_prefill_holds_no_score_tile_wider_than_the_key_block(
