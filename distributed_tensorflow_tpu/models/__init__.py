@@ -75,9 +75,11 @@ def create_model(name: str, num_classes: int = 10, **kw) -> nn.Module:
     Beside the registry's classifiers: ``resnet20``, ``bert_tiny``, ``moe``
     and the language models ``gpt`` (``models/gpt.GPTLM``), ``mla_moe``
     (latent attention, sparse experts), ``hybrid_ssm`` (state-space,
-    attention and latent-expert mixers by a pattern) and ``window_moe``
+    attention and latent-expert mixers by a pattern), ``window_moe``
     (window and full attention by a pattern, a parallel block, averaged
-    shared experts); the four serve through ``serving.SlotKVCache``."""
+    shared experts) and ``jamba`` (selective-scan state-space and
+    multi-query attention mixers by a period, a dense SwiGLU after each, a
+    tied head); the five serve through ``serving.SlotKVCache``."""
     if "dtype" in kw:
         kw["dtype"] = resolve_dtype(kw["dtype"])
     if name in ("resnet20", "resnet"):
@@ -121,10 +123,17 @@ def create_model(name: str, num_classes: int = 10, **kw) -> nn.Module:
             kw["param_dtype"] = resolve_dtype(kw["param_dtype"])
         kw.setdefault("vocab_size", num_classes)
         return WindowMoELM(**kw)
+    if name == "jamba":
+        from distributed_tensorflow_tpu.models.jamba import JambaLM
+
+        if "param_dtype" in kw:
+            kw["param_dtype"] = resolve_dtype(kw["param_dtype"])
+        kw.setdefault("vocab_size", num_classes)
+        return JambaLM(**kw)
     if name not in _REGISTRY:
         raise KeyError(f"unknown model '{name}'; known: {sorted(_REGISTRY)} "
                        f"+ resnet20, bert_tiny, moe, gpt, mla_moe, "
-                       f"hybrid_ssm, window_moe")
+                       f"hybrid_ssm, window_moe, jamba")
     return _REGISTRY[name](num_classes=num_classes, **kw)
 
 

@@ -73,6 +73,11 @@ from distributed_tensorflow_tpu.models.gpt import write_slot_rows
 from distributed_tensorflow_tpu.models.mla_moe import (
     RMSNorm, causal_attention_blocked)
 from distributed_tensorflow_tpu.models.moe import DroplessMoE
+from distributed_tensorflow_tpu.models.window_moe import (
+    window_attention_blocked)
+
+# the longest block ``GroupedQueryAttention`` takes in unrolled pieces
+ATTN_UNROLLED_MAX = 16384
 
 
 def _per_head(t, heads: int):
@@ -311,10 +316,20 @@ class GroupedQueryAttention(nn.Module):
         out = dense(self.hidden, "o_proj")
 
         def within():
-            """The block attends within itself from position 0."""
-            o = causal_attention_blocked(
-                q, jnp.repeat(k, hq // hk, axis=2),
-                jnp.repeat(v, hq // hk, axis=2), scale)
+            """The block attends within itself from position 0.  Up to
+            ``ATTN_UNROLLED_MAX`` positions in ``causal_attention_blocked``'s
+            unrolled pieces, the keys and values repeated a query head;
+            beyond it (models/jamba.py's 32,768 bucket: 576 pieces of 168 MB
+            score tiles, which the v5e's compiler cannot lay out beside the
+            weights) in ``window_attention_blocked``'s two nested scans with
+            no window, one 512 x 512 tile alive and nothing repeated."""
+            if t > ATTN_UNROLLED_MAX:
+                o = window_attention_blocked(
+                    q.reshape(bsz, t, hk, hq // hk, d), k, v, scale, None)
+            else:
+                o = causal_attention_blocked(
+                    q, jnp.repeat(k, hq // hk, axis=2),
+                    jnp.repeat(v, hq // hk, axis=2), scale)
             return out(o.reshape(bsz, t, hq * d))
 
         if not self.decode_slots:

@@ -19,8 +19,9 @@ Device-side contract (everything else lives in serving/scheduler.py):
 
   - per-position ROWS ``(slots, max_len, ...)``: per-head keys and values
     ``(slots, max_len, kv_heads, head_dim)`` for models/gpt.py, for the
-    attention layers of models/hybrid_ssm.py and for the full-attention
-    layers of models/window_moe.py, a latent and one rotated key head
+    attention layers of models/hybrid_ssm.py and models/jamba.py and for
+    the full-attention layers of models/window_moe.py, a latent and one
+    rotated key head
     ``(slots, max_len, rank)`` for models/mla_moe.py.  Validity is
     LENGTH-DRIVEN: a row at or past the slot's length is never attended,
     so a stale row, a pad row of a prefill bucket and the row a free or
@@ -51,11 +52,13 @@ Device-side contract (everything else lives in serving/scheduler.py):
     KV handoff raise ``NotImplementedError`` by name for a model with
     rings (int8 storage and a tensor-parallel table are refused by the
     model's own ``slot_decode_clone``);
-  - per-slot STATE ``(slots, ...)`` with no position axis: the recurrent
-    state ``(slots, heads, head_dim, state)`` and the convolution tail
-    ``(slots, taps - 1, width)`` of a state-space layer
-    (models/hybrid_ssm.py).  Nothing about it is length-driven: the whole
-    leaf is valid at every moment, and a second write advances it twice.
+  - per-slot STATE ``(slots, ...)`` with no position axis, of whatever
+    shape the model gives it: the recurrent state ``(slots, heads,
+    head_dim, state)`` (models/hybrid_ssm.py) or ``(slots, d_inner,
+    d_state)`` (models/jamba.py) and the convolution tail ``(slots, taps -
+    1, width)`` of a state-space layer.  Nothing about it is
+    length-driven: the whole leaf is valid at every moment, and a second
+    write advances it twice.
     So a PREFILL starts the slot's state from zero whatever the last
     occupant left and makes the bucket's pad rows inert (the state written
     is the state after ``prompt_len`` tokens, the tail its last real
@@ -297,6 +300,7 @@ class SlotKVCache:
         # three kinds)
         self.state_leaves = dict(getattr(model, "slot_state", {}))
         self.ring_leaves = dict(getattr(model, "slot_rings", {}))
+        self.served_model = type(model).__name__    # named in a refusal
         # what ``insert`` runs (the ``prefill`` span's ``form``): one call
         # over the padded prompt, or the chunk scan the prefix pool needs
         self.prefill_form = "scan" if prefix_cache_blocks else "batched"
@@ -418,6 +422,12 @@ class SlotKVCache:
         self.expert_layers = int(getattr(self.dm, "expert_layers", 0))
         self.last_routing: dict[str, float] | None = None
         self.expert_assignments = 0     # (token, expert) pairs, cumulative
+        # a model whose block prefill runs ``ops/selective_scan``: in how
+        # many layers, and the positions and prompt tokens that went
+        # through the kernel (cumulative; ``counters``)
+        self.scan_layers = int(getattr(self.dm, "selective_scan_layers", 0))
+        self.ssm_scan_positions = 0
+        self.ssm_scan_tokens = 0
         self.eos_tok = np.full(self.slots, -1, np.int32)
         self.budget = np.zeros(self.slots, np.int32)
         self.halted = np.zeros(self.slots, np.bool_)
@@ -451,14 +461,14 @@ class SlotKVCache:
             raise NotImplementedError(
                 f"{feature} is not implemented for a model that keeps "
                 f"rings of its last positions beside its full-length rows "
-                f"(models/window_moe.py: {sorted(self.ring_leaves)}): the "
-                f"monolithic table with insert/advance/evict is")
+                f"({self.served_model}: {sorted(self.ring_leaves)}): "
+                f"the monolithic table with insert/advance/evict is")
         if self.state_leaves:
             raise NotImplementedError(
                 f"{feature} is not implemented for a model that keeps "
-                f"per-slot state beside its rows (models/hybrid_ssm.py: "
-                f"{sorted(self.state_leaves)}): the monolithic table with "
-                f"insert/advance/evict is")
+                f"per-slot state beside its rows "
+                f"({self.served_model}: {sorted(self.state_leaves)}): "
+                f"the monolithic table with insert/advance/evict is")
 
     def _place_params(self, params, *, replacing=None):
         """What the table does with a tree it is given: the one door of
@@ -984,6 +994,8 @@ class SlotKVCache:
         self._phase_s["prefill_s"] += time.perf_counter() - t0
         self.prefill_tokens_computed += lp
         self.prefill_tokens_padded += lpad
+        self.ssm_scan_positions += lpad * self.scan_layers
+        self.ssm_scan_tokens += lp * self.scan_layers
         if held:        # a share of the experts: the program counted
             self.expert_assignments += int(held[0])
         elif self.expert_layers:    # every expert held: every choice
@@ -1638,9 +1650,11 @@ class SlotKVCache:
         experts held here (0 for a model without experts), what one token
         of one slot keeps in the table's full-length per-position rows,
         all layers together, what one slot keeps as per-slot state
-        whatever its length (0 for a model without state) and what it
-        keeps in ring rows whatever its length (0 for a model without
-        rings)."""
+        whatever its length (0 for a model without state), what it keeps
+        in ring rows whatever its length (0 for a model without rings),
+        and the bucket positions and the prompt tokens, each times the
+        layers, that went through the selective-scan kernel (0 for a model
+        that has none)."""
         _, state, rings = self._table_bytes()
         return {"prefill_tokens_computed": self.prefill_tokens_computed,
                 "prefill_tokens_padded": self.prefill_tokens_padded,
@@ -1650,7 +1664,9 @@ class SlotKVCache:
                     (self.kv_bytes_per_slot()
                      - (state + rings) // self.slots) // self.max_len,
                 "state_bytes_per_slot": state // self.slots,
-                "window_bytes_per_slot": rings // self.slots}
+                "window_bytes_per_slot": rings // self.slots,
+                "ssm_scan_positions": self.ssm_scan_positions,
+                "ssm_scan_tokens": self.ssm_scan_tokens}
 
     def _table_bytes(self) -> tuple[int, int, int]:
         """Stored bytes of the whole table by kind of leaf: ``(full-length
@@ -1838,6 +1854,7 @@ class PagedSlotKVCache(SlotKVCache):
         self.resumable_step = model.resumable_step
         self.state_leaves = dict(getattr(model, "slot_state", {}))
         self.ring_leaves = dict(getattr(model, "slot_rings", {}))
+        self.served_model = type(model).__name__    # named in a refusal
         self.prefill_form = "scan"      # insert goes through _chunk
         self._scan_model_only("the paged layout")
         # fused clone for the decode/verify hot ops, gather clone for the

@@ -24,6 +24,7 @@ CELLS = [w["name"] for w in BENCH["workloads"]]
     ("tiny-serve-hybrid-ssm", "serve-nemotron3s-chat"),
     ("tiny-serve", "serve-gpt2l-gen"),
     ("tiny-serve-window-moe", "serve-commandaplus-mixedlen"),
+    ("tiny-serve-jamba", "serve-jamba2-longctx"),
 ])
 def test_tiny_cell_runs_through_the_committed_driver(tiny_cell, stands_for):
     result = run_tiny(tiny_cell, stands_for)

@@ -348,6 +348,92 @@ def test_the_longest_bucket_fits_beside_weights_and_table(one_chip):
     assert tiles and max(tiles) <= 512, sorted(tiles)
 
 
+def _jamba_cell(one_chip, monkeypatch, slots, max_len=32768):
+    """The selective-scan cell's table at its published widths
+    (``benchmarks/configs/ai21-jamba2-3b.json``, 32 slots x 32,768,
+    bfloat16), as ``_window_cell``.  The model asks
+    ``ops/flash_attention.resolve_interpret`` whether a chip is attached
+    and would take its interpreter here: the test steers it to Mosaic."""
+    from benchmarks.drivers import jamba_tree
+    from distributed_tensorflow_tpu.ops import selective_scan
+
+    monkeypatch.setattr(selective_scan, "resolve_interpret",
+                        lambda interpret: False)
+    config = json.loads((Path(__file__).resolve().parent.parent / "benchmarks"
+                         / "configs" / "ai21-jamba2-3b.json").read_text())
+    model = create_model("jamba", dtype="bfloat16", param_dtype="bfloat16",
+                         **jamba_tree.model_kwargs(config, max_len))
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32),
+                           train=False))["params"]
+    kv = _ProgramProbe(model, params, 1, greedy=True, kv_dtype=jnp.bfloat16)
+    on_chip, like = _shapes_on(one_chip)
+    cache = jax.tree.map(
+        lambda t: on_chip((slots,) + t.shape[1:], t.dtype), kv.cache)
+    return kv, like(params), cache
+
+
+def test_the_selective_scan_kernel_compiles_at_the_cells_widths(
+        one_chip, monkeypatch):
+    """``ops/selective_scan`` at 4,096 positions x 5,120 channels x 16
+    (one piece of a prefill): Mosaic takes the kernel (state tiles as a
+    loop's carry, ``B`` and ``C`` as scalars in SMEM, a dynamic index on the
+    untiled position axis), and the compiled text names the custom call as
+    ``benchmarks/metrics/kernel.selective_scan_roofline.json`` finds it: by
+    the ``pallas_call``'s name, with the call's own sizes in its result."""
+    from distributed_tensorflow_tpu.ops import selective_scan
+
+    monkeypatch.setattr(selective_scan, "resolve_interpret",
+                        lambda interpret: False)
+    on_chip, _ = _shapes_on(one_chip)
+    f32 = lambda *shape: on_chip(shape, jnp.float32)
+    length, d, n = 4096, 5120, 16
+    text = jax.jit(selective_scan.selective_scan).lower(
+        on_chip((1, length, d), jnp.bfloat16), f32(1, length, d), f32(d, n),
+        f32(1, length, n), f32(1, length, n), f32(d),
+        f32(1, d, n)).compile().as_text()
+    pattern = json.loads((
+        Path(__file__).resolve().parent.parent / "benchmarks" / "metrics"
+        / "kernel.selective_scan_roofline.json").read_text())["pattern"]
+    calls = [re.search(pattern, line.strip()) for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1 and calls[0] is not None
+    assert {k: int(v) for k, v in calls[0].groupdict().items()} == {
+        "batch": 1, "length": length, "groups": d // 128}
+
+
+def test_the_32768_bucket_fits_and_loops_over_no_position(one_chip,
+                                                          monkeypatch):
+    """``kv_prefill_batched_l32768`` of ``serve-jamba2-longctx``: 6.06 GB of
+    weights and 1.37 GB of table are the program's arguments, the table is
+    donated, and the temporaries stay under 1.5 GB (in one piece the bucket
+    needs 12.6 GB, and with a stacked output a layer 9 GB: models/jamba.py).
+    The 26 state-space layers each call the kernel once a piece, and the
+    only loops are over pieces (8 a layer and a feed-forward) and attention
+    blocks (64): none over the bucket's positions."""
+    slots, lpad = 32, 32768
+    kv, params, cache = _jamba_cell(one_chip, monkeypatch, slots)
+    kv._prefill(lpad)
+    prefill, jit_kwargs = kv.programs[f"kv_prefill_batched_l{lpad}"]
+    on_chip, like = _shapes_on(one_chip)
+    compiled = jax.jit(prefill, **jit_kwargs).lower(
+        params, cache, on_chip((), jnp.int32), on_chip((lpad,), jnp.int32),
+        on_chip((), jnp.int32), like(jax.random.key(0))).compile()
+    memory = compiled.memory_analysis()
+    table = slots * (2 * 2 * lpad * 128 * 2
+                     + 26 * (5120 * 16 * 4 + 3 * 5120 * 2))
+    assert table == 1_371_930_624
+    assert memory.alias_size_in_bytes == table              # donated
+    assert 7.4e9 < memory.argument_size_in_bytes < 7.5e9
+    assert memory.peak_memory_in_bytes < 9.0e9, memory.peak_memory_in_bytes
+    text = compiled.as_text()
+    assert len(re.findall(r'custom_call_target="tpu_custom_call"', text)) == 26
+    # 26 loops over a mixer's pieces, 28 over a feed-forward's, and the two
+    # attention layers' two nested loops over blocks: a loop a position
+    # would be one more a state-space layer
+    assert len(re.findall(r" while\(", text)) == 26 + 28 + 2 * 2
+
+
 def test_flash_kernels_compile_at_the_training_cells_shape(one_chip):
     """The three flash kernels at the training cells' call (batch 8, 16
     heads, L 1,024, head 64, bfloat16, causal, no key mask: ``(128, 1024,

@@ -396,7 +396,7 @@ def test_analyze_serve_prints_the_ring_counter(model, weights, tmp_path):
             f"{RING_BYTES} of rings" in render_waterfall_text(wf))
 
 
-# ---------------------------------------------------- counters, four models
+# ---------------------------------------------------- counters, five models
 
 SERVED = {
     "gpt": (dict(vocab_size=64, hidden=32, layers=2, heads=4, ffn=64,
@@ -404,6 +404,7 @@ SERVED = {
     "mla_moe": (dict(vocab_size=64, max_len=64), None, 0, 0),
     "hybrid_ssm": (dict(vocab_size=64, max_len=64), None, None, 0),
     "window_moe": (SIZES, ROW_BYTES, 0, RING_BYTES),
+    "jamba": (dict(vocab_size=64, max_len=64), None, None, 0),
 }
 
 
@@ -412,7 +413,8 @@ def test_counters_tell_the_three_kinds_of_leaf_apart(name):
     """Full-length rows a token, state a slot, rings a slot: for the three
     older models the counters read what they read before rings were known
     (no ring leaf, ``window_bytes_per_slot`` 0), and the kinds add up to
-    the table."""
+    the table.  The two counts of the selective-scan kernel (PR 37) stand
+    at 0 in a table that has served nothing."""
     sizes, row_bytes, state_bytes, ring_bytes = SERVED[name]
     model = create_model(name, **sizes)
     params = model.init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32))[
@@ -422,7 +424,9 @@ def test_counters_tell_the_three_kinds_of_leaf_apart(name):
     assert set(counts) == {
         "prefill_tokens_computed", "prefill_tokens_padded",
         "expert_assignments", "cache_bytes_per_token",
-        "state_bytes_per_slot", "window_bytes_per_slot"}
+        "state_bytes_per_slot", "window_bytes_per_slot",
+        "ssm_scan_positions", "ssm_scan_tokens"}
+    assert counts["ssm_scan_positions"] == counts["ssm_scan_tokens"] == 0
     assert counts["window_bytes_per_slot"] == ring_bytes
     assert bool(kv.ring_leaves) == bool(ring_bytes)
     if row_bytes is not None:
