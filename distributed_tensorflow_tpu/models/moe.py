@@ -26,11 +26,11 @@ The Switch load-balancing auxiliary loss is sown into the
 adds ``aux_weight ×`` it to the task loss.
 
 ``DroplessMoE`` is the layer of today's sparse decoders
-(models/mla_moe.py): any k, sigmoid scores with a choice bias, SwiGLU
-experts, shared experts, NO capacity and no ``[tokens, E, capacity]``
-tensor — the (token, choice) pairs are sorted by expert and go through one
-grouped matrix product (``lax.ragged_dot``), so cost is linear in tokens
-and no token is ever dropped.  It is told which experts it holds.
+(models/mla_moe.py): any k, sigmoid scores with a choice bias (or softmax
+scores: models/keye.py), SwiGLU experts, shared experts, NO capacity and no
+``[tokens, E, capacity]`` tensor — the (token, choice) pairs are sorted by
+expert and go through one grouped matrix product (``lax.ragged_dot``), so
+cost is linear in tokens and no token is ever dropped.  It is told which experts it holds.
 """
 
 from __future__ import annotations
@@ -243,7 +243,13 @@ class DroplessMoE(nn.Module):
     time, so that the ``token_block * top_k`` gathered rows and their four
     companions are all that is live.  The result is the same sum.  With
     ``shared_experts`` 1 and ``token_block`` 0 the layer is what it was
-    before it knew either."""
+    before it knew either.
+
+    ``scoring="softmax"`` is the other router of today's decoders (the
+    Qwen3-MoE family's): ``p = softmax(x W_g)`` in float32 over all
+    ``num_experts``, the ``top_k`` largest of ``p`` chosen, their weights
+    ``p`` divided by their sum when ``norm_topk``; no choice bias is made.
+    The sigmoid router's program is what it was before the option."""
 
     num_experts: int = 8
     top_k: int = 2
@@ -256,6 +262,7 @@ class DroplessMoE(nn.Module):
     latent: int = 0
     shared_experts: int = 1
     token_block: int = 0
+    scoring: str = "sigmoid"
     dtype: jnp.dtype = jnp.float32
     param_dtype: jnp.dtype = jnp.float32
 
@@ -272,14 +279,22 @@ class DroplessMoE(nn.Module):
                              f"relu2")
         init = nn.initializers.lecun_normal()
 
+        if self.scoring not in ("sigmoid", "softmax"):
+            raise ValueError(f"scoring {self.scoring!r}: sigmoid or softmax")
         # --- router (f32, true f32 products: a choice is a comparison) ---
         w_router = self.param("router", init, (d, e), self.param_dtype)
-        bias = self.param("choice_bias", nn.initializers.zeros_init(), (e,),
-                          jnp.float32)
-        scores = jax.nn.sigmoid(jnp.dot(
-            x.astype(jnp.float32), w_router.astype(jnp.float32),
-            precision=jax.lax.Precision.HIGHEST))
-        _, choice = jax.lax.top_k(scores + bias, k)             # [T, k]
+        if self.scoring == "softmax":
+            scores = jax.nn.softmax(jnp.dot(
+                x.astype(jnp.float32), w_router.astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST), axis=-1)
+            _, choice = jax.lax.top_k(scores, k)                # [T, k]
+        else:
+            bias = self.param("choice_bias", nn.initializers.zeros_init(),
+                              (e,), jnp.float32)
+            scores = jax.nn.sigmoid(jnp.dot(
+                x.astype(jnp.float32), w_router.astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST))
+            _, choice = jax.lax.top_k(scores + bias, k)         # [T, k]
         weight = jnp.take_along_axis(scores, choice, axis=-1)
         if self.norm_topk:
             weight = weight / (weight.sum(-1, keepdims=True) + 1e-20)

@@ -22,7 +22,11 @@ Device-side contract (everything else lives in serving/scheduler.py):
     attention layers of models/hybrid_ssm.py and models/jamba.py and for
     the full-attention layers of models/window_moe.py, a latent and one
     rotated key head
-    ``(slots, max_len, rank)`` for models/mla_moe.py.  Validity is
+    ``(slots, max_len, rank)`` for models/mla_moe.py, keys and values
+    ``(slots, max_len, kv_heads * head_dim)`` and the indexer's keys
+    ``(slots, index_head_dim, max_len)`` (positions last) for
+    models/keye.py, whose step attends to the rows its indexer selects
+    among ``0 .. length``.  Validity is
     LENGTH-DRIVEN: a row at or past the slot's length is never attended,
     so a stale row, a pad row of a prefill bucket and the row a free or
     excluded slot writes at its own length are all invisible, and the
@@ -201,7 +205,8 @@ class SlotKVCache:
     """Fixed slot table + compiled prefill/decode programs for one model
     with a slot-decode mode (``models/gpt.GPTLM``,
     ``models/mla_moe.LatentMoELM``, ``models/hybrid_ssm.HybridSSMLM``,
-    ``models/window_moe.WindowMoELM``).
+    ``models/window_moe.WindowMoELM``, ``models/jamba.JambaLM``,
+    ``models/keye.KeyeLM``).
 
     ``model`` is the TRAINING-mode module (any attention impl); its
     ``slot_decode_clone`` gives the module served from — for ``GPTLM``
@@ -430,6 +435,14 @@ class SlotKVCache:
         # many positions of every slot the last ``advance`` read
         self._rows_rule = getattr(self.dm, "slot_rows_read", None)
         self.last_rows_read: int | None = None
+        # a model whose attention keeps the keys a learned indexer selects
+        # (models/keye.py): what the last ``advance`` scored and attended
+        # (``index_rows``, ``attended_rows``) and what the last ``insert``
+        # did (``index_pairs``, ``attended_pairs``), over the layers
+        self._index_rule = getattr(self.dm, "slot_index_rows", None)
+        self._pairs_rule = getattr(self.dm, "prefill_index_pairs", None)
+        self.last_index_rows: dict[str, int] | None = None
+        self.last_prefill_pairs: dict[str, int] | None = None
         # a model whose block prefill runs ``ops/selective_scan``: in how
         # many layers, and the positions and prompt tokens that went
         # through the kernel (cumulative; ``counters``)
@@ -1004,6 +1017,8 @@ class SlotKVCache:
         self.prefill_tokens_padded += lpad
         self.ssm_scan_positions += lpad * self.scan_layers
         self.ssm_scan_tokens += lp * self.scan_layers
+        if self._pairs_rule is not None:
+            self.last_prefill_pairs = self._pairs_rule(lp)
         if held:        # a share of the experts: the program counted
             self.expert_assignments += int(held[0])
         elif self.expert_layers:    # every expert held: every choice
@@ -1330,6 +1345,8 @@ class SlotKVCache:
                 "a single-step advance (host mirrors lag the device)")
         if self._rows_rule is not None:
             self.last_rows_read = self._rows_rule(self.lengths, mask)
+        if self._index_rule is not None:
+            self.last_index_rows = self._index_rule(self.lengths, mask)
         t0 = time.perf_counter()
         with self.tracer.span("step_dispatch"):
             self.cache, d_nxt, d_len, *routing = self._step(

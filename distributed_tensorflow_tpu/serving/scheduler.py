@@ -521,6 +521,8 @@ class ContinuousBatcher:
                 sp["slot"] = slot
                 if kv.ring_leaves:      # rows written into each ring
                     sp["ring_rows"] = min(lp, *kv.ring_leaves.values())
+                if kv.last_prefill_pairs is not None:   # an indexer's
+                    sp.update(kv.last_prefill_pairs)
             self.clock.on_prefill(kv.prefill_tokens_computed - before)
             if self._rf_cost is not None:
                 # credit only positions actually computed: a prefix-cache
@@ -1070,6 +1072,8 @@ class ContinuousBatcher:
                     sp.update(kv.last_routing)
                 if kv.last_rows_read is not None:   # GPT-2's prefix
                     sp["rows_read"] = kv.last_rows_read
+                if kv.last_index_rows is not None:  # an indexer's rows
+                    sp.update(kv.last_index_rows)
             return {slot: [int(toks[slot])] for slot in live}
         return self._spec_round(live, k_eff)
 

@@ -25,6 +25,7 @@ CELLS = [w["name"] for w in BENCH["workloads"]]
     ("tiny-serve", "serve-gpt2l-gen"),
     ("tiny-serve-window-moe", "serve-commandaplus-mixedlen"),
     ("tiny-serve-jamba", "serve-jamba2-longctx"),
+    ("tiny-serve-keye", "serve-keye2-64k"),
 ])
 def test_tiny_cell_runs_through_the_committed_driver(tiny_cell, stands_for):
     result = run_tiny(tiny_cell, stands_for)

@@ -542,3 +542,88 @@ def test_flash_kernels_compile_at_the_training_cells_shape(one_chip):
     assert re.search(r"= bf16\[128,64,1024\]", dq)
     assert re.search(
         r"= \(bf16\[128,1024,64\]\{[^}]*\}, bf16\[128,1024,64\]", dkv)
+
+
+def _keye_cell(one_chip, monkeypatch, slots=8, max_len=65536):
+    """The sparse-attention cell's table at its published widths
+    (``benchmarks/configs/keye-vl-2.0-30b-a3b-4l.json``, 8 slots x 65,536,
+    bfloat16), as ``_window_cell``.  The model asks
+    ``ops/sparse_attention.on_tpu`` whether it is built for the chip and
+    would take the kernels' plain forms here: the test steers it."""
+    from benchmarks.drivers import keye_tree
+    from distributed_tensorflow_tpu.ops import sparse_attention
+
+    monkeypatch.setattr(sparse_attention, "on_tpu", lambda: True)
+    config = json.loads((Path(__file__).resolve().parent.parent / "benchmarks"
+                         / "configs" / "keye-vl-2.0-30b-a3b-4l.json")
+                        .read_text())
+    model = create_model("keye", dtype="bfloat16", param_dtype="bfloat16",
+                         **keye_tree.model_kwargs(config, max_len))
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32),
+                           train=False))["params"]
+    kv = _ProgramProbe(model, params, 1, greedy=True, kv_dtype=jnp.bfloat16)
+    on_chip, like = _shapes_on(one_chip)
+    cache = jax.tree.map(
+        lambda t: on_chip((slots,) + t.shape[1:], t.dtype), kv.cache)
+    return kv, like(params), cache
+
+
+def test_the_sparse_attention_step_reads_selected_rows_in_place(
+        one_chip, monkeypatch):
+    """``kv_decode_step_routed`` of ``serve-keye2-64k``: 6.25 GB of weights
+    and a 4.56 GB table (K and V ``(8, 65536, 512)`` and the indexer's keys
+    ``(8, 64, 65536)`` a layer, unpadded) are the arguments, the table is
+    donated and no K or V leaf is copied (the step gathers its selected
+    rows); each layer calls the scoring and the attention kernel once, by
+    the names the metrics' patterns find."""
+    kv, params, cache = _keye_cell(one_chip, monkeypatch)
+    step, jit_kwargs = kv.programs["kv_decode_step_routed"]
+    on_chip, like = _shapes_on(one_chip)
+    vec = lambda dt: on_chip((8,), dt)
+    compiled = jax.jit(step, **jit_kwargs).lower(
+        params, cache, vec(jnp.int32), vec(jnp.int32), vec(jnp.bool_),
+        like(jax.random.key(0))).compile()
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == 8 * 65536 * 4 * 2176     # donated
+    assert 10.7e9 < memory.argument_size_in_bytes < 10.9e9
+    assert memory.temp_size_in_bytes < 0.2e9
+    text = compiled.as_text()
+    assert not re.findall(r"= \(?bf16\[8,65536,512\][^\n]* copy(-start)?\(",
+                          text)
+    calls = [line.strip() for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    pattern = json.loads((Path(__file__).parent.parent / "benchmarks"
+                          / "metrics" / "kernel.dsa_decode_roofline.json")
+                         .read_text())["rounds_pattern"]
+    assert sum(bool(re.search(pattern, c)) for c in calls) == 4
+    assert sum(c.startswith("%dsa_step_index") for c in calls) == 4
+
+
+def test_the_65536_bucket_fits_beside_weights_and_table(one_chip,
+                                                        monkeypatch):
+    """``kv_prefill_batched_l65536``: the prefill kernel keeps a query
+    block's order keys (32 MB) in VMEM and the experts take 4,096 tokens at
+    a time, so the bucket's temporaries stay under 4 GB and the program,
+    weights and table together under the chip's 16 GiB; each layer calls
+    ``dsa_prefill`` once, on the bucket's length."""
+    kv, params, cache = _keye_cell(one_chip, monkeypatch)
+    lpad = 65536
+    kv._prefill(lpad)
+    prefill, jit_kwargs = kv.programs[f"kv_prefill_batched_l{lpad}"]
+    on_chip, like = _shapes_on(one_chip)
+    compiled = jax.jit(prefill, **jit_kwargs).lower(
+        params, cache, on_chip((), jnp.int32), on_chip((lpad,), jnp.int32),
+        on_chip((), jnp.int32), like(jax.random.key(0))).compile()
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 4.0e9, memory.temp_size_in_bytes
+    assert memory.peak_memory_in_bytes < 15 * 2 ** 30
+    pattern = json.loads((Path(__file__).parent.parent / "benchmarks"
+                          / "metrics" / "kernel.dsa_prefill_roofline.json")
+                         .read_text())["pattern"]
+    calls = [re.search(pattern, line.strip()) for line in
+             compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line
+             and "dsa_prefill" in line]
+    assert len(calls) == 4 and all(
+        c is not None and int(c["length"]) == lpad for c in calls)
