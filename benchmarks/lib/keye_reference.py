@@ -57,8 +57,6 @@ import numpy as np
 from jax import lax
 
 FAULTS = (
-    "dense",            # attention over every key s <= t: no selection
-    "last_rows",        # the last top_k rows t - top_k < s <= t selected
     "no_index_rope",    # the indexer's queries and keys not rotated
     "no_qk_norm",       # q and k without their per-head RMSNorm
     "no_norm_topk",     # the chosen experts' weights not divided by their sum
@@ -188,7 +186,7 @@ def _order(x):
     return bits ^ ((bits >> 31) & 0x7FFFFFFF)
 
 
-def select(scores, at, top_k: int, fault=None):
+def select(scores, at, top_k: int):
     """The selection of queries at positions ``at`` (R,) as a mask (R, P)
     over ``scores`` (R, P): the rows of ``lax.top_k`` of each query's scores
     at ``s <= t`` (ties to the lower position).  Computed by counting, not
@@ -199,12 +197,7 @@ def select(scores, at, top_k: int, fault=None):
     block of queries, and minutes of the check at the cell's longest
     request."""
     p = scores.shape[1]
-    keys = jnp.arange(p)[None, :]
-    valid = keys <= at[:, None]
-    if fault == "dense":
-        return valid
-    if fault == "last_rows":
-        return valid & (keys > at[:, None] - top_k)
+    valid = jnp.arange(p)[None, :] <= at[:, None]
     key = jnp.where(valid, _order(scores), jnp.iinfo(jnp.int32).min)
     k = jnp.minimum(top_k, valid.sum(1, keepdims=True))
 
@@ -232,7 +225,7 @@ def attend(q, qi, wt, at, k, v, ki, dims, mode, fault):
 
     def block(q_b, qi_b, wt_b, at_b):
         mask = select(index_scores(qi_b, wt_b, ki, dims, mode), at_b,
-                      dims["top_k"], fault)
+                      dims["top_k"])
         scores = _mm(q_b.reshape(q_b.shape[0], hk, g, -1), k, mode,
                      "qhgk,thk->hgqt") * dims["head_dim"] ** -0.5
         prob = jax.nn.softmax(jnp.where(mask, scores, -jnp.inf), -1)

@@ -34,8 +34,8 @@ PUBLISHED = {
                   "q_chunk_size": 512, "topk": 2048},
     "sliding_window": None, "tie_word_embeddings": False,
     "use_sliding_window": False, "vocab_size": 151936}
-FAULTS = ("dense", "last_rows", "no_index_rope", "no_qk_norm", "no_norm_topk",
-          "stale_rows")
+FAULTS = ("no_index_rope", "no_qk_norm", "no_norm_topk")     # the reference's
+CHECKS = ("token_logit_gap", "selection_gap", "attend_gap")
 
 
 def cells_of(metric: dict) -> list[str]:
@@ -221,9 +221,26 @@ def test_the_tiny_cell_runs_through_the_new_driver():
     result = run_tiny("tiny-serve-keye", CELL)
     assert result["correct"], result["checks"]
     assert result["checks"]["selection_gap"]["value"] == 0.0
-    assert set(result["checks"]) == {"token_logit_gap", "near_tie_share",
-                                     "selection_gap", "requests_failed",
+    assert result["checks"]["attend_gap"]["value"] < 1e-5
+    assert set(result["checks"]) == {*CHECKS, "requests_failed",
                                      "compiles_in_window"}
+
+
+@pytest.mark.parametrize("fault", [
+    "prefill_dense", "prefill_last_rows", "step_dense", "step_last_rows",
+    "stale_rows"])
+def test_a_fault_planted_in_the_program_fails_a_limit(fault):
+    """The tiny cell served by a program with the fault in it: what the
+    served attention left in the table, or the logits, fail a limit."""
+    from benchmarks.drivers import serve_keye
+
+    assert fault in serve_keye.PROGRAM_FAULTS
+    with serve_keye.planted(fault):
+        result = run_tiny("tiny-serve-keye", CELL, seed=3900000011)
+    checks = result["checks"]
+    assert not result["correct"]
+    assert any(checks[k]["value"] > checks[k]["limit"] for k in CHECKS), \
+        checks
 
 
 @pytest.fixture(scope="module")
@@ -253,7 +270,11 @@ def test_the_sample_holds_the_longest_and_the_probe(served):
     assert total[rids[0]] == max(total.values())
     assert run.probe is not None and run.probe["rid"] in rids
     assert run.probe["stale"] > 0       # a longer occupant's rows remain
-    assert len(run.probe["rows"]) == 2
+    assert len(run.probe["layers"]) == 2
+    n = run.probe["layers"][0]["keys"].shape[0]
+    prompt = next(len(r["prompt"]) for r, _ in run.finished
+                  if r["rid"] == run.probe["rid"])
+    assert run.probe["at"].tolist() == [n - 1, prompt - 1]
 
 
 @pytest.mark.parametrize("variant", [{"mode": "fp8"}] + [
@@ -262,11 +283,9 @@ def test_the_control_and_the_planted_faults_fail_a_limit(served, variant):
     run, sample = served
     limits = run.cell["limits"]
     program = run.gaps(sample)
-    assert all(program[k] <= limits[k] for k in ("token_logit_gap",
-                                                  "selection_gap"))
+    assert all(program[k] <= limits[k] for k in CHECKS)
     got = run.gaps(sample, **variant)
-    assert any(got[k] > limits[k] for k in ("token_logit_gap",
-                                            "selection_gap")), got
+    assert any(got[k] > limits[k] for k in CHECKS), got
 
 
 def test_the_new_readers_read_the_windows_records_and_the_kernels_events(
@@ -322,3 +341,33 @@ def test_the_new_readers_read_the_windows_records_and_the_kernels_events(
                            (step, keye_readers.step_roofline),
                            (params, keye_readers.decode_step_mbu)):
         assert reader(params, {}, ctx) is None
+
+
+def test_the_calibration_serves_the_program_the_control_and_every_fault():
+    """``calibrate_keye.readings`` on the tiny cell: the program within
+    every limit, the control and each fault (in the reference and in the
+    program) past one."""
+    import jax
+
+    from benchmarks import calibrate_keye
+    from benchmarks.drivers import serve_keye
+    from benchmarks.lib import keye_reference
+    from helpers import tiny
+
+    bench, cell, config = tiny("tiny-serve-keye", CELL)
+    got = []
+    calibrate_keye.readings(
+        cell, config, [3900000011], jax.devices()[:1], controls=1,
+        faults=list(serve_keye.PROGRAM_FAULTS),
+        reference_faults=list(keye_reference.FAULTS), new=6, seconds=1.0,
+        emit=got.append, note=lambda *_: None)
+    limits = cell["limits"]
+    by_who = {r["who"]: r for r in got}
+    assert set(by_who) == {"program", "control_fp8"} | {
+        f"reference_{f}" for f in keye_reference.FAULTS} | {
+        f"program_{f}" for f in serve_keye.PROGRAM_FAULTS}
+    assert all(by_who["program"][k] <= limits[k] for k in CHECKS)
+    assert by_who["program"]["probe_stale"] > 0
+    for who, r in by_who.items():
+        if who != "program":
+            assert any(r[k] > limits[k] for k in CHECKS), (who, r)

@@ -32,9 +32,10 @@ scores' constant factors.
 
 The attention is ``ops/sparse_attention``: the kernel ``dsa_prefill`` over a
 block from position 0 (training-mode forward, prefill), and in the one-token
-STEP the kernel ``dsa_step_index`` (each slot's index rows to its own
-position), the exact selection, a gather of the selected K/V rows and the
-kernel ``dsa_step_attend`` over them.  Off the TPU their plain forms run.
+STEP (``step_attention``) the kernel ``dsa_step_index`` (the new index key
+written, each slot's index rows scored to its own position), the exact
+selection, a gather of the selected K/V rows and the kernel
+``dsa_step_attend`` over them.  Off the TPU their plain forms run.
 
 Slot-decode mode keeps three ROWS leaves a layer (serving/kv_cache.py's
 contract: validity is length-driven): ``cached_key`` and ``cached_value``
@@ -48,6 +49,17 @@ a slot at the slot's position, which writes that position's three rows
 first and so selects among ``0 .. pos``.  A slot that is not ``active``
 writes no row (a free slot's rows stay as its last occupant left them) and
 sends its stale token to no expert.
+
+Beside the rows, each layer keeps per-slot STATE (``slot_state``) that
+nothing in the model reads: what its attention took and gave at one query,
+so that a comparison can hold the served path itself to its rule.  Entry 0
+of ``seen_q``, ``seen_o`` (the query and the attention's output, ``(slots,
+2, Hq * d)``), ``seen_qi`` (the index queries, ``(slots, 2, Hi * Di)``) and
+``seen_w`` (the head weights, float32, ``(slots, 2, Hi)``) is the slot's
+last step, entry 1 its prefill's last prompt position; ``seen_rows``
+``(slots, top_k)`` holds the rows the last step selected (-1 after a
+prefill).  With the slot's rows, the query's selection and its attention
+can be recomputed from what the program itself computed.
 
 Departures from the published model: no vision tower (text tokens only; the
 three mrope sections carry one position for a text token, which is this
@@ -70,18 +82,27 @@ from distributed_tensorflow_tpu.ops import sparse_attention as dsa
 # tokens the expert layer takes at a time past its router (DroplessMoE's
 # ``token_block``): what keeps a 65,536 bucket's gathered rows small
 MOE_TOKEN_BLOCK = 4096
+# what a slot's attention took and gave at one query (module docstring)
+SEEN = ("seen_q", "seen_o", "seen_qi", "seen_w")
 
 
-def write_slot_columns(table, update, pos):
-    """``table[b, :, pos[b]] = update[b, :, 0]``: ``write_slot_rows`` for a
-    table whose positions lie along its last axis (``(slots, width,
-    max_len)``); a position outside the table writes nothing."""
-    dnums = lax.ScatterDimensionNumbers(
-        update_window_dims=(1, 2), inserted_window_dims=(),
-        scatter_dims_to_operand_dims=(2,), operand_batching_dims=(0,),
-        scatter_indices_batching_dims=(0,))
-    return lax.scatter(table, pos[:, :1], update.astype(table.dtype), dnums,
-                       mode=lax.GatherScatterMode.FILL_OR_DROP)
+def step_attention(q, qi, w, keys, values, index_keys, new_key, at, put, *,
+                   top_k: int, scale: float):
+    """The one-token step's attention of every slot: the new index key
+    written at ``put`` and each slot's index rows scored to ``at``
+    (``dsa_step_index``), the selection (``step_select``), the selected K/V
+    rows gathered and attended (``dsa_step_attend``).  ``q`` (slots, Hq,
+    d), ``qi`` (slots, Hi, Di), ``w`` (slots, Hi), ``keys`` / ``values``
+    (slots, max_len, Hk * d), ``index_keys`` (slots, Di, max_len),
+    ``new_key`` (slots, Di), ``at`` / ``put`` (slots,) -> the output
+    (slots, Hq, d), the selected rows (slots, K) and the index rows."""
+    scores, index_keys = dsa.dsa_step_index(qi, w, index_keys, new_key, at,
+                                            put)
+    rows, count = dsa.step_select(scores, at, top_k)
+    picked = [jnp.take_along_axis(t, rows[..., None], axis=1).astype(q.dtype)
+              for t in (keys, values)]
+    return (dsa.dsa_step_attend(q, *picked, count, scale=scale), rows,
+            index_keys)
 
 
 def rope_part(x, pos, dims: int, theta: float):
@@ -144,8 +165,11 @@ class SparseIndexAttention(nn.Module):
         ik_gain = vector("index_k_norm", di)
         ik_bias = vector("index_k_bias", di, nn.initializers.zeros_init())
 
-        q = apply_rope(rms(product(x, w_q).reshape(b, t, hq, d), q_norm),
-                       pos, self.rope_theta)
+        def query(rows, at):
+            return apply_rope(rms(product(rows, w_q).reshape(
+                rows.shape[:2] + (hq, d)), q_norm), at, self.rope_theta)
+
+        q = query(x, pos)
         k = apply_rope(rms(product(x, w_k).reshape(b, t, hk, d), k_norm),
                        pos, self.rope_theta)
         v = product(x, w_v).reshape(b, t, hk, d)
@@ -164,13 +188,13 @@ class SparseIndexAttention(nn.Module):
         def out(o):
             return product(o.reshape(b, t, hq * d), w_o)
 
-        def within():
+        def attend():
             """The block attends within itself from position 0."""
-            return out(dsa.dsa_prefill(q, k, v, qi, w, ki,
-                                       top_k=self.index_topk, scale=scale))
+            return dsa.dsa_prefill(q, k, v, qi, w, ki, top_k=self.index_topk,
+                                   scale=scale)
 
         if not self.decode_slots:
-            return within()
+            return out(attend())
         # has_variable is False exactly during .init(): create the table,
         # write nothing (models/gpt.py's guard)
         ready = self.has_variable("cache", "cached_key")
@@ -179,8 +203,15 @@ class SparseIndexAttention(nn.Module):
                   for name in ("cached_key", "cached_value"))
         cki = self.variable("cache", "index_key", jnp.zeros,
                             (b, di, self.max_len), self.dtype)
+        seen = [self.variable("cache", name, jnp.zeros, (b, 2, width), kind)
+                for name, width, kind in zip(
+                    SEEN, (hq * d, hq * d, hi * di, hi),
+                    (self.dtype,) * 3 + (f32,))]
+        seen_rows = self.variable("cache", "seen_rows", jnp.zeros,
+                                  (b, min(self.index_topk, self.max_len)),
+                                  jnp.int32)
         if not ready:
-            return within()
+            return out(attend())
         if prompt_len is not None:
             # the bucket's rows from position 0; pad rows lie past the
             # prompt, invisible under the length
@@ -189,7 +220,20 @@ class SparseIndexAttention(nn.Module):
                              (cki, jnp.swapaxes(ki, 1, 2))):
                 var.value = lax.dynamic_update_slice(
                     var.value, new.astype(var.value.dtype), (0, 0, 0))
-            return within()
+            o = attend()
+            last = (prompt_len - 1).astype(jnp.int32)[:, None]
+            at_last = lambda a: jnp.take_along_axis(
+                a.reshape(b, t, -1), last[..., None], axis=1)
+            # the last position's query made again from its row: a gather
+            # of the bucket's queries would hold all of them past the kernel
+            q_last = query(at_last(x), jnp.take_along_axis(pos, last, 1))
+            for var, row in zip(seen, (q_last, o, qi, w)):
+                row = row if row is q_last else at_last(row)
+                var.value = jnp.broadcast_to(
+                    row.reshape(b, 1, -1), var.value.shape).astype(
+                        var.value.dtype)
+            seen_rows.value = jnp.full_like(seen_rows.value, -1)
+            return out(o)
         if t != 1:
             raise ValueError(
                 "the sparse-attention step takes one token a slot: a token "
@@ -197,18 +241,21 @@ class SparseIndexAttention(nn.Module):
         at = pos[:, 0]
         # a slot that sits the round out writes nothing: its position is
         # taken past the table, which the writes drop
-        put = pos if active is None \
-            else jnp.where(active[:, None], pos, self.max_len)
-        ck.value = write_slot_rows(ck.value, k.reshape(b, 1, hk * d), put)
-        cv.value = write_slot_rows(cv.value, v.reshape(b, 1, hk * d), put)
-        cki.value = write_slot_columns(cki.value, jnp.swapaxes(ki, 1, 2), put)
-        scores = dsa.dsa_step_index(qi[:, 0], w[:, 0], cki.value, at)
-        idx, count = dsa.step_select(scores, at, self.index_topk)
-        keys, values = (jnp.take_along_axis(var.value, idx[..., None], axis=1)
-                        for var in (ck, cv))
-        return out(dsa.dsa_step_attend(q[:, 0], keys.astype(self.dtype),
-                                       values.astype(self.dtype), count,
-                                       scale=scale))
+        live = jnp.ones((b,), bool) if active is None else active
+        put = jnp.where(live, at, self.max_len)
+        ck.value = write_slot_rows(ck.value, k.reshape(b, 1, hk * d),
+                                   put[:, None])
+        cv.value = write_slot_rows(cv.value, v.reshape(b, 1, hk * d),
+                                   put[:, None])
+        o, rows, cki.value = step_attention(
+            q[:, 0], qi[:, 0], w[:, 0], ck.value, cv.value, cki.value,
+            ki[:, 0], at, put, top_k=self.index_topk, scale=scale)
+        for var, x in zip(seen, (q, o, qi, w)):
+            var.value = var.value.at[:, 0].set(jnp.where(
+                live[:, None], x.reshape(b, -1).astype(var.value.dtype),
+                var.value[:, 0]))
+        seen_rows.value = jnp.where(live[:, None], rows, seen_rows.value)
+        return out(o[:, None])
 
 
 class KeyeLM(nn.Module):
@@ -243,6 +290,9 @@ class KeyeLM(nn.Module):
 
     causal_lm = True
     resumable_step = False       # the step has no token block to resume
+    # per-slot state (serving/kv_cache.py), none narrowed by the table's
+    # dtype: what each layer's attention took and gave at one query
+    slot_state = {name: False for name in SEEN + ("seen_rows",)}
 
     @property
     def expert_layers(self) -> int:

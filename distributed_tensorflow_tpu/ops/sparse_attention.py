@@ -36,9 +36,12 @@ Three kernels, each a ``pallas_call`` with a ``name`` the device trace finds:
 * ``dsa_step_index`` (the one-token step's scoring).  The grid is ``(slots,
   index tiles)``; a slot's tiles past its own position fetch and compute
   nothing, so a round reads each slot's index rows to its length and not to
-  ``max_len``.  The step takes the ``top_k`` of those scores with
-  ``lax.top_k`` (exact, and its ties are the rule's by definition) and
-  gathers the selected K/V rows.
+  ``max_len``.  It also writes the round's new index key: the tile that
+  holds a slot's write position is scored with the key in it and copied
+  back into the table, which the kernel holds in place (aliased), so no
+  other program touches the index rows in the step.  The step takes the
+  ``top_k`` of those scores with ``lax.top_k`` (exact, and its ties are the
+  rule's by definition) and gathers the selected K/V rows.
 * ``dsa_step_attend`` (the one-token step's attention): each slot's query
   over its gathered rows only, the rows past the slot's count masked.
 
@@ -128,14 +131,19 @@ def prefill_plain(q, k, v, qi, w, ki, *, top_k: int, scale: float):
     return _attend_masked(q, k, v, mask, scale)
 
 
-def step_index_plain(qi, w, index_keys, pos):
+def step_index_plain(qi, w, index_keys, new_key, pos, put):
     """What ``dsa_step_index`` computes: ``qi`` (slots, Hi, Di), ``w``
-    (slots, Hi), ``index_keys`` (slots, Di, max_len), ``pos`` (slots,) ->
-    (slots, max_len) float32, meaningful at positions ``<= pos``."""
+    (slots, Hi), ``index_keys`` (slots, Di, max_len), ``new_key`` (slots,
+    Di) written at ``put`` (slots,; a position past the table writes
+    nothing), ``pos`` (slots,) -> the scores (slots, max_len) float32,
+    meaningful at positions ``<= pos``, and the index rows."""
     del pos
+    index_keys = index_keys.at[jnp.arange(qi.shape[0]), :, put].set(
+        new_key.astype(index_keys.dtype), mode="drop")
     r = jnp.einsum("bhd,bdl->bhl", qi, index_keys,
                    preferred_element_type=jnp.float32)
-    return jnp.einsum("bhl,bh->bl", jax.nn.relu(r), w.astype(jnp.float32))
+    return (jnp.einsum("bhl,bh->bl", jax.nn.relu(r), w.astype(jnp.float32)),
+            index_keys)
 
 
 def step_attend_plain(q, keys, values, count, *, scale: float):
@@ -395,51 +403,100 @@ def _last_tile(pos, bk: int, tiles: int):
     return jnp.minimum(pos // bk, tiles - 1)
 
 
-def _index_kernel(pos_ref, qi_ref, w_ref, ki_ref, o_ref, *, bk: int):
+def _index_kernel(pos_ref, put_ref, qi_ref, w_ref, new_ref, ki_ref, o_ref,
+                  ki_hbm, tile_scr, sem, *, bk: int, max_len: int):
+    """One (slot, index tile) grid step; ``ki_hbm`` is the whole table,
+    aliased to the input whose blocks ``ki_ref`` are."""
     b, j = pl.program_id(0), pl.program_id(1)
+    last = _last_tile(pos_ref[b], bk, pl.num_programs(1))
+    put = put_ref[b]
+    # the slot writes its new key where its write position lies in a tile
+    # it reads (a position past the table is no tile's)
+    writes = (put >= 0) & (put < max_len) & (put // bk <= last)
+    here = writes & (j == put // bk)
 
-    @pl.when(j <= _last_tile(pos_ref[b], bk, pl.num_programs(1)))
-    def _():
-        r = jnp.dot(qi_ref[0], ki_ref[0], preferred_element_type=jnp.float32)
+    def write():
+        return pltpu.make_async_copy(
+            tile_scr, ki_hbm.at[b, :, pl.ds((put // bk) * bk, bk)], sem.at[0])
+
+    def score(tile):
+        r = jnp.dot(qi_ref[0], tile, preferred_element_type=jnp.float32)
         o_ref[0] = jnp.sum(w_ref[0] * jnp.maximum(r, 0.0), axis=0,
                            keepdims=True)
 
+    @pl.when((j <= last) & ~here)
+    def _():
+        score(ki_ref[0])
+
+    @pl.when(here)
+    def _():
+        lane = j * bk + lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+        tile_scr[...] = jnp.where(
+            lane == put, new_ref[0].astype(jnp.float32),
+            ki_ref[0].astype(jnp.float32)).astype(tile_scr.dtype)
+        write().start()
+        score(tile_scr[...])
+
+    # the write overlaps the slot's later tiles: wait for it at the slot's
+    # last step, before the next slot's tile takes the scratch
+    @pl.when(writes & (j == pl.num_programs(1) - 1))
+    def _():
+        write().wait()
+
 
 @functools.partial(jax.jit, static_argnames=("interpret",), inline=True)
-def _index_call(qi, w, index_keys, pos, *, interpret: bool):
+def _index_call(qi, w, index_keys, new_key, pos, put, *, interpret: bool):
     slots, hi, di = qi.shape
     max_len = index_keys.shape[2]
     bk = min(STEP_BLOCK, max_len)
-    tiles = max_len // bk
-    held = lambda b, j, p: (b, 0, jnp.minimum(j, _last_tile(p[b], bk, tiles)))
-    out = pl.pallas_call(
-        functools.partial(_index_kernel, bk=bk),
+    tiles = -(-max_len // bk)
+    if tiles * bk != max_len:   # whole tiles: the rows past the table
+        index_keys = jnp.pad(   # lie past every position, never selected
+            index_keys, ((0, 0), (0, 0), (0, tiles * bk - max_len)))
+    held = lambda b, j, p, u: (
+        b, 0, jnp.minimum(j, _last_tile(p[b], bk, tiles)))
+    one = lambda b, j, p, u: (b, 0, 0)
+    scores, index_keys = pl.pallas_call(
+        functools.partial(_index_kernel, bk=bk, max_len=max_len),
         name="dsa_step_index",
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=2,
             grid=(slots, tiles),
-            in_specs=[pl.BlockSpec((1, hi, di), lambda b, j, p: (b, 0, 0)),
-                      pl.BlockSpec((1, hi, 1), lambda b, j, p: (b, 0, 0)),
+            in_specs=[pl.BlockSpec((1, hi, di), one),
+                      pl.BlockSpec((1, hi, 1), one),
+                      pl.BlockSpec((1, di, 1), one),
                       pl.BlockSpec((1, di, bk), held)],
-            out_specs=pl.BlockSpec((1, 1, bk), held)),
-        out_shape=jax.ShapeDtypeStruct((slots, 1, max_len), jnp.float32),
+            out_specs=[pl.BlockSpec((1, 1, bk), held),
+                       pl.BlockSpec(memory_space=pl.ANY)],
+            scratch_shapes=[pltpu.VMEM((di, bk), index_keys.dtype),
+                            pltpu.SemaphoreType.DMA((1,))]),
+        out_shape=[jax.ShapeDtypeStruct((slots, 1, tiles * bk), jnp.float32),
+                   jax.ShapeDtypeStruct(index_keys.shape, index_keys.dtype)],
+        input_output_aliases={5: 1},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(pos.astype(jnp.int32), qi, w.astype(jnp.float32)[..., None],
-      index_keys)
-    return out[:, 0]
+    )(pos.astype(jnp.int32), put.astype(jnp.int32), qi,
+      w.astype(jnp.float32)[..., None],
+      new_key.astype(index_keys.dtype)[..., None], index_keys)
+    return scores[:, 0, :max_len], index_keys[:, :, :max_len]
 
 
-def dsa_step_index(qi, w, index_keys, pos, *, interpret: bool | None = None):
-    """The step's indexer scores: ``qi`` (slots, Hi, Di), ``w`` (slots,
-    Hi), ``index_keys`` (slots, Di, max_len), ``pos`` (slots,) the position
-    each slot's query is at -> (slots, max_len) float32, computed (and
-    read) only to each slot's position; what lies past it is not a score."""
+def dsa_step_index(qi, w, index_keys, new_key, pos, put=None, *,
+                   interpret: bool | None = None):
+    """The step's indexer scores, with the round's new index key written
+    first: ``qi`` (slots, Hi, Di), ``w`` (slots, Hi), ``index_keys``
+    (slots, Di, max_len), ``new_key`` (slots, Di) written at ``put``
+    (slots,; ``pos`` where None; a position past the table writes nothing),
+    ``pos`` (slots,) the position each slot's query is at -> the scores
+    (slots, max_len) float32, computed (and read) only to each slot's
+    position (what lies past it is not a score), and the index rows."""
+    put = pos if put is None else put
     interpret = bool(interpret) and resolve_interpret(True)
     if not (interpret or on_tpu()):
-        return step_index_plain(qi, w, index_keys, pos)
-    return _index_call(qi, w, index_keys, pos, interpret=interpret)
+        return step_index_plain(qi, w, index_keys, new_key, pos, put)
+    return _index_call(qi, w, index_keys, new_key, pos, put,
+                       interpret=interpret)
 
 
 def _attend_kernel(count_ref, q_ref, k_ref, v_ref, o_ref, *, scale: float,

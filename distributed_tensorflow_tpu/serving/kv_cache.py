@@ -60,7 +60,9 @@ Device-side contract (everything else lives in serving/scheduler.py):
     shape the model gives it: the recurrent state ``(slots, heads,
     head_dim, state)`` (models/hybrid_ssm.py) or ``(slots, d_inner,
     d_state)`` (models/jamba.py) and the convolution tail ``(slots, taps -
-    1, width)`` of a state-space layer.  Nothing about it is
+    1, width)`` of a state-space layer, or what an attention layer of
+    models/keye.py took and gave at its last query (read by no later
+    token: kept for a comparison to read back).  Nothing about it is
     length-driven: the whole leaf is valid at every moment, and a second
     write advances it twice.
     So a PREFILL starts the slot's state from zero whatever the last

@@ -17,12 +17,15 @@ TOL: program and reference both compute in float32 here and differ in the
 order of their sums only; measured 2e-6 on logits of size 5.  2e-5 leaves a
 factor of ten, and the least planted fault moves a logit by 1.7."""
 
+import contextlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 import keye_reference as ref
+from benchmarks.drivers import serve_keye
 from distributed_tensorflow_tpu.models import create_model
 from distributed_tensorflow_tpu.models.moe import DroplessMoE
 from distributed_tensorflow_tpu.observability.trace import recorder
@@ -171,6 +174,43 @@ def test_a_slot_reused_after_a_longer_occupant_serves_as_a_fresh_one(
     assert float(jnp.max(jnp.abs(got - want))) < TOL
 
 
+@pytest.mark.parametrize("fault", [None, *serve_keye.PROGRAM_FAULTS])
+def test_what_the_served_attention_left_holds_it_to_its_rule(
+        fault, model, weights, tokens):
+    """A slot's ``seen_*`` state, read back with its rows after a prefill
+    (in a slot a longer occupant left) and 6 steps: from the program's own
+    query, K/V and index rows, its output at the step and at the prompt's
+    last position is attention over the selection its own index query makes
+    (to float32 rounding), and the rows the step recorded are that
+    selection.  A fault planted in the program breaks one of the two."""
+    slot, lp, steps = 1, 21, 6
+    longer = np.asarray(jax.random.randint(jax.random.key(5), (50,), 0, 97))
+    with serve_keye.planted(fault) if fault else contextlib.nullcontext():
+        kv = SlotKVCache(model, to_flax(weights), 3, prefill_bucket=8)
+        kv.insert(longer, slot=slot)
+        for _ in range(5):
+            kv.advance()
+        kv.evict(slot)
+        kv.insert(np.asarray(tokens[:lp]), slot=slot)
+        for _ in range(steps):
+            kv.advance()
+    n, at = lp + steps, jnp.array([lp + steps - 1, lp - 1])
+    worst, same = 0.0, True
+    for i in range(LAYERS):
+        c = jax.tree.map(lambda t: t[slot], kv.cache[f"attn_{i}"])
+        mask, gap = serve_keye.witness(
+            {k: c[k] for k in serve_keye.SEEN[:4]}, c["cached_key"][:n],
+            c["cached_value"][:n], c["index_key"][:, :n], at, top_k=TOP_K,
+            kv_heads=2)
+        worst = max(worst, float(jnp.max(gap)))
+        rows = np.sort(np.asarray(c["seen_rows"]))
+        same &= rows.tolist() == np.flatnonzero(mask[0]).tolist()
+    if fault is None:
+        assert worst < 1e-5 and same
+    else:
+        assert worst > 0.05 or not same, (worst, same)
+
+
 def test_the_batcher_serves_each_request_as_the_reference(model, weights,
                                                           tokens):
     kv = SlotKVCache(model, to_flax(weights), 2, prefill_bucket=8)
@@ -264,21 +304,33 @@ def test_the_prefill_kernel_is_its_plain_form_on_random_inputs():
     assert float(jnp.max(jnp.abs(got - want))) < 1e-5
 
 
-def test_the_step_kernels_select_and_attend_as_the_reference():
-    """Slots at a position below ``top_k`` (fewer rows than it), in the
-    middle, at ``max_len - 1`` and past the table (a slot frozen there
-    reads to its end); the scores past a slot's position are not read."""
-    rng = np.random.default_rng(4)
-    slots, max_len, k, hi, di = 4, 512, 16, 4, 8
+def _step_index_inputs(rng, slots, max_len, hi=4, di=8):
     qi = jnp.asarray(rng.integers(-2, 3, (slots, hi, di)), jnp.float32)
     w = jnp.asarray(rng.choice([-1.0, 1.0, 2.0], (slots, hi)), jnp.float32)
     rows = jnp.asarray(rng.integers(-2, 3, (slots, di, max_len)),
                        jnp.float32)
+    new = jnp.asarray(rng.integers(3, 5, (slots, di)), jnp.float32)
+    return qi, w, rows, new
+
+
+def test_the_step_kernels_select_and_attend_as_the_reference():
+    """Slots at a position below ``top_k`` (fewer rows than it), in the
+    middle, at ``max_len - 1`` and past the table (a slot frozen there
+    reads to its end and writes nothing); the scores past a slot's position
+    are not read, and the new index key lands at the slot's position only,
+    scored with it."""
+    rng = np.random.default_rng(4)
+    slots, max_len, k = 4, 512, 16
+    qi, w, rows, new = _step_index_inputs(rng, slots, max_len)
     pos = jnp.array([5, 300, max_len - 1, max_len])
-    got = dsa.dsa_step_index(qi, w, rows, pos, interpret=True)
-    want = dsa.step_index_plain(qi, w, rows, pos)
+    got, table = dsa.dsa_step_index(qi, w, rows, new, pos, interpret=True)
+    want, want_table = dsa.step_index_plain(qi, w, rows, new, pos, pos)
     seen = jnp.arange(max_len)[None] <= pos[:, None]
     assert bool(jnp.all(jnp.where(seen, got == want, True)))
+    assert bool(jnp.all(table == want_table))
+    written = np.asarray(jnp.any(table != rows, axis=1))
+    assert [np.flatnonzero(r).tolist() for r in written] == [
+        [5], [300], [max_len - 1], []]
     idx, count = dsa.step_select(got, pos, k)
     assert count.tolist() == [6, k, k, k]
     mask = ref.select(want, jnp.minimum(pos, max_len - 1), k)
@@ -292,6 +344,29 @@ def test_the_step_kernels_select_and_attend_as_the_reference():
                               interpret=True)
     want = dsa.step_attend_plain(q, keys, values, count, scale=0.3)
     assert float(jnp.max(jnp.abs(got - want))) < 1e-5
+
+
+@pytest.mark.parametrize("max_len", [200, 333])
+def test_the_step_scoring_at_a_table_of_no_whole_tiles(max_len, monkeypatch):
+    """A table whose length is not a multiple of the scoring's tile: the
+    last tile's rows past the table are scored as no position, the rows in
+    it are, and a slot at the last position writes its key there; the
+    write position of a slot that sits the round out (past the table)
+    writes nothing."""
+    monkeypatch.setattr(dsa, "STEP_BLOCK", 64)
+    rng = np.random.default_rng(max_len)
+    slots = 4
+    qi, w, rows, new = _step_index_inputs(rng, slots, max_len)
+    pos = jnp.array([3, 130, max_len - 1, max_len - 2])
+    put = pos.at[3].set(max_len)
+    got, table = dsa.dsa_step_index(qi, w, rows, new, pos, put,
+                                    interpret=True)
+    want, want_table = dsa.step_index_plain(qi, w, rows, new, pos, put)
+    assert got.shape == (slots, max_len) and table.shape == rows.shape
+    seen = jnp.arange(max_len)[None] <= pos[:, None]
+    assert bool(jnp.all(jnp.where(seen, got == want, True)))
+    assert bool(jnp.all(table == want_table))
+    assert bool(jnp.all(table[3] == rows[3]))
 
 
 def test_the_model_through_the_kernels_is_the_model_through_the_plain_forms(
