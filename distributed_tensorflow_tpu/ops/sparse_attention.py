@@ -20,7 +20,11 @@ midpoint, which needs no sort and no gather.  What the bisection finds is a
 threshold ``thr`` and a position ``cut``: a key is selected when it lies
 above ``thr``, or at ``thr`` and at or before ``cut`` (the ties at the
 threshold are taken from the lowest position up, as many as the top_k still
-needs).  A row whose count lands on ``top_k`` exactly stops early.
+needs).  A row whose count lands on ``top_k`` exactly stops early.  A count
+is a compare and an add an element (``_count``): each key tile's hits go as
+0 or 1 into a (rows, 128) accumulator held in registers, summed over lanes
+once a count, and only the tile on the block's diagonal is masked by
+position, since every earlier tile lies wholly at or before every row.
 
 Three kernels, each a ``pallas_call`` with a ``name`` the device trace finds:
 
@@ -65,6 +69,8 @@ from distributed_tensorflow_tpu.ops.flash_attention import resolve_interpret
 Q_BLOCK = 128           # prefill queries a grid step
 K_BLOCK = 512           # prefill keys a grid step
 STEP_BLOCK = 2048       # index rows a grid step of the step's scoring
+UNROLL = 4              # key tiles a step of the selection's counting loop
+LANES = 128             # lanes of the selection's hit accumulator
 NEG = -1e30             # a score no selected key has
 INT_MIN, INT_MAX = -(2 ** 31), 2 ** 31 - 1
 # the prefill's order keys (32 MB at 65,536 positions), its blocks and the
@@ -187,18 +193,47 @@ def _tile_keys(qi, w, ki):
     return order_key(s)
 
 
-def _count(keys, last, t_pos, pred):
+def _count(keys, last, upto, pred, bound, *, every_tile=False):
     """Over key tiles ``0 .. last`` of ``keys`` (tiles, bq, bk), how many
-    positions ``s <= t`` (each row's ``t_pos``) satisfy ``pred(tile,
-    s_pos)``: (bq, 1) int32."""
+    positions ``s <= upto`` (bq, 1) of each row hold an order key ``k``
+    with ``pred(k, bound)`` (``bound`` (bq, 1)): (bq, 1) int32.
+
+    A tile's hits are added as 0 or 1, 128 lanes at a time, into a
+    (bq, 128) accumulator held in registers: a compare and an add an
+    element, and one sum over lanes a count.  Every position of a tile
+    before ``last`` lies at or before every query of the block (a block
+    starts at a multiple of ``bq``, a tile at one of ``bk``, and ``bq``
+    divides ``bk``), so only tile ``last`` is held to ``upto``, unless
+    ``every_tile`` asks for a bound below the block's positions."""
     _, bq, bk = keys.shape
+    w = LANES if bk % LANES == 0 else bk
+    wide = jnp.broadcast_to(bound, (bq, w))
+    lane = lax.broadcasted_iota(jnp.int32, (bq, w), 1)
 
-    def body(jj, c):
-        s_pos = jj * bk + lax.broadcasted_iota(jnp.int32, (1, bk), 1)
-        hit = pred(keys[jj], s_pos) & (s_pos <= t_pos)
-        return c + jnp.sum(hit.astype(jnp.int32), axis=1, keepdims=True)
+    def add(jj, acc, masked):
+        for c in range(0, bk, w):
+            hit = pred(keys[jj, :, pl.ds(c, w)], wide)
+            if masked:
+                hit = hit & (jj * bk + c + lane <= upto)
+            acc = acc + hit.astype(jnp.int32)
+        return acc
 
-    return lax.fori_loop(0, last + 1, body, jnp.zeros((bq, 1), jnp.int32))
+    acc = jnp.zeros((bq, w), jnp.int32)
+    if every_tile:
+        acc = lax.fori_loop(0, last + 1,
+                            lambda jj, a: add(jj, a, True), acc)
+    else:
+        def unrolled(u, a):
+            for v in range(UNROLL):
+                a = add(u * UNROLL + v, a, False)
+            return a
+
+        whole = last // UNROLL
+        acc = lax.fori_loop(0, whole, unrolled, acc)
+        acc = lax.fori_loop(whole * UNROLL, last,
+                            lambda jj, a: add(jj, a, False), acc)
+        acc = add(last, acc, True)
+    return jnp.sum(acc, axis=1, keepdims=True)
 
 
 def _any(flag, rows):
@@ -213,6 +248,7 @@ def _select(keys, thr, cut, flag, t_pos, last, top_k: int):
     from the order keys of tiles ``0 .. last``."""
     bq = t_pos.shape[0]
     everything = t_pos + 1 <= top_k
+    count = functools.partial(_count, keys, last, t_pos)
 
     # bisection: count(key >= lo) >= top_k > count(key >= hi) until hi =
     # lo + 1; a row whose count lands on top_k exactly is done at once
@@ -221,7 +257,7 @@ def _select(keys, thr, cut, flag, t_pos, last, top_k: int):
     def step(carry):
         it, lo, hi, _ = carry
         mid = (lo >> 1) + (hi >> 1) + (lo & hi & 1)
-        c = _count(keys, last, t_pos, lambda tile, s: tile >= mid)
+        c = count(jnp.greater_equal, mid)
         live = hi - 1 > lo
         up = live & (c >= top_k)
         exact = live & (c == top_k)
@@ -238,19 +274,19 @@ def _select(keys, thr, cut, flag, t_pos, last, top_k: int):
 
     # the ties at thr, lowest position first: the m-th of them is the cut
     # (m = 0: none of them; all of them fit: every one)
-    m = top_k - _count(keys, last, t_pos, lambda tile, s: tile > thr[...])
-    ties = _count(keys, last, t_pos, lambda tile, s: tile == thr[...])
+    m = top_k - count(jnp.greater, thr[...])
+    ties = count(jnp.equal, thr[...])
     cut[...] = jnp.where(~everything & (m <= 0), -1, INT_MAX)
     need = ~everything & (m > 0) & (ties > m)
 
     @pl.when(_any(flag, need))
     def _():
+        # the m-th tie lies at or before t: a bound past t changes nothing
         def halve(_, carry):
             lo_p, hi_p = carry
             mid = (lo_p + hi_p) >> 1
-            c = _count(keys, last, t_pos,
-                       lambda tile, s: (tile == thr[...]) & (s <= mid))
-            ok = c >= m
+            ok = _count(keys, last, mid, jnp.equal, thr[...],
+                        every_tile=True) >= m
             return jnp.where(ok, lo_p, mid), jnp.where(ok, mid, hi_p)
 
         span = (last + 1) * keys.shape[2]

@@ -23,6 +23,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 import keye_reference as ref
 from benchmarks.drivers import serve_keye
@@ -269,10 +271,13 @@ def _index_inputs(rng, t, hi=4, di=8):
     return qi, w, ki
 
 
-@pytest.mark.parametrize("t, k", [(64, 8), (256, 40), (384, 150)])
+@pytest.mark.parametrize("t, k", [(64, 8), (256, 40), (384, 150), (100, 30),
+                                  (640, 200), (1000, 64)])
 def test_the_prefill_kernel_selects_as_the_reference(t, k):
-    """Through the interpreter, at one block, at several query blocks of
-    128 over tiles of 512 and of 128: the selection (ties to the lower
+    """Through the interpreter, at one block (of 64, and of 100: not a
+    multiple of the counts' 128 lanes), at several query blocks of 128
+    over tiles of 512 (1,000: the last block partly padding) and of 128
+    (640: rows up to 199 take every key): the selection (ties to the lower
     position) is the reference's row for row; with q = k = 0 each output is
     the mean of the selected values, so a row selected otherwise shows."""
     rng = np.random.default_rng(t)
@@ -289,6 +294,77 @@ def test_the_prefill_kernel_selects_as_the_reference(t, k):
             / mask.sum(1, keepdims=True)).reshape(t, 2, 1, 8)
     got = got[0].reshape(t, 2, 2, 8)
     assert float(jnp.max(jnp.abs(got - want))) < 1e-5
+
+
+def _selection_scores(kind, t, rng):
+    """Scores (t, t) float32 for ``test_the_kernels_selection_...``."""
+    if kind == "straddle":
+        # 100 keys above 1.0, then 40 ties at 1.0 over positions 500-539
+        # (the boundary of the first two tiles of 512), the rest below
+        scores = -1.0 - rng.permutation(t * t).reshape(t, t) / (t * t)
+        scores[:, :100] = 2.0
+        scores[:, 500:540] = 1.0
+        return scores
+    if kind == "zeros":
+        return rng.choice([0.0, -0.0, -0.5, -2.0, 0.25, 3.0], (t, t))
+    if kind == "ties":
+        return rng.choice([0.0, 1.0, 2.0], (t, t), p=[0.2, 0.75, 0.05])
+    return rng.standard_normal((t, t))
+
+
+def _kernel_selection(scores, top_k):
+    """The prefill kernel's selection (``dsa._select``) over ``scores``,
+    query block by query block in the interpreter, as a mask (t, t); and
+    each row's ``cut``."""
+    t = scores.shape[0]
+    bq, bk, tp = dsa._blocks(t)
+    nq, nk = tp // bq, tp // bk
+    keys = dsa.order_key(jnp.pad(scores, ((0, tp - t), (0, tp - t))))
+    tiles = keys.reshape(nq, bq, nk, bk).transpose(0, 2, 1, 3).reshape(
+        nq * nk, bq, bk)
+
+    def kernel(keys_ref, thr_ref, cut_ref, flag):
+        i = pl.program_id(0)
+        t_pos = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
+        dsa._select(keys_ref, thr_ref, cut_ref, flag, t_pos,
+                    (i * bq + bq - 1) // bk, top_k)
+
+    thr, cut = pl.pallas_call(
+        kernel, grid=(nq,),
+        in_specs=[pl.BlockSpec((nk, bq, bk), lambda i: (i, 0, 0))],
+        out_specs=[pl.BlockSpec((bq, 1), lambda i: (i, 0))] * 2,
+        out_shape=[jax.ShapeDtypeStruct((tp, 1), jnp.int32)] * 2,
+        scratch_shapes=[pltpu.SMEM((1,), jnp.int32)],
+        interpret=True)(tiles)
+    s, row = jnp.arange(tp)[None, :], jnp.arange(tp)[:, None]
+    mask = ((keys > thr) | ((keys == thr) & (s <= cut))) & (s <= row)
+    return mask[:t, :t], cut[:t, 0]
+
+
+@pytest.mark.parametrize("kind, t, k", [
+    ("straddle", 1024, 120),    # ties at the threshold across two tiles
+    ("zeros", 640, 40),         # +0 and -0 as one value, negatives
+    ("ties", 384, 200),         # tie-halving; rows t + 1 <= top_k
+    ("normal", 1000, 64),       # 512-key tiles, last block partly padding
+    ("normal", 100, 30),        # one block, not a multiple of 128 lanes
+    ("ties", 72, 16),           # tie-halving in one block
+])
+def test_the_kernels_selection_is_the_references_row_for_row(kind, t, k):
+    """``dsa._select`` alone, on scores chosen to reach each of its paths:
+    the mask its ``thr`` and ``cut`` make is ``ref.select``'s in every row;
+    where ties at the threshold outnumber what the top_k still needs, the
+    tie-halving loop ran (a cut other than -1 and INT_MAX)."""
+    rng = np.random.default_rng(t + k)
+    scores = jnp.asarray(_selection_scores(kind, t, rng), jnp.float32)
+    got, cut = _kernel_selection(scores, k)
+    want = ref.select(scores, jnp.arange(t), k)
+    assert bool(jnp.all(got == want))
+    assert got.sum(1).tolist() == jnp.minimum(jnp.arange(t) + 1, k).tolist()
+    halved = (cut != -1) & (cut != dsa.INT_MAX)
+    if kind in ("straddle", "ties"):
+        assert bool(jnp.any(halved))
+    if kind == "straddle":      # 20 of the 40 ties: the 20th is at 519
+        assert set(np.asarray(cut[540:]).tolist()) == {519}
 
 
 def test_the_prefill_kernel_is_its_plain_form_on_random_inputs():

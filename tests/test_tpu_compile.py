@@ -612,7 +612,8 @@ def test_the_65536_bucket_fits_beside_weights_and_table(one_chip,
     block's order keys (32 MB) in VMEM and the experts take 4,096 tokens at
     a time, so the bucket's temporaries stay under 4 GB and the program,
     weights and table together under the chip's 16 GiB; each layer calls
-    ``dsa_prefill`` once, on the bucket's length."""
+    ``dsa_prefill`` once, on the bucket's length, in less fast memory than
+    the kernel asks for."""
     kv, params, cache = _keye_cell(one_chip, monkeypatch)
     lpad = 65536
     kv._prefill(lpad)
@@ -627,9 +628,18 @@ def test_the_65536_bucket_fits_beside_weights_and_table(one_chip,
     pattern = json.loads((Path(__file__).parent.parent / "benchmarks"
                           / "metrics" / "kernel.dsa_prefill_roofline.json")
                          .read_text())["pattern"]
-    calls = [re.search(pattern, line.strip()) for line in
-             compiled.as_text().splitlines()
+    lines = [line.strip() for line in compiled.as_text().splitlines()
              if 'custom_call_target="tpu_custom_call"' in line
              and "dsa_prefill" in line]
+    calls = [re.search(pattern, line) for line in lines]
     assert len(calls) == 4 and all(
         c is not None and int(c["length"]) == lpad for c in calls)
+    # the fast memory each call holds (the order keys' 32 MB among it;
+    # the selection's counts keep their hits in registers) stays under
+    # the limit the kernel asks for
+    from distributed_tensorflow_tpu.ops import sparse_attention
+    vmem = [int(m) for line in lines for m in re.findall(
+        r'"used_scoped_memory_configs":\[\{"memory_space":"1",'
+        r'"offset":"0","size":"(\d+)"', line)]
+    assert len(vmem) == 4 and all(
+        32 * 2 ** 20 < v < sparse_attention.VMEM_LIMIT for v in vmem), vmem
