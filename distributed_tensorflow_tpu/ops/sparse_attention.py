@@ -36,7 +36,15 @@ Three kernels, each a ``pallas_call`` with a ``name`` the device trace finds:
   is a flash attention over the K/V tiles whose mask is the selection.  A
   tile past the block's last query fetches nothing (its block index is
   held) and computes nothing, and a tile in which no query of the block
-  selects a key is skipped.
+  selects a key is skipped.  Phase 1 holds its tiles keys down and queries
+  across, as the training flash kernels do: at the first step of phase 1
+  each K/V head's whole group of queries is laid across the lanes,
+  ``(D, group x Q_BLOCK)`` head by head, so a K/V tile is multiplied once
+  a group (``STRIP`` lanes a piece), the running max and sum of a query
+  are lane rows that reduce down sublanes, and the selection's mask is
+  turned to ``(K_BLOCK, Q_BLOCK)`` once a tile and serves every head.  At
+  the block's last tile each head's ``(D, Q_BLOCK)`` is turned back into
+  the output's ``(heads, positions, D)``.
 * ``dsa_step_index`` (the one-token step's scoring).  The grid is ``(slots,
   index tiles)``; a slot's tiles past its own position fetch and compute
   nothing, so a round reads each slot's index rows to its length and not to
@@ -57,6 +65,7 @@ test asks for them (``interpret=True``)."""
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -64,13 +73,15 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from distributed_tensorflow_tpu.ops.flash_attention import resolve_interpret
+from distributed_tensorflow_tpu.ops.flash_attention import (
+    _TN, resolve_interpret)
 
 Q_BLOCK = 128           # prefill queries a grid step
 K_BLOCK = 512           # prefill keys a grid step
 STEP_BLOCK = 2048       # index rows a grid step of the step's scoring
 UNROLL = 4              # key tiles a step of the selection's counting loop
 LANES = 128             # lanes of the selection's hit accumulator
+STRIP = 256             # lanes of a group's queries a piece of phase 1 takes
 NEG = -1e30             # a score no selected key has
 INT_MIN, INT_MAX = -(2 ** 31), 2 ** 31 - 1
 # the prefill's order keys (32 MB at 65,536 positions), its blocks and the
@@ -298,12 +309,13 @@ def _select(keys, thr, cut, flag, t_pos, last, top_k: int):
 
 
 def _prefill_kernel(w_ref, qi_ref, ki_ref, q_ref, k_ref, v_ref, o_ref,
-                    keys, thr, cut, flag, m_scr, l_scr, acc_scr, *,
-                    bq: int, bk: int, top_k: int, scale: float, group: int):
+                    keys, thr, cut, flag, qt_scr, m_scr, l_scr, acc_scr, *,
+                    bq: int, bk: int, top_k: int, scale: float, strip: int):
     i, phase, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     last = (i * bq + bq - 1) // bk
     t_pos = i * bq + lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
-    hq = q_ref.shape[0]
+    hq, hk = q_ref.shape[0], k_ref.shape[0]
+    group = hq // hk
 
     @pl.when((phase == 0) & (j <= last))
     def _():
@@ -312,6 +324,11 @@ def _prefill_kernel(w_ref, qi_ref, ki_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when((phase == 1) & (j == 0))
     def _():
         _select(keys, thr, cut, flag, t_pos, last, top_k)
+        # each K/V head's group of queries across the lanes, head-major:
+        # (d, group * bq), laid once a block
+        for h in range(hq):
+            g, c = divmod(h, group)
+            qt_scr[g, :, pl.ds(c * bq, bq)] = q_ref[h].T
         m_scr[...] = jnp.full_like(m_scr, NEG)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
@@ -326,26 +343,37 @@ def _prefill_kernel(w_ref, qi_ref, ki_ref, q_ref, k_ref, v_ref, o_ref,
         @pl.when(_any(flag, jnp.max(sel.astype(jnp.int32), axis=1,
                                     keepdims=True) > 0))
         def _():
-            for h in range(hq):
-                g = h // group
-                s = lax.dot_general(
-                    q_ref[h], k_ref[g], (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32) * scale
-                s = jnp.where(sel, s, NEG)
-                m_prev = m_scr[h]
-                m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-                p = jnp.exp(s - m_new)
-                fade = jnp.exp(m_prev - m_new)
-                m_scr[h] = m_new
-                l_scr[h] = l_scr[h] * fade + jnp.sum(p, axis=1, keepdims=True)
-                acc_scr[h] = acc_scr[h] * fade + jnp.dot(
-                    p.astype(v_ref.dtype), v_ref[g],
-                    preferred_element_type=jnp.float32)
+            # keys down, queries across: one head's mask, for every head,
+            # as a bias (a score plus NEG is NEG: no score reaches its
+            # last bit)
+            bias = jnp.where(sel, 0.0, NEG).T
+            bias = jnp.concatenate([bias] * (strip // bq), axis=1)
+            for g in range(hk):
+                k, v = k_ref[g], v_ref[g]
+                for c in range(0, group * bq, strip):
+                    cols = pl.ds(c, strip)
+                    s = jnp.dot(k, qt_scr[g, :, cols],
+                                preferred_element_type=jnp.float32)
+                    s = s * scale + bias
+                    m_prev = m_scr[g, :, cols]
+                    m_new = jnp.maximum(m_prev,
+                                        jnp.max(s, axis=0, keepdims=True))
+                    p = jnp.exp(s - m_new)
+                    fade = jnp.exp(m_prev - m_new)
+                    m_scr[g, :, cols] = m_new
+                    l_scr[g, :, cols] = l_scr[g, :, cols] * fade + jnp.sum(
+                        p, axis=0, keepdims=True)
+                    acc_scr[g, :, cols] = acc_scr[g, :, cols] * fade + (
+                        lax.dot_general(v, p.astype(v.dtype), _TN,
+                                        preferred_element_type=jnp.float32))
 
     @pl.when((phase == 1) & (j == last))
     def _():
         for h in range(hq):
-            o_ref[h] = (acc_scr[h] / l_scr[h]).astype(o_ref.dtype)
+            g, c = divmod(h, group)
+            cols = pl.ds(c * bq, bq)
+            o_ref[h] = (acc_scr[g, :, cols] / l_scr[g, :, cols]).T.astype(
+                o_ref.dtype)
 
 
 def _blocks(t: int) -> tuple[int, int, int]:
@@ -378,9 +406,12 @@ def _prefill_call(q, k, v, qi, w, ki, *, top_k: int, scale: float,
     def last_tile(i):
         return (i * bq + bq - 1) // bk
 
+    group = hq // hk
+    # a piece of phase 1: as many whole heads of a group as STRIP holds
+    strip = bq * math.gcd(group, max(1, STRIP // bq))
     o = pl.pallas_call(
         functools.partial(_prefill_kernel, bq=bq, bk=bk, top_k=top_k,
-                          scale=scale, group=hq // hk),
+                          scale=scale, strip=strip),
         name="dsa_prefill",
         grid=(nq, 2, nk),
         in_specs=[
@@ -401,9 +432,10 @@ def _prefill_call(q, k, v, qi, w, ki, *, top_k: int, scale: float,
             pltpu.VMEM((bq, 1), jnp.int32),
             pltpu.VMEM((bq, 1), jnp.int32),
             pltpu.SMEM((1,), jnp.int32),
-            pltpu.VMEM((hq, bq, 1), jnp.float32),
-            pltpu.VMEM((hq, bq, 1), jnp.float32),
-            pltpu.VMEM((hq, bq, d), jnp.float32),
+            pltpu.VMEM((hk, d, group * bq), q.dtype),
+            pltpu.VMEM((hk, 1, group * bq), jnp.float32),
+            pltpu.VMEM((hk, 1, group * bq), jnp.float32),
+            pltpu.VMEM((hk, d, group * bq), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),

@@ -367,12 +367,16 @@ def test_the_kernels_selection_is_the_references_row_for_row(kind, t, k):
         assert set(np.asarray(cut[540:]).tolist()) == {519}
 
 
-def test_the_prefill_kernel_is_its_plain_form_on_random_inputs():
+@pytest.mark.parametrize("t, hq, hk", [(256, 4, 2), (256, 8, 1), (640, 8, 1),
+                                      (1000, 16, 2), (100, 4, 4)])
+def test_the_prefill_kernel_is_its_plain_form_on_random_inputs(t, hq, hk):
+    """Phase 1 attends a K/V head's whole group of queries per key tile:
+    groups of 2, 8 and 1, key tiles of 512 and of 128 (640), a last block
+    partly padding (1,000) and one block of 100 positions."""
     rng = np.random.default_rng(9)
-    t = 256
-    q = jnp.asarray(rng.standard_normal((1, t, 4, 8)), jnp.float32)
-    k = jnp.asarray(rng.standard_normal((1, t, 2, 8)), jnp.float32)
-    v = jnp.asarray(rng.standard_normal((1, t, 2, 8)), jnp.float32)
+    q = jnp.asarray(rng.standard_normal((1, t, hq, 8)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((1, t, hk, 8)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((1, t, hk, 8)), jnp.float32)
     qi, w, ki = _index_inputs(rng, t)
     got = dsa.dsa_prefill(q, k, v, qi, w, ki, top_k=24, scale=0.3,
                           interpret=True)
