@@ -245,36 +245,6 @@ def build_parser() -> argparse.ArgumentParser:
                    metavar="T",
                    help="--serve: tokens per prefix-cache block (reuse "
                         "granularity; only full blocks are pooled)")
-    p.add_argument("--serve-kv-layout", default="monolithic",
-                   choices=["monolithic", "paged"],
-                   help="--serve: KV storage layout.  'paged' swaps the "
-                        "per-slot max_len rows for ONE refcounted "
-                        "physical block pool + per-slot block tables "
-                        "(vLLM PagedAttention): prefix-cache hits alias "
-                        "pooled blocks by pointer (zero KV bytes "
-                        "copied), first write into a shared block "
-                        "copies on write, and decode/verify read "
-                        "through the table in one fused Pallas kernel "
-                        "(in-kernel int8 dequant; token parity vs the "
-                        "monolithic oracle is tolerance-based — the "
-                        "attention-reassociation caveat, like int8).  "
-                        "Default 'monolithic' keeps the pre-round-16 "
-                        "programs byte-identical")
-    p.add_argument("--serve-paged-block", type=int, default=0,
-                   metavar="T",
-                   help="--serve-kv-layout paged: tokens per physical "
-                        "KV block.  0 (default) inherits --serve-prefix-"
-                        "block; with the prefix pool on the two must "
-                        "agree (hits alias physical blocks by pointer)")
-    p.add_argument("--serve-paged-blocks", type=int, default=0,
-                   metavar="N",
-                   help="--serve-kv-layout paged: physical block-pool "
-                        "capacity.  0 (default) auto-sizes so every "
-                        "slot can reach max_len and the prefix pool can "
-                        "pin its bound — never exhausts; smaller "
-                        "explicit pools defer admissions "
-                        "(serve_kv_block_deferrals) when the free list "
-                        "cannot cover a request's worst-case need")
     p.add_argument("--serve-shared-prefix", type=int, default=0,
                    metavar="T",
                    help="--serve: prepend a fixed T-token synthetic "
@@ -359,8 +329,7 @@ def build_parser() -> argparse.ArgumentParser:
                         "P prefill replicas (admission + chunked "
                         "prefill only) hand finished KV to D decode "
                         "replicas via serialized-block transfer "
-                        "(extract_handoff/restore_handoff; works for "
-                        "monolithic and paged layouts, int8 scales "
+                        "(extract_handoff/restore_handoff; int8 scales "
                         "ride along), so decode replicas never share "
                         "an iteration with a long prompt.  Overrides "
                         "--serve-replicas with P+D; the prefix pool "
@@ -770,9 +739,6 @@ def main(argv: list[str] | None = None, *, model_fn=None,
         serve_fault_spec=args.serve_fault_spec,
         serve_hot_swap=args.serve_hot_swap,
         serve_watchdog_s=args.serve_watchdog,
-        serve_kv_layout=args.serve_kv_layout,
-        serve_paged_block=args.serve_paged_block,
-        serve_paged_blocks=args.serve_paged_blocks,
         serve_disaggregate=args.serve_disaggregate,
         serve_routing=args.serve_routing,
         serve_autoscale=args.serve_autoscale,

@@ -45,6 +45,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from distributed_tensorflow_tpu.models.slot_contract import SlotContract
 from distributed_tensorflow_tpu.ops.slot_attention import (
     prefix_rows, slot_decode_attention, takes_kernel)
 from distributed_tensorflow_tpu.parallel import collectives as coll
@@ -155,31 +156,14 @@ class CausalSelfAttention(nn.Module):
                                # head; parallel/compression.py channel
                                # quantizer), dequantized on the attention
                                # read — the stored table is what shrinks
-    paged_blocks: int = 0      # >0: paged KV layout (decode_slots only).
-                               # The cache becomes ONE physical pool of
-                               # this many (kvh, paged_block, head_dim)
-                               # blocks shared by every slot; the caller
-                               # passes per-slot int32 block tables and
-                               # owns allocation/aliasing/CoW
-                               # (serving/kv_cache.py PagedSlotKVCache)
-    paged_block: int = 16      # tokens per physical block (must divide
-                               # max_len)
-    paged_fused: bool = False  # read the pool through the fused Pallas
-                               # kernel (ops/paged_attention.py) instead
-                               # of gather + dense — the gather path is
-                               # bitwise the monolithic math (prefill /
-                               # oracle); the fused path is the decode
-                               # hot op (tolerance parity)
-    paged_mesh: Any = None     # the serving mesh when the slot table
-                               # shards over its 'data' axis: the fused
-                               # paged kernel then runs under shard_map
-                               # and the monolithic one-token step takes
-                               # its plain form (Mosaic kernels cannot be
-                               # GSPMD-partitioned)
+    slot_mesh: Any = None      # the serving mesh when the slot table
+                               # shards over its 'data' axis: the one-
+                               # token step then takes its plain form
+                               # (GSPMD partitions the program, and a
+                               # Mosaic kernel cannot be partitioned)
 
     @nn.compact
-    def __call__(self, x, pos=None, block_tables=None,
-                 prefill: bool = False, active=None):
+    def __call__(self, x, pos=None, prefill: bool = False, active=None):
         head_dim = self.hidden // self.heads
         tp = self.partition_model
         if self.rope and pos is None:
@@ -306,27 +290,6 @@ class CausalSelfAttention(nn.Module):
                         "decode_slots=True needs per-slot positions "
                         "(B, 1) — the serving engine passes the slot "
                         "length vector")
-                if self.paged_blocks:
-                    # PAGED layout (vLLM PagedAttention): the cache
-                    # variables are ONE pool of physical blocks shared by
-                    # all slots + nothing per-slot on device — each row's
-                    # writes scatter through its caller-supplied block
-                    # table, and reads either gather the table back
-                    # (bitwise the monolithic math — the prefill/oracle
-                    # path) or run the fused Pallas kernel that follows
-                    # the table in-kernel (the decode/verify hot op).
-                    # Aliasing is invisible here by design: two tables
-                    # pointing at one block read identical KV, which is
-                    # exactly the zero-copy prefix share.
-                    out = self._paged_attend(x, q, k, v, pos, block_tables,
-                                             widen, kvh, head_dim)
-                    out = out.reshape(out.shape[:-2]
-                                      + (self.heads * head_dim,))
-                    return nn.Dense(
-                        self.hidden, dtype=self.dtype, name="out",
-                        kernel_init=_part(
-                            nn.initializers.lecun_normal(),
-                            (meshlib.MODEL_AXIS, None), tp))(out)
                 ready = self.has_variable("cache", "cached_key")
                 store = jnp.int8 if self.kv_quant else self.dtype
                 ck = self.variable(
@@ -380,7 +343,7 @@ class CausalSelfAttention(nn.Module):
                     ck.value, cv.value, out = slot_decode_attention(
                         q, k, v, ck.value, cv.value, pos, active,
                         widen=widen, plain=(self.partition_model
-                                            or self.paged_mesh is not None))
+                                            or self.slot_mesh is not None))
                 else:
                     # token-block write (speculative verify) and/or int8
                     # storage: write every position's K/V (+ scale) with
@@ -478,98 +441,6 @@ class CausalSelfAttention(nn.Module):
             kernel_init=_part(nn.initializers.lecun_normal(),
                               (meshlib.MODEL_AXIS, None), tp))(out)
 
-    def _paged_attend(self, x, q, k, v, pos, block_tables, widen,
-                      kvh, head_dim):
-        """Paged KV write + read (decode_slots + paged_blocks > 0).
-
-        Cache variables are the shared physical pools; per-slot state is
-        the caller's block table.  Writes scatter each (row, position)
-        K/V vector into ``pool[bt[row, pos // blk], :, pos % blk]``; reads
-        go fused (Pallas kernel) or unfused (gather + dense — bitwise
-        the monolithic token-block branch's math over the gathered
-        table, which is what keeps the paged chunk scan exactly equal to
-        the monolithic one)."""
-        blk = self.paged_block
-        if self.max_len % blk:
-            raise ValueError(
-                f"paged_block={blk} must divide max_len={self.max_len}")
-        ready = self.has_variable("cache", "key_pool")
-        store = jnp.int8 if self.kv_quant else self.dtype
-        kp = self.variable(
-            "cache", "key_pool", jnp.zeros,
-            (self.paged_blocks, kvh, blk, head_dim), store)
-        vp = self.variable(
-            "cache", "value_pool", jnp.zeros,
-            (self.paged_blocks, kvh, blk, head_dim), store)
-        if self.kv_quant:
-            ksp = self.variable(
-                "cache", "key_scale_pool", jnp.zeros,
-                (self.paged_blocks, kvh, blk), jnp.float32)
-            vsp = self.variable(
-                "cache", "value_scale_pool", jnp.zeros,
-                (self.paged_blocks, kvh, blk), jnp.float32)
-        if not ready:
-            # .init(): create the pools, write nothing (the same
-            # init-time guard as the monolithic cache)
-            return dense_attention(q, widen(k), widen(v), causal=True)
-        if block_tables is None:
-            raise ValueError(
-                "paged decode needs block_tables (B, max_blocks) — the "
-                "serving engine passes each slot's block table")
-        idx = pos                                    # (B, L)
-        # positions past max_len (pad rows of a chunk-scan bucket) must
-        # DROP like the monolithic write does (write_slot_rows' drop
-        # rule) — but gather CLAMPS, so an unclamped table lookup would
-        # alias the slot's own last block.  Route oob positions to an
-        # oob OFFSET instead: the block-id gather is clamped harmlessly
-        # and the scatter's default drop rule discards the write.
-        j = idx // blk
-        oob = j >= block_tables.shape[1]
-        blk_ids = jnp.take_along_axis(
-            block_tables, jnp.minimum(j, block_tables.shape[1] - 1), axis=1)
-        off = jnp.where(oob, blk, idx % blk)
-        # the pool keeps the kv head ahead of the token axis (the Pallas
-        # kernel's window is then the pool's full last two dims); the two
-        # advanced indices around the head slice index (B, L) first, so
-        # the update keeps k/v's own (B, L, kvh[, D]) shape
-        if self.kv_quant:
-            qk, sk = compression.int8_channel_encode(k)
-            qv, sv = compression.int8_channel_encode(v)
-            kp.value = kp.value.at[blk_ids, :, off].set(qk)
-            vp.value = vp.value.at[blk_ids, :, off].set(qv)
-            ksp.value = ksp.value.at[blk_ids, :, off].set(sk)
-            vsp.value = vsp.value.at[blk_ids, :, off].set(sv)
-        else:
-            kp.value = kp.value.at[blk_ids, :, off].set(
-                k.astype(kp.value.dtype))
-            vp.value = vp.value.at[blk_ids, :, off].set(
-                v.astype(vp.value.dtype))
-        from distributed_tensorflow_tpu.ops.paged_attention import (
-            gather_pool, paged_attention)
-        if self.paged_fused:
-            return paged_attention(
-                q, kp.value, vp.value, block_tables, idx[:, 0],
-                k_scale=ksp.value if self.kv_quant else None,
-                v_scale=vsp.value if self.kv_quant else None,
-                mesh=self.paged_mesh,
-            ).astype(self.dtype)
-        # unfused: gather the logical table back through the block table
-        # and run the SAME masked dense attention as the monolithic
-        # token-block branch — garbage rows from unmapped entries sit
-        # past the validity mask
-        t = self.max_len
-        keys = gather_pool(kp.value, block_tables)
-        vals = gather_pool(vp.value, block_tables)
-        if self.kv_quant:
-            keys = compression.int8_channel_decode(
-                keys, gather_pool(ksp.value, block_tables), self.dtype)
-            vals = compression.int8_channel_decode(
-                vals, gather_pool(vsp.value, block_tables), self.dtype)
-        valid = (jnp.arange(t)[None, None, :]
-                 <= idx[:, :, None]).astype(self.dtype)
-        return dense_attention(q, widen(keys), widen(vals),
-                               causal=False, kv_mask=valid)
-
 
 class GPTBlock(nn.Module):
     """Pre-LN decoder block: x + attn(LN(x)); x + ffn(LN(x)).
@@ -600,13 +471,10 @@ class GPTBlock(nn.Module):
     partition_experts: bool = False
     decode_slots: bool = False   # serving slot-table decode (see attention)
     kv_quant: bool = False       # int8 KV storage (see attention)
-    paged_blocks: int = 0        # paged KV pool size (see attention)
-    paged_block: int = 16        # tokens per physical block
-    paged_fused: bool = False    # fused Pallas paged read (see attention)
-    paged_mesh: Any = None       # serving mesh for the fused read
+    slot_mesh: Any = None        # the serving mesh (see attention)
 
     @nn.compact
-    def __call__(self, x, train: bool = False, pos=None, block_tables=None,
+    def __call__(self, x, train: bool = False, pos=None,
                  prefill: bool = False, active=None):
         tp = self.partition_model
         y = CausalSelfAttention(self.hidden, self.heads, self.attention_impl,
@@ -614,12 +482,9 @@ class GPTBlock(nn.Module):
                                 self.rope, self.kv_heads, self.dtype,
                                 decode_slots=self.decode_slots,
                                 kv_quant=self.kv_quant,
-                                paged_blocks=self.paged_blocks,
-                                paged_block=self.paged_block,
-                                paged_fused=self.paged_fused,
-                                paged_mesh=self.paged_mesh)(
+                                slot_mesh=self.slot_mesh)(
                                     nn.LayerNorm(dtype=self.dtype)(x), pos,
-                                    block_tables, prefill, active)
+                                    prefill, active)
         y = nn.Dropout(self.dropout_rate, deterministic=not train)(y)
         x = x + y
         y = nn.LayerNorm(dtype=self.dtype)(x)
@@ -703,47 +568,48 @@ class GPTLM(nn.Module):
                                  # scales (decode_slots only; --serve-kv-
                                  # dtype int8 — the stored table is ~¼ of
                                  # f32, ~½ of bf16)
-    paged_blocks: int = 0        # >0: paged KV layout (decode_slots only;
-                                 # --serve-kv-layout paged) — one shared
-                                 # physical block pool + caller-owned
-                                 # per-slot block tables instead of
-                                 # (slots, max_len) rows
-    paged_block: int = 16        # tokens per physical block (divides
-                                 # max_len)
-    paged_fused: bool = False    # fused Pallas paged-attention read
-                                 # (ops/paged_attention.py)
-    paged_mesh: Any = None       # serving mesh for the fused read (its
-                                 # 'data' axis shards the slots)
+    slot_mesh: Any = None        # the serving mesh (its 'data' axis
+                                 # shards the slots; see attention)
 
     causal_lm = True  # read by engines/harness to select the LM data layout
-    resumable_step = True   # the slot-decode step takes any start position
-                            # over per-head K/V rows: what SlotKVCache's
-                            # chunk, pool, paged, int8, multi-step, verify
-                            # and handoff programs are built from
 
-    def slot_decode_clone(self, *, partition_model: bool = False,
-                          kv_quant: bool = False, **paged) -> "GPTLM":
+    def slot_decode_clone(self, *, mesh=None,
+                          kv_quant: bool = False) -> "GPTLM":
         """The module ``serving/kv_cache.SlotKVCache`` serves from: dense
-        cache attention over a slot table, dropout off."""
+        cache attention over a slot table, dropout off, the Megatron
+        layout kept where the model was partitioned and ``mesh`` has a
+        'model' axis."""
+        tp = (self.partition_model and mesh is not None
+              and meshlib.MODEL_AXIS in mesh.axis_names)
         return self.clone(decode=True, decode_slots=True,
-                          attention_impl="dense",
-                          partition_model=partition_model, dropout_rate=0.0,
-                          kv_quant=kv_quant, **paged)
+                          attention_impl="dense", partition_model=tp,
+                          dropout_rate=0.0, kv_quant=kv_quant,
+                          slot_mesh=mesh)
 
-    def slot_rows_read(self, lengths, advancing) -> int:
-        """How many positions of every slot's rows the one-token step
-        reads in a round that advances ``advancing`` at ``lengths`` (host
-        arrays): the prefix of ops/slot_attention.py's rule where the step
-        runs the kernel, the whole table where it runs the plain form
-        (``SlotKVCache`` puts it on the ``decode_step`` span as
-        ``rows_read``)."""
-        plain = (self.kv_quant or self.paged_blocks or self.partition_model
-                 or self.paged_mesh is not None)
+    def slot_contract(self) -> SlotContract:
+        """The slot step takes ``active`` (it reads the prefix the active
+        slots need) and resumes from any position over per-head K/V rows,
+        which SlotKVCache's chunk, pool, multi-step, verify and handoff
+        programs are built from."""
+        return SlotContract(
+            takes_active=True, resumable_step=True,
+            step_param_dtype=self.step_param_dtype,
+            tensor_parallel=self.partition_model, int8_rows=True,
+            round_attrs=self.slot_round_attrs)
+
+    def slot_round_attrs(self, lengths, advancing) -> dict[str, int]:
+        """``rows_read``: how many positions of every slot's rows the
+        one-token step reads in a round that advances ``advancing`` at
+        ``lengths`` (host arrays): the prefix of ops/slot_attention.py's
+        rule where the step runs the kernel, the whole table where it runs
+        the plain form."""
+        plain = (self.kv_quant or self.partition_model
+                 or self.slot_mesh is not None)
         if not takes_kernel(self.heads, self.kv_heads or self.heads,
                             self.hidden // self.heads, self.max_len,
                             plain=plain):
-            return self.max_len
-        return prefix_rows(lengths, advancing, self.max_len)
+            return {"rows_read": self.max_len}
+        return {"rows_read": prefix_rows(lengths, advancing, self.max_len)}
 
     def step_param_dtype(self, path: tuple[str, ...]):
         """The dtype in which a call first uses the parameter at ``path``
@@ -769,7 +635,7 @@ class GPTLM(nn.Module):
 
     @nn.compact
     def __call__(self, token_ids, train: bool = False, positions=None,
-                 block_tables=None, prompt_len=None, active=None):
+                 prompt_len=None, active=None):
         seq_parallel = self.attention_impl in ("ring", "ring_flash",
                                                "ulysses", "ulysses_flash")
         lq = token_ids.shape[1]
@@ -778,10 +644,10 @@ class GPTLM(nn.Module):
         # tokens are real — the attention layers' block-prefill contract,
         # and logits for the last real position alone
         prefill = prompt_len is not None
-        if prefill and (not self.decode_slots or self.paged_blocks):
+        if prefill and not self.decode_slots:
             raise ValueError(
                 "prompt_len marks a slot prefill from position 0: it "
-                "requires decode_slots=True and the monolithic table")
+                "requires decode_slots=True")
         if self.decode_slots and not self.decode:
             raise ValueError("decode_slots=True requires decode=True "
                              "(slot serving is a KV-cache decode mode)")
@@ -789,15 +655,6 @@ class GPTLM(nn.Module):
             raise ValueError(
                 "positions is only accepted in decode_slots mode — every "
                 "other mode derives positions internally (cursor/offset)")
-        if self.paged_blocks and not self.decode_slots:
-            raise ValueError(
-                "paged_blocks > 0 is a serving storage layout: it "
-                "requires decode_slots=True (the serving engine owns the "
-                "block tables)")
-        if block_tables is not None and not self.paged_blocks:
-            raise ValueError(
-                "block_tables is only accepted in paged decode_slots "
-                "mode (paged_blocks > 0)")
         if self.decode:
             if seq_parallel:
                 # the hard constraint: ring/ulysses run inside shard_map
@@ -868,7 +725,7 @@ class GPTLM(nn.Module):
             x = x + nn.Embed(self.max_len, self.hidden, dtype=self.dtype,
                              name="pos_embed")(pos)
         x = nn.Dropout(self.dropout_rate, deterministic=not train)(x)
-        # remat: train (arg 2) and prefill (arg 5) are static python bools;
+        # remat: train (arg 2) and prefill (arg 4) are static python bools;
         # x and pos trace.
         # The wrapped class is instantiated with an explicit name pinned to
         # the unwrapped auto-name ("GPTBlock_{i}") — nn.remat renames the
@@ -883,7 +740,7 @@ class GPTLM(nn.Module):
                 "intermediates (aux_loss/z_loss/overflow) would be re-sown "
                 "during backward recompute, double-counting the balance "
                 "losses; train MoE blocks without --remat")
-        block_cls = (nn.remat(GPTBlock, static_argnums=(2, 5))
+        block_cls = (nn.remat(GPTBlock, static_argnums=(2, 4))
                      if self.remat else GPTBlock)
         for i in range(self.layers):
             # slot decode threads pos regardless of rope: the attention
@@ -896,14 +753,11 @@ class GPTLM(nn.Module):
                           self.moe_capacity_factor, self.partition_experts,
                           decode_slots=self.decode_slots,
                           kv_quant=self.kv_quant,
-                          paged_blocks=self.paged_blocks,
-                          paged_block=self.paged_block,
-                          paged_fused=self.paged_fused,
-                          paged_mesh=self.paged_mesh,
+                          slot_mesh=self.slot_mesh,
                           name=f"GPTBlock_{i}")(
                               x, train,
                               pos if (rope or self.decode_slots) else None,
-                              block_tables, prefill, active)
+                              prefill, active)
         if prefill:     # the one position whose logits sample a token
             x = jnp.take_along_axis(
                 x, (prompt_len - 1)[:, None, None].astype(jnp.int32), axis=1)

@@ -40,10 +40,10 @@ serving/kv_cache.py):
 * per-position rows ``(slots, max_len, kv_heads, head_dim)``: the
   attention layer's keys and values, written through ``write_slot_rows``,
   valid up to the caller's position;
-* per-slot state, named in ``slot_state``: ``ssm_state`` ``(slots, H, P,
-  N)`` float32 (an error in ``S`` is carried through every later token)
-  and ``conv_tail`` ``(slots, taps - 1, d_i + 2 G N)``, the pre-activation
-  ``xBC`` rows of the slot's last tokens.
+* per-slot state, named in the slot contract's ``slot_state``:
+  ``ssm_state`` ``(slots, H, P, N)`` float32 (an error in ``S`` is carried
+  through every later token) and ``conv_tail`` ``(slots, taps - 1, d_i +
+  2 G N)``, the pre-activation ``xBC`` rows of the slot's last tokens.
 
 A call with ``prompt_len`` is a PREFILL from position 0 and from a ZERO
 state: the chunked scan over the whole padded block with pads inert, so
@@ -73,6 +73,7 @@ from distributed_tensorflow_tpu.models.gpt import write_slot_rows
 from distributed_tensorflow_tpu.models.mla_moe import (
     RMSNorm, causal_attention_blocked)
 from distributed_tensorflow_tpu.models.moe import DroplessMoE
+from distributed_tensorflow_tpu.models.slot_contract import SlotContract
 from distributed_tensorflow_tpu.models.window_moe import (
     window_attention_blocked)
 
@@ -403,25 +404,27 @@ class HybridSSMLM(nn.Module):
                                  # caller's
 
     causal_lm = True
-    resumable_step = False       # a prompt cannot resume from a position:
-                                 # the state has no positions to resume at
-    # the per-slot state leaves of the ``cache`` collection, and whether
-    # the table may narrow them to its ``kv_dtype``
-    slot_state = {"ssm_state": False, "conv_tail": True}
 
-    @property
-    def expert_layers(self) -> int:
-        return self.pattern.count("E")
-
-    def slot_decode_clone(self, *, partition_model: bool = False,
+    def slot_decode_clone(self, *, mesh=None,
                           kv_quant: bool = False) -> "HybridSSMLM":
-        """The module ``SlotKVCache`` serves from."""
-        for on, what in ((partition_model, "a tensor-parallel slot table"),
-                         (kv_quant, "int8 storage of the table")):
-            if on:
-                raise NotImplementedError(
-                    f"{type(self).__name__} does not support {what}")
+        """The module ``SlotKVCache`` serves from: the same under any
+        ``mesh`` (replicated over a 'model' axis), and with float rows
+        (its contract declares no int8 rows, so the table refuses
+        ``kv_quant``)."""
         return self.clone(decode=True, decode_slots=True)
+
+    def slot_contract(self) -> SlotContract:
+        """Per-slot state beside the rows (the recurrent state is never
+        narrowed to the table's ``kv_dtype``, the convolution tail may
+        be); a prompt cannot resume from a position: the state has no
+        positions to resume at."""
+        return SlotContract(
+            takes_active=True,
+            slot_state={"ssm_state": False, "conv_tail": True},
+            expert_layers=self.pattern.count("E"),
+            num_experts=self.num_experts,
+            experts_per_token=self.experts_per_token,
+            experts_held=self.experts_held)
 
     @nn.compact
     def __call__(self, token_ids, train: bool = False, positions=None,

@@ -47,9 +47,10 @@ order comes from the state-space layers), here at ONE key/value head.
 
 Slot-decode mode keeps the two kinds of leaf of serving/kv_cache.py's
 contract: the attention layers' rows ``(slots, max_len, kv_heads,
-head_dim)``, and per-slot state named in ``slot_state``: ``ssm_state``
-``(slots, d_i, N)`` float32 and ``conv_tail`` ``(slots, taps - 1, d_i)``,
-the pre-activation ``u`` rows of the slot's last tokens.  A call with
+head_dim)``, and per-slot state named in the slot contract's
+``slot_state``: ``ssm_state`` ``(slots, d_i, N)`` float32 and
+``conv_tail`` ``(slots, taps - 1, d_i)``, the pre-activation ``u`` rows of
+the slot's last tokens.  A call with
 ``prompt_len`` is a PREFILL from position 0 and a ZERO state over the whole
 padded bucket (``dt`` = 0 on the pads; the tail written is the rows
 ``prompt_len - (taps - 1) .. prompt_len - 1``, zeros where the prompt is
@@ -68,6 +69,7 @@ from distributed_tensorflow_tpu.models.hybrid_ssm import (
     GroupedQueryAttention, dt_bias_init)
 from distributed_tensorflow_tpu.models.mla_moe import RMSNorm
 from distributed_tensorflow_tpu.models.moe import SwiGLU
+from distributed_tensorflow_tpu.models.slot_contract import SlotContract
 from distributed_tensorflow_tpu.ops.selective_scan import (
     selective_scan, selective_step)
 
@@ -309,10 +311,6 @@ class JambaLM(nn.Module):
                                  # caller's
 
     causal_lm = True
-    resumable_step = False       # the state has no positions to resume at
-    # the per-slot state leaves of the ``cache`` collection, and whether
-    # the table may narrow them to its ``kv_dtype``
-    slot_state = {"ssm_state": False, "conv_tail": True}
 
     def is_attention(self, i: int) -> bool:
         return i % self.attn_period == self.attn_offset
@@ -322,15 +320,22 @@ class JambaLM(nn.Module):
         """The layers whose block form runs ``ops/selective_scan``."""
         return sum(not self.is_attention(i) for i in range(self.layers))
 
-    def slot_decode_clone(self, *, partition_model: bool = False,
+    def slot_decode_clone(self, *, mesh=None,
                           kv_quant: bool = False) -> "JambaLM":
-        """The module ``SlotKVCache`` serves from."""
-        for on, what in ((partition_model, "a tensor-parallel slot table"),
-                         (kv_quant, "int8 storage of the table")):
-            if on:
-                raise NotImplementedError(
-                    f"{type(self).__name__} does not support {what}")
+        """The module ``SlotKVCache`` serves from: the same under any
+        ``mesh`` (replicated over a 'model' axis), and with float rows
+        (its contract declares no int8 rows, so the table refuses
+        ``kv_quant``)."""
         return self.clone(decode=True, decode_slots=True)
+
+    def slot_contract(self) -> SlotContract:
+        """Per-slot state beside the rows (the recurrent state is never
+        narrowed to the table's ``kv_dtype``, the convolution tail may
+        be), which has no positions to resume at; no experts."""
+        return SlotContract(
+            takes_active=True,
+            slot_state={"ssm_state": False, "conv_tail": True},
+            scan_layers=self.selective_scan_layers)
 
     @nn.compact
     def __call__(self, token_ids, train: bool = False, positions=None,

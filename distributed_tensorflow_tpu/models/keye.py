@@ -50,9 +50,10 @@ first and so selects among ``0 .. pos``.  A slot that is not ``active``
 writes no row (a free slot's rows stay as its last occupant left them) and
 sends its stale token to no expert.
 
-Beside the rows, each layer keeps per-slot STATE (``slot_state``) that
-nothing in the model reads: what its attention took and gave at one query,
-so that a comparison can hold the served path itself to its rule.  Entry 0
+Beside the rows, each layer keeps per-slot STATE (the slot contract's
+``slot_state``) that nothing in the model reads: what its attention took
+and gave at one query, so that a comparison can hold the served path
+itself to its rule.  Entry 0
 of ``seen_q``, ``seen_o`` (the query and the attention's output, ``(slots,
 2, Hq * d)``), ``seen_qi`` (the index queries, ``(slots, 2, Hi * Di)``) and
 ``seen_w`` (the head weights, float32, ``(slots, 2, Hi)``) is the slot's
@@ -77,6 +78,7 @@ from jax import lax
 from distributed_tensorflow_tpu.models.gpt import apply_rope, write_slot_rows
 from distributed_tensorflow_tpu.models.mla_moe import RMSNorm
 from distributed_tensorflow_tpu.models.moe import DroplessMoE
+from distributed_tensorflow_tpu.models.slot_contract import SlotContract
 from distributed_tensorflow_tpu.ops import sparse_attention as dsa
 
 # tokens the expert layer takes at a time past its router (DroplessMoE's
@@ -289,14 +291,6 @@ class KeyeLM(nn.Module):
                                  # caller's
 
     causal_lm = True
-    resumable_step = False       # the step has no token block to resume
-    # per-slot state (serving/kv_cache.py), none narrowed by the table's
-    # dtype: what each layer's attention took and gave at one query
-    slot_state = {name: False for name in SEEN + ("seen_rows",)}
-
-    @property
-    def expert_layers(self) -> int:
-        return self.layers
 
     def _topk(self) -> int:
         return min(self.index_topk, self.max_len)
@@ -321,15 +315,26 @@ class KeyeLM(nn.Module):
         return {"index_pairs": n * (n + 1) // 2 * self.layers,
                 "attended_pairs": attended * self.layers}
 
-    def slot_decode_clone(self, *, partition_model: bool = False,
+    def slot_decode_clone(self, *, mesh=None,
                           kv_quant: bool = False) -> "KeyeLM":
-        """The module ``SlotKVCache`` serves from."""
-        for on, what in ((partition_model, "a tensor-parallel slot table"),
-                         (kv_quant, "int8 storage of the table")):
-            if on:
-                raise NotImplementedError(
-                    f"{type(self).__name__} does not support {what}")
+        """The module ``SlotKVCache`` serves from: the same under any
+        ``mesh`` (replicated over a 'model' axis), and with float rows
+        (its contract declares no int8 rows, so the table refuses
+        ``kv_quant``)."""
         return self.clone(decode=True, decode_slots=True)
+
+    def slot_contract(self) -> SlotContract:
+        """Per-slot state, none narrowed by the table's dtype: what each
+        layer's attention took and gave at one query (the step has no
+        token block to resume); a round reports the index rows it scored
+        and the rows it attended, a prefill the pairs."""
+        return SlotContract(
+            takes_active=True,
+            slot_state={name: False for name in SEEN + ("seen_rows",)},
+            expert_layers=self.layers, num_experts=self.num_experts,
+            experts_per_token=self.experts_per_token,
+            round_attrs=self.slot_index_rows,
+            prefill_attrs=self.prefill_index_pairs)
 
     @nn.compact
     def __call__(self, token_ids, train: bool = False, positions=None,

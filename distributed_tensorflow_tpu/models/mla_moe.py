@@ -59,6 +59,7 @@ from jax import lax
 
 from distributed_tensorflow_tpu.models.gpt import write_slot_rows
 from distributed_tensorflow_tpu.models.moe import DroplessMoE, SwiGLU
+from distributed_tensorflow_tpu.models.slot_contract import SlotContract
 
 # expanded form: H x 512 x 4096 float32 scores at most
 ATTN_QUERY_BLOCK = 512
@@ -305,24 +306,25 @@ class LatentMoELM(nn.Module):
                                  # caller's
 
     causal_lm = True
-    resumable_step = False       # the one-token step reads every expert a
-                                 # token and the table holds latents:
-                                 # SlotKVCache builds no program that
-                                 # resumes a prompt or moves per-head K/V
 
-    @property
-    def expert_layers(self) -> int:
-        return max(self.layers - self.first_dense, 0)
-
-    def slot_decode_clone(self, *, partition_model: bool = False,
+    def slot_decode_clone(self, *, mesh=None,
                           kv_quant: bool = False) -> "LatentMoELM":
-        """The module ``SlotKVCache`` serves from."""
-        for on, what in ((partition_model, "a tensor-parallel slot table"),
-                         (kv_quant, "int8 storage of the latent table")):
-            if on:
-                raise NotImplementedError(
-                    f"{type(self).__name__} does not support {what}")
+        """The module ``SlotKVCache`` serves from: the same under any
+        ``mesh`` (replicated over a 'model' axis), and with float rows
+        (its contract declares no int8 rows, so the table refuses
+        ``kv_quant``)."""
         return self.clone(decode=True, decode_slots=True)
+
+    def slot_contract(self) -> SlotContract:
+        """No ``active``; the one-token step reads every expert a token and
+        the table holds latents: SlotKVCache builds no program that
+        resumes a prompt or moves per-head K/V."""
+        return SlotContract(
+            takes_active=False,
+            expert_layers=max(self.layers - self.first_dense, 0),
+            num_experts=self.num_experts,
+            experts_per_token=self.experts_per_token,
+            experts_held=self.experts_held)
 
     @nn.compact
     def __call__(self, token_ids, train: bool = False, positions=None,

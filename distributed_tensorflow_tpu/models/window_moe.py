@@ -64,6 +64,7 @@ from jax import lax
 from distributed_tensorflow_tpu.models.gpt import write_slot_rows
 from distributed_tensorflow_tpu.models.mla_moe import rope_adjacent
 from distributed_tensorflow_tpu.models.moe import DroplessMoE
+from distributed_tensorflow_tpu.models.slot_contract import SlotContract
 
 ATTN_BLOCK = 512            # query and key block of the blocked attention
 MASKED = -0.7 * float(jnp.finfo(jnp.float32).max)   # a score no key has
@@ -290,11 +291,6 @@ class WindowMoELM(nn.Module):
                                  # caller's
 
     causal_lm = True
-    resumable_step = False       # a ring holds no position to resume at
-
-    @property
-    def expert_layers(self) -> int:
-        return len(self.pattern)
 
     @property
     def slot_rings(self) -> dict[str, int]:
@@ -305,15 +301,38 @@ class WindowMoELM(nn.Module):
         rows = min(self.window, self.max_len)
         return {"ring_key": rows, "ring_value": rows}
 
-    def slot_decode_clone(self, *, partition_model: bool = False,
+    def slot_decode_clone(self, *, mesh=None,
                           kv_quant: bool = False) -> "WindowMoELM":
-        """The module ``SlotKVCache`` serves from."""
-        for on, what in ((partition_model, "a tensor-parallel slot table"),
-                         (kv_quant, "int8 storage of the table")):
-            if on:
-                raise NotImplementedError(
-                    f"{type(self).__name__} does not support {what}")
+        """The module ``SlotKVCache`` serves from: the same under any
+        ``mesh`` (replicated over a 'model' axis), and with float rows
+        (its contract declares no int8 rows, so the table refuses
+        ``kv_quant``)."""
         return self.clone(decode=True, decode_slots=True)
+
+    def slot_contract(self) -> SlotContract:
+        """Rings beside the full-length rows (a ring holds no position to
+        resume at); a model with rings reports ``past_window`` for a round
+        and ``ring_rows`` for a prefill."""
+        rings = self.slot_rings
+        hooks = dict(round_attrs=self.past_window,
+                     prefill_attrs=self.ring_rows) if rings else {}
+        return SlotContract(
+            takes_active=True, slot_rings=rings,
+            expert_layers=len(self.pattern), num_experts=self.num_experts,
+            experts_per_token=self.experts_per_token,
+            experts_held=self.experts_held, **hooks)
+
+    def past_window(self, lengths, advancing) -> dict[str, int]:
+        """The ``advancing`` slots whose length (host array ``lengths``)
+        exceeds the rings' rows: whose window layers no longer hold every
+        position."""
+        ring = min(self.slot_rings.values())
+        return {"past_window": int((lengths[advancing] > ring).sum())}
+
+    def ring_rows(self, prompt_len: int) -> dict[str, int]:
+        """The rows a prefill of ``prompt_len`` tokens writes into each
+        ring."""
+        return {"ring_rows": min(prompt_len, *self.slot_rings.values())}
 
     @nn.compact
     def __call__(self, token_ids, train: bool = False, positions=None,

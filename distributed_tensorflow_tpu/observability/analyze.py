@@ -704,14 +704,6 @@ _DIFF_METRICS: tuple[tuple[str, str], ...] = (
     # regression).  Both lower-is-better.
     ("serve_failover_recovery_p95_s", "lower"),
     ("serve_duplicate_emissions", "lower"),
-    # paged KV (round 16; BASELINE.md "Paged accounting"): blocks in use
-    # at equal workload is the footprint the block pool exists to shrink
-    # (aliased prefixes stored once), and the zero-copy hit rate is the
-    # fraction of prefix-pool lookups served by pointer aliasing instead
-    # of device copies — fewer zero-copy hits at the same trace means
-    # admissions are paying prefill for KV the pool already holds.
-    ("serve_kv_blocks_in_use", "lower"),
-    ("serve_prefix_zero_copy_hit_rate", "higher"),
     # timeline + XLA ledger (round 17; BASELINE.md "Memory/compile
     # accounting"): the summed per-program HBM estimate is the
     # capacity-per-chip number every KV/precision optimization exists to
@@ -725,11 +717,10 @@ _DIFF_METRICS: tuple[tuple[str, str], ...] = (
     ("serve_sink_dropped", "lower"),
     ("serve_trace_overhead_s", "lower"),
     ("timeline_overhead_s", "lower"),
-    # queue-depth area (requests·s of queueing over the window) and the
-    # KV block-footprint p95 — the autoscaler's target signals; at equal
-    # offered load, growth is an admission/capacity regression
+    # queue-depth area (requests·s of queueing over the window) — the
+    # autoscaler's target signal; at equal offered load, growth is an
+    # admission/capacity regression
     ("queue_depth_auc", "lower"),
-    ("kv_blocks_in_use_p95", "lower"),
     # heterogeneous fleet (round 18; BASELINE.md "Disaggregation
     # accounting"): the affinity router's fleet-wide prefix hit rate is
     # the number the router exists to raise (fewer hits at the same
@@ -819,8 +810,7 @@ def load_report(path: str | Path) -> dict[str, Any]:
         # the --timeline gauge digests ride the serve section under their
         # own names (batcher/fleet summary keys, no serve_ prefix) —
         # surface the gated ones flat
-        for key in ("queue_depth_auc", "kv_blocks_in_use_p95",
-                    "timeline_overhead_s"):
+        for key in ("queue_depth_auc", "timeline_overhead_s"):
             if isinstance(serve.get(key), (int, float)):
                 flat.setdefault(key, serve[key])
         # fleet-mode telemetry self-accounting (serve_fleet subsection)

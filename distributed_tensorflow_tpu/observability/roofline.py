@@ -15,7 +15,7 @@ Three parts:
                    FLOPs per chunk (chunk sums are exact — they telescope
                    to the monolithic figure), and the bytes a decode step
                    MUST read (params once per batched step + per-slot
-                   context KV under monolithic/paged/int8 layouts).
+                   context KV under f32/bf16/int8 storage).
   DevicePeaks    — peak matmul flops/s per dtype + HBM bytes/s, keyed on
                    ``device_kind`` substrings.  An unknown kind returns
                    None and every downstream MFU/MBU honestly reports
@@ -32,11 +32,11 @@ Accounting rules (the BASELINE.md "Roofline accounting" contract):
   FLOPs and XLA's bookkeeping are never credited; XLA's own
   ``cost_analysis`` count rides alongside as a cross-check only.
 * MBU counts bytes the model *must* read — the weights once per batched
-  decode step and the written KV context per slot (block-granular under
-  the paged layout, payload+scales under int8) — never the bytes XLA
-  happened to move (a monolithic decode program scans the full
-  ``max_len`` table; those idle bytes are the *inefficiency* MBU exists
-  to expose, not part of the denominator's credit).
+  decode step and the written KV context per slot (payload+scales under
+  int8) — never the bytes XLA happened to move (a decode program may
+  scan the full ``max_len`` table; those idle bytes are the
+  *inefficiency* MBU exists to expose, not part of the denominator's
+  credit).
 * Any published MFU/MBU states ``PEAK_TABLE_REVISION`` — peak figures
   are revisable, and a revision bump re-bases every claim.
 
@@ -46,7 +46,6 @@ report or manifest without importing jax.
 
 from __future__ import annotations
 
-import math
 from dataclasses import dataclass
 
 # Revision of the peak figures below.  Bump when any entry changes and
@@ -139,8 +138,6 @@ class GPTCostModel:
     tie_embeddings: bool = True
     moe_experts: int = 0
     kv_dtype: str = "f32"          # KV cache storage: "f32"|"bf16"|"int8"
-    kv_layout: str = "monolithic"  # "monolithic"|"paged"
-    paged_block: int = 16
     # measured stored-param bytes (sum of actual leaf nbytes) when the
     # caller has real params in hand; the analytic 4-byte-f32 weight
     # count is the fallback
@@ -299,16 +296,10 @@ class GPTCostModel:
 
     def kv_read_bytes(self, length: int) -> int:
         """Bytes a decode step MUST read for one slot with ``length``
-        cached positions.  Paged layout reads block-granular —
-        ceil(L/block)·block positions; monolithic credits exactly L even
-        though the compiled program scans the whole max_len table (those
-        idle bytes are the inefficiency MBU exposes)."""
-        length = int(length)
-        if length <= 0:
-            return 0
-        if self.kv_layout == "paged":
-            length = math.ceil(length / self.paged_block) * self.paged_block
-        return length * self._kv_bytes_per_position
+        cached positions: exactly L, even where the compiled program scans
+        more of the max_len table (those idle bytes are the inefficiency
+        MBU exposes)."""
+        return max(int(length), 0) * self._kv_bytes_per_position
 
     def decode_step_bytes(self, contexts) -> int:
         """Must-read bytes of ONE batched decode (or speculative verify)
@@ -436,17 +427,13 @@ class Roofline:
     @classmethod
     def for_kv(cls, kv, device_kind, n_devices: int = 1) -> "Roofline":
         """Serving-side construction from a slot KV table: the decode
-        model's config plus the table's ACTUAL storage dtype/layout, and
+        model's config plus the table's ACTUAL storage dtype, and
         measured stored-param bytes when the table exposes them."""
         model = getattr(kv, "dm", None)
         cost = GPTCostModel.from_model(
             model,
             kv_dtype=str(getattr(kv, "kv_dtype", None)
                          or getattr(model, "dtype", "float32")),
-            kv_layout=str(getattr(kv, "kv_layout", "monolithic")
-                          or "monolithic"),
-            paged_block=int(getattr(kv, "paged_block", None)
-                            or getattr(model, "paged_block", 16) or 16),
         ) if model is not None else None
         if cost is not None:
             cost.param_bytes_override = getattr(kv, "param_bytes", None)

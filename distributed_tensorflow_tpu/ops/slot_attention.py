@@ -50,7 +50,7 @@ waited at its last step.  Nothing else of the table is written: a round
 writes ``slots`` blocks a leaf, where the plain form rewrote the table.
 
 A Mosaic kernel cannot be partitioned by GSPMD, so a served module with a
-mesh (``paged_mesh``) or tensor-parallel heads takes the plain form, as do
+mesh (``slot_mesh``) or tensor-parallel heads takes the plain form, as do
 grouped-query heads and a block that is not a whole number of lanes
 (``takes_kernel``).  Off the TPU the plain form is what runs, so every CPU
 test of the serving path keeps its exact numbers; the kernel runs in the

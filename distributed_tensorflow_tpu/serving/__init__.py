@@ -9,14 +9,8 @@ Three layers:
   (``begin_insert``/``prefill_chunk``) splits an admission into fixed
   token-budget chunks, and the optional block-granular prefix pool
   (``prefix_cache_blocks``) reuses cached shared-prompt KV with LRU
-  eviction and hit/miss accounting.  ``kv_layout="paged"`` swaps the
-  per-slot rows for ``PagedSlotKVCache``'s refcounted physical block
-  pool (vLLM PagedAttention): prefix-pool hits alias blocks by pointer
-  (zero KV bytes copied), first write into a shared block copies on
-  write, decode/verify read through the block table in one fused Pallas
-  kernel (``ops/paged_attention.py``, in-kernel int8 dequant), and pool
-  pressure defers admission (``can_admit``) or raises
-  ``BlockPoolExhausted``.
+  eviction and hit/miss accounting.  A model family is served through
+  one declared ``SlotContract`` (the ``kv_cache`` module docstring).
 * ``scheduler.ContinuousBatcher`` — the host half: an iteration-level
   request scheduler (admit between decode steps, evict finished slots,
   with ``prefill_chunk`` at most one prompt chunk interleaved per decode
@@ -51,7 +45,7 @@ from distributed_tensorflow_tpu.serving.fleet import (  # noqa: F401
     AutoscalePolicy, CorruptionDetected, FaultInjector, FaultSpec,
     InjectedFault, ReplicaSet, RequestJournal, build_replica_kvs)
 from distributed_tensorflow_tpu.serving.kv_cache import (  # noqa: F401
-    BlockPoolExhausted, PagedSlotKVCache, SlotKVCache, SlotOverflow)
+    SlotContract, SlotKVCache, SlotOverflow)
 from distributed_tensorflow_tpu.serving.scheduler import (  # noqa: F401
     ContinuousBatcher, Request, RequestQueue, RequestResult, VirtualClock,
     WallClock)

@@ -982,7 +982,6 @@ class ReplicaSet:
         self._sums: dict[str, float] = {}
         self._spec_sums: dict[str, int] = {}
         self._prefix_sums: dict[str, int] = {}
-        self._paged_sums: dict[str, int] = {}   # zero-copy/CoW across replicas
         self._phase_sums: dict[str, float] = {}
         # --roofline ledgers (identically empty flag-off): fleet totals of
         # the batchers' analytic counters + the same split per replica id
@@ -1595,16 +1594,6 @@ class ReplicaSet:
                     if isinstance(v, int):
                         self._prefix_sums[k] = (self._prefix_sums.get(k, 0)
                                                 + v)
-            pg = s.get("paged")
-            if pg:
-                # counter deltas sum across replicas; pool-state keys
-                # (blocks_in_use/utilization) are read live at summary
-                # time from the replica kvs instead
-                for k in ("zero_copy_hits", "zero_copy_blocks",
-                          "zero_copy_tokens", "cow_copies",
-                          "block_deferrals"):
-                    self._paged_sums[k] = (self._paged_sums.get(k, 0)
-                                           + pg.get(k, 0))
             for k, v in (s.get("device_phase_s") or {}).items():
                 self._phase_sums[k] = self._phase_sums.get(k, 0.0) + v
             # multi-step dispatch ledger (keys absent flag-off): host
@@ -1906,27 +1895,6 @@ class ReplicaSet:
             proposed = spec_sec.get("proposed_tokens", 0)
             accept_rate = (spec_sec.get("accepted_tokens", 0) / proposed
                            if proposed else None)
-        # fleet paged accounting: counters summed across replica windows,
-        # pool state (blocks in use / utilization) summed/averaged over
-        # the CURRENT replica pools
-        paged_sec = zero_copy_rate = None
-        paged_kvs = [r.kv for r in self.replicas
-                     if hasattr(r.kv, "paged_stats")]
-        if paged_kvs:
-            states = [kv.paged_stats() for kv in paged_kvs]
-            paged_sec = dict(self._paged_sums)
-            paged_sec["num_blocks"] = sum(s["num_blocks"] for s in states)
-            paged_sec["block"] = states[0]["block"]
-            paged_sec["blocks_in_use"] = sum(s["blocks_in_use"]
-                                             for s in states)
-            paged_sec["utilization"] = (paged_sec["blocks_in_use"]
-                                        / paged_sec["num_blocks"])
-            asked = (self._prefix_sums.get("hits", 0)
-                     + self._prefix_sums.get("misses", 0))
-            if self._prefix_sums:
-                zero_copy_rate = (
-                    paged_sec.get("zero_copy_blocks", 0) / asked
-                    if asked else 0.0)
         qw = merged.histogram("queue_wait")
         qd = merged.histogram("queue_depth")
         prefill_tokens = int(self._sums.get("prefill_tokens", 0))
@@ -1940,16 +1908,6 @@ class ReplicaSet:
             "serve_kv_bytes_per_slot":
                 self.replicas[0].kv.kv_bytes_per_slot(),
             "serve_param_bytes": self.replicas[0].kv.param_bytes,
-            "serve_kv_layout": getattr(self.replicas[0].kv, "kv_layout",
-                                       "monolithic"),
-            "serve_kv_blocks_in_use": (paged_sec["blocks_in_use"]
-                                       if paged_sec else None),
-            "serve_kv_block_utilization": (paged_sec["utilization"]
-                                           if paged_sec else None),
-            "serve_prefix_zero_copy_hit_rate": zero_copy_rate,
-            "serve_kv_block_deferrals": int(self._paged_sums.get(
-                "block_deferrals", 0)),
-            "paged": paged_sec,
             "serve_accept_rate": accept_rate,
             "speculative": spec_sec,
             "decode_iterations": int(self._sums.get(
@@ -2051,11 +2009,6 @@ class ReplicaSet:
                 filter(None, (self.timeline.stat("queue_depth", "auc",
                                                  replica=r.id)
                               for r in self.replicas))) or None
-            summary["kv_blocks_in_use_p95"] = max(
-                filter(lambda v: v is not None,
-                       (self.timeline.stat("kv_blocks_in_use", "p95",
-                                           replica=r.id)
-                        for r in self.replicas)), default=None)
             summary["timeline_overhead_s"] = self.timeline.overhead_s
         if self.roofline is not None:
             # --roofline fleet keys only when attached (flag-off parity

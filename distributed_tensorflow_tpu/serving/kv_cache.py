@@ -10,12 +10,43 @@ single-token decode step advances every active slot regardless of age.
 Admission and eviction are per-slot edits between decode iterations — the
 decode program never recompiles.
 
+The contract with a served model (``SlotContract``).  A model family is
+served by two methods on its class and nothing else:
+
+* ``slot_decode_clone(*, mesh=None, kv_quant=False)`` returns the module
+  the table runs: the family's slot-table decode mode (``decode_slots``),
+  dropout off.  ``mesh`` is the serving mesh and ``kv_quant`` asks for
+  int8 rows; a family that uses neither takes both and ignores them.
+* ``slot_contract()`` on that clone returns one frozen ``SlotContract``
+  (``models/slot_contract.py``, which imports nothing of the serving
+  stack, so a model file can import it).  It is the only thing
+  ``SlotKVCache`` reads of the model besides ``max_len`` and the module's
+  ``init``/``apply``: whether the call takes ``active`` and whether its
+  one-token step resumes from any position; its state and ring leaves;
+  its routed experts (layers, width, choices a token, the share held
+  here); its selective-scan layers; the dtype rule of its parameters;
+  whether its heads split over the mesh's ``model`` axis (the table then
+  splits its kv-head dim with them; otherwise both replicate over that
+  axis, as a draft model beside a tensor-parallel target does) and
+  whether it stores int8 rows (the table refuses int8 storage
+  otherwise); and two hooks,
+  ``round_attrs(lengths, advancing)`` and ``prefill_attrs(prompt_len)``,
+  which return the span attributes the family reports for a decode round
+  and for a prefill (the scheduler puts them on ``decode_step`` and
+  ``prefill`` as they come).  A family without the two methods is
+  refused at construction, by its name.
+
+A new family plugs in by declaring these in its model file, with tests of
+its own (tests/test_slot_contract.py holds every family to its contract,
+and serves a toy decoder that no file of this package names); no file
+under serving/ changes.
+
 Device-side contract (everything else lives in serving/scheduler.py):
 
 * the cache is a pytree with the slot dim first on every leaf, and THREE
-  kinds of leaf, which the served model tells apart (its ``slot_rings``
-  names the second kind and its ``slot_state`` the third; a model with
-  neither attribute keeps the first kind only):
+  kinds of leaf, which the contract tells apart (``slot_rings`` names the
+  second kind and ``slot_state`` the third; a model that declares neither
+  keeps the first kind only):
 
   - per-position ROWS ``(slots, max_len, ...)``: per-head keys and values
     ``(slots, max_len, kv_heads, head_dim)`` for models/gpt.py, for the
@@ -52,10 +83,8 @@ Device-side contract (everything else lives in serving/scheduler.py):
     moves validity backwards or copies a slot by rows of ``max_len``
     cannot hold over a ring (the row a rewind would uncover is
     overwritten): ``rewind``, ``commit_block`` / ``verify_block``, the
-    prefix pool, chunk resume, the paged layout, multi-step dispatch and
-    KV handoff raise ``NotImplementedError`` by name for a model with
-    rings (int8 storage and a tensor-parallel table are refused by the
-    model's own ``slot_decode_clone``);
+    prefix pool, chunk resume, multi-step dispatch and KV handoff raise
+    ``NotImplementedError`` by name for a model with rings;
   - per-slot STATE ``(slots, ...)`` with no position axis, of whatever
     shape the model gives it: the recurrent state ``(slots, heads,
     head_dim, state)`` (models/hybrid_ssm.py) or ``(slots, d_inner,
@@ -71,8 +100,7 @@ Device-side contract (everything else lives in serving/scheduler.py):
     rows), the STEP is handed ``active`` and keeps the state of a slot
     that is not active bit for bit, and validity cannot be moved by
     bookkeeping: ``rewind``, ``commit_block`` / ``verify_block``, the
-    prefix pool, chunk resume, the paged layout, int8 storage, multi-step
-    dispatch, KV handoff and a tensor-parallel table raise
+    prefix pool, chunk resume, multi-step dispatch and KV handoff raise
     ``NotImplementedError`` by name for a model with state (no state
     snapshot is built).
 
@@ -87,10 +115,9 @@ Device-side contract (everything else lives in serving/scheduler.py):
   ONE call of the served module over the whole padded prompt from
   position 0 (``prompt_len`` marks it: the block attends within itself,
   writes the slot's rows in one piece and returns logits for the last
-  real position only), for models/gpt.py, models/mla_moe.py and
-  models/hybrid_ssm.py alike: a prompt token then costs its FLOPs, where
-  a scan of the one-token step read every weight (and every expert) once
-  a token;
+  real position only), for every served family alike: a prompt token
+  then costs its FLOPs, where a scan of the one-token step read every
+  weight (and every expert) once a token;
 * ``begin_insert``/``prefill_chunk`` split that admission into fixed
   token-budget chunks (Sarathi-Serve, arXiv:2403.02310): each chunk feeds
   its tokens through the SAME per-token decode math inside a ``lax.scan``
@@ -104,6 +131,11 @@ Device-side contract (everything else lives in serving/scheduler.py):
   the exact token bytes of the prefix: on admission the longest cached
   prefix is copied into the slot and prefill starts at the first uncached
   block, with hit/miss/evict accounting and bounded LRU eviction.
+
+What is built from a one-token step that resumes from any position over
+per-head K/V rows (chunk resume, the prefix pool, multi-step dispatch,
+speculative verify, KV handoff) raises ``NotImplementedError`` by name
+for a model whose contract says ``resumable_step=False``.
 
 Greedy slot decode is token-identical to the sequential ``generate``
 sampler per request (tests/test_serving.py): the chunk scan at position t
@@ -132,7 +164,6 @@ oracle is tolerance-based, the one serving feature with that caveat).
 from __future__ import annotations
 
 import hashlib
-import inspect
 import time
 from collections import OrderedDict
 
@@ -144,6 +175,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 import flax.linen as nn
 
+from distributed_tensorflow_tpu.models.slot_contract import SlotContract
 from distributed_tensorflow_tpu.observability.trace import recorder
 from distributed_tensorflow_tpu.parallel import mesh as meshlib
 
@@ -195,39 +227,25 @@ class SlotOverflow(RuntimeError):
     ADVICE r3: never silently clamp)."""
 
 
-class BlockPoolExhausted(RuntimeError):
-    """The paged KV block pool has no free physical block for a required
-    write.  Admission-level pool pressure is the SCHEDULER's problem
-    (``PagedSlotKVCache.can_admit`` + block budgets defer admissions);
-    reaching this mid-flight means the block accounting is broken — the
-    paged twin of ``SlotOverflow``, not an overload signal."""
-
-
 class SlotKVCache:
     """Fixed slot table + compiled prefill/decode programs for one model
-    with a slot-decode mode (``models/gpt.GPTLM``,
-    ``models/mla_moe.LatentMoELM``, ``models/hybrid_ssm.HybridSSMLM``,
-    ``models/window_moe.WindowMoELM``, ``models/jamba.JambaLM``,
-    ``models/keye.KeyeLM``).
+    that declares the slot contract (``SlotContract``;
+    ``models/gpt.GPTLM``, ``models/mla_moe.LatentMoELM``,
+    ``models/hybrid_ssm.HybridSSMLM``, ``models/window_moe.WindowMoELM``,
+    ``models/jamba.JambaLM``, ``models/keye.KeyeLM``).
 
     ``model`` is the TRAINING-mode module (any attention impl); its
     ``slot_decode_clone`` gives the module served from — for ``GPTLM``
     exactly like ``generate`` clones into cursor-decode mode: dense cache
     attention, dropout off, Megatron TP layout kept when ``mesh`` has a
-    'model' axis and the model was partitioned.  The table's leaves come
-    from that module's own abstract init.  What is built from a resumable
-    one-token step over per-head K/V rows — the paged layout, chunk
-    resume, the prefix pool, int8 storage, multi-step dispatch,
-    speculative verify, KV handoff — raises ``NotImplementedError`` by
-    name for a model without one (``resumable_step`` on the model class),
-    and so does what moves validity by bookkeeping for a model that keeps
-    per-slot state (``slot_state`` on the model class) or rings
-    (``slot_rings``).
+    'model' axis and the model was partitioned — and that module's
+    ``slot_contract()`` is all the table reads of the family.  The
+    table's leaves come from the module's own abstract init.
     ``params`` may be a TP engine's committed TrainState params or
     host/single-device params (replicated over the mesh).  The table
     HOLDS each leaf in the dtype in which the model's call first uses it
-    (``_place_params``; the model's ``step_param_dtype`` is the rule): a
-    float32 ``GPTLM`` checkpoint served at ``dtype=bfloat16`` is held as
+    (``_place_params``; the contract's ``step_param_dtype`` is the rule):
+    a float32 ``GPTLM`` checkpoint served at ``dtype=bfloat16`` is held as
     bfloat16 kernels, biases and embeddings beside float32 ``LayerNorm``
     leaves, converted once when the table takes it, and ``self.params``
     is then not the caller's tree (which is not donated: it is freed
@@ -244,31 +262,11 @@ class SlotKVCache:
     through the two compiled programs.
     """
 
-    def __new__(cls, *args, kv_layout: str = "monolithic", **kwargs):
-        # --serve-kv-layout dispatch: constructing a SlotKVCache with
-        # kv_layout="paged" yields the paged subclass, so every call site
-        # (harness, fleet's build_replica_kvs **kv_kwargs
-        # pass-through) selects the layout with one kwarg and no factory
-        if cls is SlotKVCache and kv_layout == "paged":
-            return super().__new__(PagedSlotKVCache)
-        return super().__new__(cls)
-
     def __init__(self, model: nn.Module, params, slots: int, *,
                  mesh=None, greedy: bool = True, temperature: float = 1.0,
                  prefill_bucket: int = 8, rng=None, kv_dtype=None,
                  prefix_cache_blocks: int = 0, prefix_block: int = 16,
-                 kv_layout: str = "monolithic", paged_blocks: int = 0,
-                 paged_block: int = 0, paged_fused: bool = True,
                  ledger=None):
-        if kv_layout not in ("monolithic", "paged"):
-            raise ValueError(
-                f"kv_layout must be 'monolithic' or 'paged', "
-                f"got {kv_layout!r}")
-        if paged_blocks or paged_block:
-            raise ValueError(
-                "paged_blocks/paged_block only apply to "
-                "kv_layout='paged'")
-        self.kv_layout = "monolithic"
         # --timeline's XLA memory/compile ledger: when attached, every
         # compiled program routes through ledger.jit (same program, AOT-
         # observed); None keeps the literal jax.jit path byte-identical
@@ -297,28 +295,25 @@ class SlotKVCache:
         if kv_dtype is not None:
             kv_dtype = jnp.dtype(kv_dtype)
             self.quantized = kv_dtype == jnp.dtype(jnp.int8)
-        keep_tp = (mesh is not None
-                   and getattr(model, "partition_model", False)
-                   and meshlib.MODEL_AXIS in mesh.axis_names)
-        self.resumable_step = model.resumable_step
-        # the leaves that are per-slot state and not per-position rows,
-        # by name, and whether ``kv_dtype`` may narrow each; the leaves
-        # that are rings, by name, and their rows (module docstring: the
-        # three kinds)
-        self.state_leaves = dict(getattr(model, "slot_state", {}))
-        self.ring_leaves = dict(getattr(model, "slot_rings", {}))
         self.served_model = type(model).__name__    # named in a refusal
+        try:
+            clone, _ = model.slot_decode_clone, type(model).slot_contract
+        except AttributeError:
+            raise TypeError(
+                f"{self.served_model} declares no slot contract: a served "
+                f"model gives slot_decode_clone() and slot_contract() "
+                f"(serving/kv_cache.py)") from None
+        self.dm = clone(mesh=mesh, kv_quant=self.quantized)
+        self.contract = c = self.dm.slot_contract()
+        if self.quantized and not c.int8_rows:
+            raise NotImplementedError(
+                f"{self.served_model} does not support int8 storage of the "
+                f"table")
         # what ``insert`` runs (the ``prefill`` span's ``form``): one call
         # over the padded prompt, or the chunk scan the prefix pool needs
         self.prefill_form = "scan" if prefix_cache_blocks else "batched"
         if prefix_cache_blocks:
             self._scan_model_only("the prefix pool")
-        self.dm = model.slot_decode_clone(partition_model=keep_tp,
-                                          kv_quant=self.quantized)
-        if mesh is not None and hasattr(self.dm, "paged_mesh"):
-            # GSPMD partitions the program: GPT-2's one-token step keeps
-            # its plain form, a Mosaic kernel cannot be partitioned
-            self.dm = self.dm.clone(paged_mesh=mesh)
         self._rng = rng if rng is not None else jax.random.key(0)
 
         # zero slot cache from an abstract init — zeros-from-shape IS the
@@ -341,7 +336,7 @@ class SlotKVCache:
             cache = jax.tree_util.tree_map_with_path(
                 lambda path, t: t.astype(kv_dtype)
                 if jnp.issubdtype(t.dtype, jnp.floating)
-                and self.state_leaves.get(path[-1].key, True) else t, cache)
+                and c.slot_state.get(path[-1].key, True) else t, cache)
         # the table's actual storage dtype, surfaced in the serve report
         # section (for int8 the first FLOAT leaf is a scale, so the name
         # is pinned explicitly; otherwise it is the rows' dtype)
@@ -349,7 +344,7 @@ class SlotKVCache:
             (str(leaf.dtype) for path, leaf in
              jax.tree_util.tree_leaves_with_path(cache)
              if jnp.issubdtype(leaf.dtype, jnp.floating)
-             and path[-1].key not in self.state_leaves), "float32")
+             and path[-1].key not in c.slot_state), "float32")
 
         self._vec_sharding = None
         self._blk_sharding = None
@@ -362,7 +357,7 @@ class SlotKVCache:
                     f"block")
             cache = jax.tree.map(
                 lambda t: jax.device_put(t, meshlib.kv_slot_sharding(
-                    mesh, t.ndim, shard_heads=keep_tp)), cache)
+                    mesh, t.ndim, shard_heads=c.tensor_parallel)), cache)
             self._vec_sharding = meshlib.kv_slot_sharding(mesh, 1)
             self._blk_sharding = meshlib.kv_slot_sharding(mesh, 2)
         self.cache = cache
@@ -375,7 +370,43 @@ class SlotKVCache:
         self.reserved = np.zeros(self.slots, np.bool_)
         self.tokens = np.zeros(self.slots, np.int32)   # last token per slot
         self._pending: dict[int, dict] = {}            # slot → prefill state
-        self._init_multi_state()
+        # ``tracer`` takes the table's own spans: ``program_build`` and, in
+        # a single-step round, ``step_dispatch`` and ``token_fetch`` (the
+        # batcher that drives this table hands it its own)
+        self.tracer = recorder()
+        # the span attributes of the last ``advance`` (the contract's
+        # ``round_attrs``, and for a model with routed experts the round's
+        # routing load over the experts held here) and of the last
+        # admission (``prefill_attrs``): the scheduler's ``decode_step``
+        # and ``prefill`` spans carry them
+        self.last_round_attrs: dict = {}
+        self.last_prefill_attrs: dict = {}
+        self.expert_assignments = 0     # (token, expert) pairs, cumulative
+        # a model whose block prefill runs ``ops/selective_scan``: the
+        # positions and prompt tokens that went through the kernel, times
+        # its layers (cumulative; ``counters``)
+        self.ssm_scan_positions = 0
+        self.ssm_scan_tokens = 0
+        # the multi-step decode state: ``_dev_vecs`` is the value-keyed
+        # host→device cache behind ``_dev_cached`` (slot vectors stay on
+        # device between iterations and re-upload only when the host
+        # VALUE changed — the explicit host-mirror sync point);
+        # ``eos_tok`` / ``budget`` arm the fused program's in-device
+        # deactivation (-1 = no EOS, 0 = unlimited budget — the draft
+        # table's mode); ``halted`` mirrors slots the device stopped
+        # advancing that the scheduler has not yet evicted (occupancy
+        # ``active`` is separate); ``dispatch_count`` counts every
+        # compiled-program host call
+        self.eos_tok = np.full(self.slots, -1, np.int32)
+        self.budget = np.zeros(self.slots, np.int32)
+        self.halted = np.zeros(self.slots, np.bool_)
+        self.dispatch_count = 0
+        self._dev_vecs: dict[str, tuple[np.ndarray, object]] = {}
+        self._multis: dict[int, object] = {}    # k → fused decode program
+        self._multi_state = None    # device carry after the last dispatch
+        self._multi_snap = None     # host view at the last dispatch
+        self._multi_pending: list[dict] = []    # in-flight rounds (FIFO)
+        self._inflight = np.zeros(self.slots, np.int32)
         self.params = self._place_params(params)    # under self.tracer
 
         # block-aligned prefix pool (LRU over exact prefix-byte keys);
@@ -411,67 +442,16 @@ class SlotKVCache:
         self._handoff_read = None                      # disagg KV handoff
         self._handoff_write = None
 
-    def _init_multi_state(self) -> None:
-        """Shared (monolithic + paged) init for the device-resident
-        vector cache and the multi-step decode state.
-
-        ``_dev_vecs`` is the value-keyed host→device cache behind
-        ``_dev_cached``: slot vectors (tokens/lengths/mask) stay on
-        device between iterations and re-upload only when the host VALUE
-        changed — the explicit host-mirror sync point.  ``eos_tok`` /
-        ``budget`` arm the fused program's in-device deactivation
-        (-1 = no EOS, 0 = unlimited budget — the draft table's mode);
-        ``halted`` mirrors slots the device stopped advancing that the
-        scheduler has not yet evicted (occupancy ``active`` is separate);
-        ``dispatch_count`` counts every compiled-program host call;
-        ``tracer`` takes the table's own spans: ``program_build`` and, in
-        a single-step round, ``step_dispatch`` and ``token_fetch`` (the
-        batcher that drives this table hands it its own)."""
-        self.tracer = recorder()
-        # a model with routed experts: the decode step also returns the
-        # round's routing load (advance -> last_routing)
-        self.expert_layers = int(getattr(self.dm, "expert_layers", 0))
-        self.last_routing: dict[str, float] | None = None
-        self.expert_assignments = 0     # (token, expert) pairs, cumulative
-        # a model whose one-token step reads a prefix of the table: how
-        # many positions of every slot the last ``advance`` read
-        self._rows_rule = getattr(self.dm, "slot_rows_read", None)
-        self.last_rows_read: int | None = None
-        # a model whose attention keeps the keys a learned indexer selects
-        # (models/keye.py): what the last ``advance`` scored and attended
-        # (``index_rows``, ``attended_rows``) and what the last ``insert``
-        # did (``index_pairs``, ``attended_pairs``), over the layers
-        self._index_rule = getattr(self.dm, "slot_index_rows", None)
-        self._pairs_rule = getattr(self.dm, "prefill_index_pairs", None)
-        self.last_index_rows: dict[str, int] | None = None
-        self.last_prefill_pairs: dict[str, int] | None = None
-        # a model whose block prefill runs ``ops/selective_scan``: in how
-        # many layers, and the positions and prompt tokens that went
-        # through the kernel (cumulative; ``counters``)
-        self.scan_layers = int(getattr(self.dm, "selective_scan_layers", 0))
-        self.ssm_scan_positions = 0
-        self.ssm_scan_tokens = 0
-        self.eos_tok = np.full(self.slots, -1, np.int32)
-        self.budget = np.zeros(self.slots, np.int32)
-        self.halted = np.zeros(self.slots, np.bool_)
-        self.dispatch_count = 0
-        self._dev_vecs: dict[str, tuple[np.ndarray, object]] = {}
-        self._multis: dict[int, object] = {}    # k → fused decode program
-        self._multi_state = None    # device carry after the last dispatch
-        self._multi_snap = None     # host view at the last dispatch
-        self._multi_pending: list[dict] = []    # in-flight rounds (FIFO)
-        self._inflight = np.zeros(self.slots, np.int32)
-
     def _scan_model_only(self, feature: str) -> None:
         """What rests on a scan of the one-token step from any start
         position and on per-head K/V leaves is refused by name for a model
         that has no such step."""
         self._rows_only(feature)
-        if not self.resumable_step:
+        if not self.contract.resumable_step:
             raise NotImplementedError(
                 f"{feature} is not implemented for a model without a "
-                f"resumable one-token step (models/mla_moe.py): the "
-                f"monolithic table with insert/advance/evict is")
+                f"resumable one-token step ({self.served_model}): the "
+                f"table's insert/advance/evict are")
 
     def _rows_only(self, feature: str) -> None:
         """What moves a slot's validity by bookkeeping, or copies a slot
@@ -480,18 +460,19 @@ class SlotKVCache:
         position, and no snapshot of it is built) and for a model that
         keeps rings (the row a shorter length would uncover has been
         overwritten, and a ring has no rows of ``max_len`` to copy)."""
-        if self.ring_leaves:
+        c = self.contract
+        if c.slot_rings:
             raise NotImplementedError(
                 f"{feature} is not implemented for a model that keeps "
                 f"rings of its last positions beside its full-length rows "
-                f"({self.served_model}: {sorted(self.ring_leaves)}): "
-                f"the monolithic table with insert/advance/evict is")
-        if self.state_leaves:
+                f"({self.served_model}: {sorted(c.slot_rings)}): "
+                f"the table's insert/advance/evict are")
+        if c.slot_state:
             raise NotImplementedError(
                 f"{feature} is not implemented for a model that keeps "
                 f"per-slot state beside its rows "
-                f"({self.served_model}: {sorted(self.state_leaves)}): "
-                f"the monolithic table with insert/advance/evict is")
+                f"({self.served_model}: {sorted(c.slot_state)}): "
+                f"the table's insert/advance/evict are")
 
     def _place_params(self, params, *, replacing=None):
         """What the table does with a tree it is given: the one door of
@@ -535,7 +516,7 @@ class SlotKVCache:
     def _narrow_params(self, params):
         """Each leaf in the dtype in which the served model's call first
         uses it.  A leaf that the model says its call converts before the
-        first use (``step_param_dtype`` on the model: ``GPTLM``'s
+        first use (the contract's ``step_param_dtype``: ``GPTLM``'s
         ``Dense`` and ``Embed`` leaves go to ``model.dtype``, its
         ``LayerNorm`` leaves do not), and that is wider than that dtype,
         is converted here once: the bits the call would make, which every
@@ -544,7 +525,7 @@ class SlotKVCache:
         with no rule, a float32 model and a tree narrowed before get
         their tree back.  A ``jax.ShapeDtypeStruct`` is narrowed
         abstractly."""
-        use_dtype = getattr(self.dm, "step_param_dtype", None)
+        use_dtype = self.contract.step_param_dtype
         if use_dtype is None:
             return params
 
@@ -614,16 +595,12 @@ class SlotKVCache:
             rng, logits / max(self.temperature, 1e-6))
 
     def _hold(self, active) -> dict:
-        """``active`` for a model whose call takes it: one with per-slot
-        state keeps the state of a slot that is not active bit for bit,
-        one with routed experts may keep such a slot's stale token from
-        every expert, GPT-2 reads the prefix the active slots need."""
-        takes = "active" in inspect.signature(
-            type(self.dm).__call__).parameters
-        return {"active": active} if takes else {}
+        """``active`` for a model whose call takes it (the contract's
+        ``takes_active``)."""
+        return {"active": active} if self.contract.takes_active else {}
 
     def _build_step(self):
-        dm = self.dm
+        dm, c = self.dm, self.contract
 
         def step(params, cache, tokens, lengths, active, rng):
             # ROWS: write index = current length, written by the model:
@@ -663,17 +640,16 @@ class SlotKVCache:
             nxt = self._sample(logits[:, -1], rng).astype(tokens.dtype)
             choice = jnp.stack(jax.tree.leaves(upd["intermediates"]))
             counts = jnp.sum(
-                jax.nn.one_hot(choice, dm.num_experts, dtype=jnp.int32)
+                jax.nn.one_hot(choice, c.num_experts, dtype=jnp.int32)
                 * active[None, :, None, None].astype(jnp.int32), axis=(1, 2))
-            first, n = getattr(dm, "experts_held", None) \
-                or (0, dm.num_experts)
+            first, n = c.experts_held or (0, c.num_experts)
             counts = counts[:, first:first + n]
             routing = jnp.stack([jnp.sum(counts > 0), jnp.max(counts),
                                  jnp.sum(counts)])
             return (upd["cache"], jnp.where(active, nxt, tokens),
                     jnp.where(active, lengths + 1, lengths), routing)
 
-        if self.expert_layers:
+        if c.expert_layers:
             return self._jit(routed_step, "kv_decode_step_routed",
                              donate_argnums=1)
         return self._jit(step, "kv_decode_step", donate_argnums=1)
@@ -699,8 +675,8 @@ class SlotKVCache:
         A model that holds a SHARE of its experts (``experts_held``) also
         returns the (token, expert) pairs of the prompt's real tokens that
         fell to experts held here: what was computed."""
-        dm = self.dm
-        share = self.expert_layers and getattr(dm, "experts_held", None)
+        dm, c = self.dm, self.contract
+        share = c.expert_layers and c.experts_held
 
         def batched(params, cache, slot, tokens, prompt_len, rng):
             sub = jax.tree.map(
@@ -941,6 +917,7 @@ class SlotKVCache:
         elif self.active[slot] or self.reserved[slot]:
             raise RuntimeError(f"slot {slot} is active — evict it first")
         self._reset_multi_slot(slot)
+        self.last_prefill_attrs = self.contract.prefill_attrs(lp)
         return prompt, lp, slot
 
     def _reset_multi_slot(self, slot: int) -> None:
@@ -1017,15 +994,14 @@ class SlotKVCache:
         self._phase_s["prefill_s"] += time.perf_counter() - t0
         self.prefill_tokens_computed += lp
         self.prefill_tokens_padded += lpad
-        self.ssm_scan_positions += lpad * self.scan_layers
-        self.ssm_scan_tokens += lp * self.scan_layers
-        if self._pairs_rule is not None:
-            self.last_prefill_pairs = self._pairs_rule(lp)
+        c = self.contract
+        self.ssm_scan_positions += lpad * c.scan_layers
+        self.ssm_scan_tokens += lp * c.scan_layers
         if held:        # a share of the experts: the program counted
             self.expert_assignments += int(held[0])
-        elif self.expert_layers:    # every expert held: every choice
+        elif c.expert_layers:    # every expert held: every choice
             self.expert_assignments += (
-                lp * self.expert_layers * self.dm.experts_per_token)
+                lp * c.expert_layers * c.experts_per_token)
         self.active[slot] = True
         self.lengths[slot] = lp
         self.tokens[slot] = first
@@ -1126,9 +1102,8 @@ class SlotKVCache:
 
     # ------------------------------------------------------- KV handoff
     def _claim_restore_slot(self, length: int, slot: int | None) -> int:
-        """Shared restore-side validation (monolithic + paged): the
-        restored sequence must leave room to decode, exactly insert's
-        admission rule."""
+        """Restore-side validation: the restored sequence must leave room
+        to decode, exactly insert's admission rule."""
         if not 1 <= length < self.max_len:
             raise ValueError(
                 f"handoff length {length} must lie in [1, max_len="
@@ -1146,10 +1121,9 @@ class SlotKVCache:
 
     def _check_handoff_payload(self, payload: dict, block: int) -> int:
         """Transfer-format compatibility gate: a payload restores only
-        into a table with the same layout, block granularity, storage
-        dtype and max_len — anything else would reinterpret bytes."""
-        for key, want in (("layout", self.kv_layout),
-                          ("block", block),
+        into a table with the same block granularity, storage dtype and
+        max_len — anything else would reinterpret bytes."""
+        for key, want in (("block", block),
                           ("kv_dtype", self.kv_dtype),
                           ("max_len", self.max_len)):
             if payload.get(key) != want:
@@ -1186,7 +1160,7 @@ class SlotKVCache:
             entry = self._handoff_read(
                 self.cache, jnp.int32(slot), jnp.int32(start))
             blocks.append(jax.device_get(entry))
-        return {"layout": self.kv_layout, "block": hb, "length": length,
+        return {"block": hb, "length": length,
                 "token": int(self.tokens[slot]),
                 "kv_dtype": self.kv_dtype, "max_len": self.max_len,
                 "blocks": blocks}
@@ -1345,10 +1319,7 @@ class SlotKVCache:
             raise RuntimeError(
                 "a fused multi-step round is in flight — drain it before "
                 "a single-step advance (host mirrors lag the device)")
-        if self._rows_rule is not None:
-            self.last_rows_read = self._rows_rule(self.lengths, mask)
-        if self._index_rule is not None:
-            self.last_index_rows = self._index_rule(self.lengths, mask)
+        self.last_round_attrs = self.contract.round_attrs(self.lengths, mask)
         t0 = time.perf_counter()
         with self.tracer.span("step_dispatch"):
             self.cache, d_nxt, d_len, *routing = self._step(
@@ -1361,9 +1332,9 @@ class SlotKVCache:
             if routing:
                 touched, load_max, pairs = (
                     int(v) for v in np.asarray(routing[0]))
-                self.last_routing = {
-                    "experts_touched": touched / self.expert_layers,
-                    "expert_load_max": load_max}
+                self.last_round_attrs.update(
+                    experts_touched=touched / self.contract.expert_layers,
+                    expert_load_max=load_max)
                 self.expert_assignments += pairs
         self._phase_s["decode_s"] += time.perf_counter() - t0
         self.lengths[mask] += 1
@@ -1424,12 +1395,6 @@ class SlotKVCache:
 
         return self._jit(multi, f"kv_decode_multi_k{k}", donate_argnums=1)
 
-    def _multi_prepare(self, mask: np.ndarray, k: int) -> tuple:
-        """Layout hook before a fused dispatch: extra program operands
-        plus writability guarantees (the paged table overrides this to
-        cover in-flight growth and snapshot the block table)."""
-        return ()
-
     def dispatch_multi(self, k: int) -> dict:
         """Issue one fused k-iteration decode round WITHOUT materializing
         its results: the token/mask stacks start their D2H copy
@@ -1447,7 +1412,6 @@ class SlotKVCache:
         if k not in self._multis:
             self._multis[k] = self._multi(k)
         mask = self.active & ~self.halted
-        extra = self._multi_prepare(mask, k)
         self._inflight[mask] += k
         h_tok = self.tokens.astype(np.int32)
         h_len = self.lengths.astype(np.int32)
@@ -1478,7 +1442,7 @@ class SlotKVCache:
             self._put_vec(h_act), self._put_vec(h_bud),
             self._put_vec(edited),
             self._dev_cached("eos", self.eos_tok),
-            *extra, self._multi_rngs(k))
+            self._multi_rngs(k))
         self.cache = carry[0]
         self._multi_state = tuple(carry[1:])
         for arr in (toks, acts, dones):
@@ -1688,7 +1652,6 @@ class SlotKVCache:
         return {"prefill_tokens_computed": self.prefill_tokens_computed,
                 "prefill_tokens_padded": self.prefill_tokens_padded,
                 "expert_assignments": self.expert_assignments,
-                # (the paged layout counts the blocks that back live slots)
                 "cache_bytes_per_token":
                     (self.kv_bytes_per_slot()
                      - (state + rings) // self.slots) // self.max_len,
@@ -1701,17 +1664,12 @@ class SlotKVCache:
         """Stored bytes of the whole table by kind of leaf: ``(full-length
         per-position rows, per-slot state, rings)``."""
         size = {"rows": 0, "state": 0, "rings": 0}
+        c = self.contract
         for path, leaf in jax.tree_util.tree_leaves_with_path(self.cache):
-            kind = "state" if path[-1].key in self.state_leaves else \
-                "rings" if path[-1].key in self.ring_leaves else "rows"
+            kind = "state" if path[-1].key in c.slot_state else \
+                "rings" if path[-1].key in c.slot_rings else "rows"
             size[kind] += int(leaf.size) * jnp.dtype(leaf.dtype).itemsize
         return size["rows"], size["state"], size["rings"]
-
-    def past_window(self) -> int:
-        """Active slots whose length exceeds the rings' rows: whose window
-        layers no longer hold every position."""
-        ring = min(self.ring_leaves.values())
-        return int(np.sum(self.lengths[self.active] > ring))
 
     def kv_bytes_per_slot(self) -> int:
         """Stored table bytes per serving slot: every cache leaf — K/V
@@ -1766,796 +1724,4 @@ class SlotKVCache:
             "kv_live_tokens": live_tokens,
             "kv_live_bytes": live_tokens * per_tok,
             "kv_prefix_pool_blocks": len(self._prefix_pool),
-        }
-
-
-class PagedSlotKVCache(SlotKVCache):
-    """Paged KV layout (vLLM PagedAttention, arXiv:2309.06180): one
-    physical block pool shared by every slot + host-owned per-slot block
-    tables, selected by ``SlotKVCache(..., kv_layout="paged")``.
-
-    What changes vs the monolithic table:
-
-    * DEVICE: cache leaves are pools ``(num_blocks+1, kv_heads, block,
-      head_dim)`` (+1 is a scratch block — see below) instead of
-      ``(slots, max_len, ...)`` rows; the model's paged decode mode
-      (models/gpt.py ``paged_blocks``) scatters each write through the
-      block table and reads either fused (ops/paged_attention.py Pallas
-      kernel — the decode/verify hot op) or by gather + dense (bitwise
-      the monolithic math — the prefill scan).
-    * HOST: block allocation, refcounts, and the block tables.  A
-      prefix-pool hit is a POINTER WRITE — matched pool blocks are
-      aliased into the slot's table with a refcount bump and zero KV
-      bytes copied (counted in ``paged_stats``); the pool itself stores
-      block IDS with a refcount pin, so each hot prefix exists exactly
-      once in device memory.  The first write into a shared block
-      triggers copy-on-write: one jitted block copy into a freshly
-      allocated block, after which the writer owns its copy and the
-      other sharers (and the pool) are untouched.
-    * SAFETY: the pool carries one extra SCRATCH block (id
-      ``num_blocks``); unmapped table entries point at it, and during
-      decode/verify the device sees scratch-only table rows for
-      non-participating slots — the monolithic layout's "garbage writes
-      land in your own row" argument becomes "garbage writes land in
-      scratch".  Out-of-range positions are dropped by construction
-      (models/gpt.py routes them to an out-of-bounds offset, the scatter
-      drop rule).
-    * CAPACITY: ``kv_bytes_per_slot`` reports bytes actually backing
-      live sequences — in-use pool blocks (payload + scales) + block
-      tables, amortized over live slots (the BASELINE stored-bytes
-      rule) — not ``slots × max_len``.  Admission is gated by
-      ``can_admit`` (free blocks vs the request's worst-case block need
-      plus committed-but-unallocated budgets of live slots); running the
-      pool dry mid-flight raises ``BlockPoolExhausted``.
-
-    Parity contract: prefill (gather path) is bitwise the monolithic
-    prefill; fused decode/verify is tolerance-based (online-softmax
-    reassociation — the int8 precedent).  ``paged_fused=False`` keeps
-    even decode on the gather path (the parity oracle in paged clothes).
-    """
-
-    def __init__(self, model: nn.Module, params, slots: int, *,
-                 mesh=None, greedy: bool = True, temperature: float = 1.0,
-                 prefill_bucket: int = 8, rng=None, kv_dtype=None,
-                 prefix_cache_blocks: int = 0, prefix_block: int = 16,
-                 kv_layout: str = "paged", paged_blocks: int = 0,
-                 paged_block: int = 0, paged_fused: bool = True,
-                 ledger=None):
-        if kv_layout != "paged":
-            raise ValueError("PagedSlotKVCache is the kv_layout='paged' "
-                             "implementation")
-        self._ledger = ledger
-        if slots < 1:
-            raise ValueError(f"slots must be positive, got {slots}")
-        if prefix_cache_blocks < 0:
-            raise ValueError(f"prefix_cache_blocks must be >= 0, got "
-                             f"{prefix_cache_blocks}")
-        if prefix_block < 1:
-            raise ValueError(f"prefix_block must be positive, got "
-                             f"{prefix_block}")
-        self.kv_layout = "paged"
-        self.slots = int(slots)
-        self.max_len = int(model.max_len)
-        self.greedy = bool(greedy)
-        self.temperature = float(temperature)
-        self.prefill_bucket = int(prefill_bucket)
-        self.mesh = mesh
-        # ONE block granularity: aliasing a pooled prefix block into a
-        # slot's table only works when the prefix pool and the physical
-        # pool agree on the block size
-        block = int(paged_block) if paged_block else int(prefix_block)
-        if prefix_cache_blocks and paged_block \
-                and int(paged_block) != int(prefix_block):
-            raise ValueError(
-                f"paged_block ({paged_block}) must equal prefix_block "
-                f"({prefix_block}) when the prefix pool is on: pool hits "
-                f"alias physical blocks by pointer")
-        if block < 1:
-            raise ValueError(f"paged_block must be positive, got {block}")
-        if self.max_len % block:
-            raise ValueError(
-                f"paged_block={block} must divide max_len={self.max_len}")
-        self.paged_block = block
-        self.prefix_block = block
-        self.max_blocks = self.max_len // block          # table width
-        # default pool: every slot can grow to max_len (+1 block CoW
-        # headroom per slot when aliasing is possible) and the prefix
-        # pool can pin its full capacity — sized so the default NEVER
-        # exhausts; smaller explicit pools rely on can_admit deferral
-        cow_pad = 1 if prefix_cache_blocks else 0
-        self.num_blocks = int(paged_blocks) if paged_blocks else (
-            self.slots * (self.max_blocks + cow_pad)
-            + int(prefix_cache_blocks))
-        if self.num_blocks < self.max_blocks + cow_pad:
-            raise ValueError(
-                f"paged_blocks={self.num_blocks} cannot hold even one "
-                f"full slot ({self.max_blocks} blocks + {cow_pad} CoW "
-                f"headroom)")
-        self._scratch = self.num_blocks  # physical id of the scratch block
-
-        self.quantized = False
-        if kv_dtype is not None:
-            kv_dtype = jnp.dtype(kv_dtype)
-            self.quantized = kv_dtype == jnp.dtype(jnp.int8)
-        keep_tp = (mesh is not None
-                   and getattr(model, "partition_model", False)
-                   and meshlib.MODEL_AXIS in mesh.axis_names)
-        self.resumable_step = model.resumable_step
-        self.state_leaves = dict(getattr(model, "slot_state", {}))
-        self.ring_leaves = dict(getattr(model, "slot_rings", {}))
-        self.served_model = type(model).__name__    # named in a refusal
-        self.prefill_form = "scan"      # insert goes through _chunk
-        self._scan_model_only("the paged layout")
-        # fused clone for the decode/verify hot ops, gather clone for the
-        # prefill scan (bitwise-monolithic math) — same params, same
-        # cache variables, only the read path differs
-        self.paged_fused = bool(paged_fused)
-        self.dm = model.slot_decode_clone(
-            partition_model=keep_tp, kv_quant=self.quantized,
-            paged_blocks=self.num_blocks + 1, paged_block=block,
-            paged_fused=self.paged_fused, paged_mesh=mesh)
-        self.dm_gather = self.dm.clone(paged_fused=False)
-        self._rng = rng if rng is not None else jax.random.key(0)
-
-        dummy = jnp.zeros((self.slots, 1), jnp.int32)
-        shapes = jax.eval_shape(
-            lambda: self.dm.init(jax.random.key(0), dummy, train=False,
-                                 positions=dummy))["cache"]
-        cache = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), shapes)
-        if kv_dtype is not None and not self.quantized:
-            cache = jax.tree.map(
-                lambda t: t.astype(kv_dtype)
-                if jnp.issubdtype(t.dtype, jnp.floating) else t, cache)
-        self.kv_dtype = "int8" if self.quantized else next(
-            (str(leaf.dtype) for leaf in jax.tree.leaves(cache)
-             if jnp.issubdtype(leaf.dtype, jnp.floating)), "float32")
-
-        self._vec_sharding = None
-        self._blk_sharding = None
-        if mesh is not None:
-            dp = mesh.shape.get(meshlib.DATA_AXIS, 1)
-            if self.slots % dp:
-                raise ValueError(
-                    f"slots ({self.slots}) must divide by the mesh's data "
-                    f"axis ({dp}): each data shard owns a contiguous slot "
-                    f"block")
-            # pool leaves REPLICATE: any slot (sharded over 'data') may
-            # read/write any physical block, so a block-dim sharding
-            # would turn every table-indirect access into a reshard
-            repl = NamedSharding(mesh, P())
-            cache = jax.tree.map(lambda t: jax.device_put(t, repl), cache)
-            self._vec_sharding = meshlib.kv_slot_sharding(mesh, 1)
-            self._blk_sharding = meshlib.kv_slot_sharding(mesh, 2)
-        self.cache = cache
-
-        # host slot table (identical to monolithic) ...
-        self.lengths = np.zeros(self.slots, np.int32)
-        self.active = np.zeros(self.slots, np.bool_)
-        self.reserved = np.zeros(self.slots, np.bool_)
-        self.tokens = np.zeros(self.slots, np.int32)
-        self._pending: dict[int, dict] = {}
-        self._init_multi_state()
-        self.params = self._place_params(params)    # under self.tracer
-
-        # ... plus the paged substrate: refcounted physical blocks, a
-        # free list, per-slot logical→physical tables (host numpy; the
-        # device sees a masked snapshot per program call)
-        self._block_refs = np.zeros(self.num_blocks, np.int32)
-        self._free_list = list(range(self.num_blocks))[::-1]  # pop() → 0,1,..
-        self._slot_blocks: list[list[int]] = [[] for _ in range(self.slots)]
-        self.block_tables_np = np.full(
-            (self.slots, self.max_blocks), self._scratch, np.int32)
-        # committed block budgets (can_admit's outstanding ledger):
-        # worst-case blocks each live admission may still allocate
-        self._slot_need = np.zeros(self.slots, np.int32)
-        self._paged_counters = {"zero_copy_hits": 0, "zero_copy_blocks": 0,
-                                "zero_copy_tokens": 0, "cow_copies": 0}
-
-        # prefix pool: key → PHYSICAL BLOCK ID with a refcount pin (the
-        # monolithic pool stores device byte copies; here the pool IS
-        # the aliasing table — zero bytes stored twice)
-        self.prefix_cache_blocks = int(prefix_cache_blocks)
-        self._prefix_pool: OrderedDict[bytes, int] = OrderedDict()
-        self.prefix_stats = {"hits": 0, "misses": 0, "evictions": 0,
-                             "tokens_reused": 0, "inserted_blocks": 0}
-
-        self.prefill_tokens_computed = 0
-        self.prefill_tokens_padded = 0
-        self._phase_s = {"prefill_s": 0.0, "decode_s": 0.0}
-
-        self._step = self._build_step()
-        self._prefills: dict[int, object] = {}   # unused: paged admission
-        self._chunks: dict[int, object] = {}     # always chunks
-        self._verifies: dict[int, object] = {}
-        self._read_block = None                  # monolithic pool programs
-        self._write_block = None                 # never built under paged
-        self._copy_block = None                  # CoW block copy (lazy)
-        self._handoff_read = None                # disagg KV handoff (lazy)
-        self._handoff_write = None
-
-    # -------------------------------------------------- block bookkeeping
-    @property
-    def blocks_in_use(self) -> int:
-        """Allocated physical blocks (scratch excluded)."""
-        return self.num_blocks - len(self._free_list)
-
-    def _alloc_block(self) -> int:
-        if not self._free_list:
-            raise BlockPoolExhausted(
-                f"paged KV pool exhausted: all {self.num_blocks} blocks "
-                f"in use — the scheduler's can_admit gate should have "
-                f"deferred this admission (block budget accounting bug, "
-                f"or the pool was sized below slots × max_len/block)")
-        bid = self._free_list.pop()
-        self._block_refs[bid] = 1
-        return bid
-
-    def _release_block(self, bid: int) -> None:
-        self._block_refs[bid] -= 1
-        if self._block_refs[bid] == 0:
-            self._free_list.append(bid)
-
-    def _release_slot_blocks(self, slot: int) -> None:
-        for bid in self._slot_blocks[slot]:
-            self._release_block(bid)
-        self._slot_blocks[slot].clear()
-        self.block_tables_np[slot, :] = self._scratch
-        self._slot_need[slot] = 0
-
-    def _build_copy(self):
-        def copy(cache, src, dst):
-            return jax.tree.map(
-                lambda t: lax.dynamic_update_slice(
-                    t, lax.dynamic_slice(
-                        t, (src,) + (0,) * (t.ndim - 1),
-                        (1,) + t.shape[1:]),
-                    (dst,) + (0,) * (t.ndim - 1)), cache)
-
-        return self._jit(copy, "kv_paged_cow_copy", donate_argnums=0)
-
-    def _ensure_writable(self, slot: int, start: int, end: int) -> None:
-        """Make positions ``[start, end)`` of ``slot`` safely writable:
-        allocate missing blocks, copy-on-write shared ones.  A shared
-        block (refcount > 1 — aliased from the prefix pool or pinned BY
-        it) gets one jitted block copy into a fresh allocation; the
-        slot's table then points at its private copy and every other
-        sharer keeps reading the original."""
-        if end <= start:
-            return
-        sb = self._slot_blocks[slot]
-        blk = self.paged_block
-        last = min((end - 1) // blk, self.max_blocks - 1)
-        for j in range(start // blk, last + 1):
-            while len(sb) <= j:      # extend coverage with fresh blocks
-                bid = self._alloc_block()
-                sb.append(bid)
-                self.block_tables_np[slot, len(sb) - 1] = bid
-            bid = sb[j]
-            if self._block_refs[bid] > 1:   # shared → copy-on-write
-                if self._copy_block is None:
-                    self._copy_block = self._build_copy()
-                new = self._alloc_block()
-                self.cache = self._copy_block(
-                    self.cache, jnp.int32(bid), jnp.int32(new))
-                self._release_block(bid)
-                sb[j] = new
-                self.block_tables_np[slot, j] = new
-                self._paged_counters["cow_copies"] += 1
-
-    def _masked_bt(self, mask):
-        """Device block-table snapshot with non-participating rows routed
-        wholly to scratch — their garbage scatter writes can never land
-        in a live (possibly shared) block.  Value-cached like the slot
-        vectors: an unchanged table re-uploads nothing."""
-        bt = np.where(np.asarray(mask, np.bool_)[:, None],
-                      self.block_tables_np, np.int32(self._scratch))
-        return self._dev_cached("bt", bt.astype(np.int32),
-                                put=self._put_repl)
-
-    # ------------------------------------------------- admission budgets
-    def _block_need(self, total_len: int) -> int:
-        need = -(-int(total_len) // self.paged_block)
-        if self.prefix_cache_blocks:
-            need += 1   # CoW headroom: a fully-aligned prefix hit
-                        # recomputes its last token INTO a shared block
-        return min(need, self.max_blocks + (1 if self.prefix_cache_blocks
-                                            else 0))
-
-    def can_admit(self, prompt_len: int, max_new_tokens: int) -> bool:
-        """Block-exhaustion admission gate: free blocks (minus what live
-        admissions may still claim under their registered budgets) must
-        cover this request's worst-case need.  Conservative — aliasing
-        only helps (aliased blocks never touch the free list)."""
-        outstanding = sum(
-            max(int(self._slot_need[s]) - len(self._slot_blocks[s]), 0)
-            for s in range(self.slots) if self._slot_need[s])
-        need = self._block_need(int(prompt_len) + int(max_new_tokens))
-        return len(self._free_list) - outstanding >= need
-
-    def note_admission(self, slot: int, total_len: int) -> None:
-        """Register an admitted request's worst-case block budget (the
-        scheduler calls this with prompt + max_new_tokens); cleared on
-        evict/abort."""
-        self._slot_need[slot] = self._block_need(total_len)
-
-    # ------------------------------------------------------------ programs
-    def _build_step(self):
-        dm = self.dm
-
-        def step(params, cache, tokens, lengths, active, bt, rng):
-            logits, upd = dm.apply(
-                {"params": params, "cache": cache}, tokens[:, None],
-                train=False, positions=lengths[:, None],
-                block_tables=bt, mutable=["cache"])
-            nxt = self._sample(logits[:, -1], rng).astype(tokens.dtype)
-            return (upd["cache"], jnp.where(active, nxt, tokens),
-                    jnp.where(active, lengths + 1, lengths))
-
-        return self._jit(step, "kv_paged_decode_step", donate_argnums=1)
-
-    def _chunk(self, lpad: int):
-        """Chunk-resumable prefill over the FULL pool (there is no
-        per-slot cache slice to extract — the slot's identity is its
-        block table row): same scan/positions/sampling contract as the
-        monolithic ``_chunk``, gather read path (bitwise-monolithic
-        math over the gathered table)."""
-        dm = self.dm_gather
-
-        def chunk(params, cache, bt_row, tokens, start, n_valid, rng):
-            def body(c, xs):
-                tok, t = xs
-                logits, upd = dm.apply(
-                    {"params": params, "cache": c}, tok[None, None],
-                    train=False, positions=t[None, None],
-                    block_tables=bt_row, mutable=["cache"])
-                return upd["cache"], logits[0, -1]
-
-            cache, all_logits = lax.scan(
-                body, cache,
-                (tokens, start + jnp.arange(lpad, dtype=jnp.int32)))
-            last = jnp.take(all_logits, n_valid - 1, axis=0)
-            first = self._sample(last[None, :], rng)[0]
-            return cache, first.astype(tokens.dtype)
-
-        return self._jit(chunk, f"kv_paged_prefill_chunk_l{lpad}",
-                         donate_argnums=1)
-
-    def _verify(self, width: int):
-        dm = self.dm
-
-        def verify(params, cache, block, lengths, bt):
-            positions = (lengths[:, None]
-                         + jnp.arange(width, dtype=jnp.int32)[None, :])
-            logits, upd = dm.apply(
-                {"params": params, "cache": cache}, block,
-                train=False, positions=positions, block_tables=bt,
-                mutable=["cache"])
-            return upd["cache"], logits.argmax(-1).astype(block.dtype)
-
-        return self._jit(verify, f"kv_paged_verify_w{width}",
-                         donate_argnums=1)
-
-    # ------------------------------------------------------------ slot API
-    def insert(self, prompt, slot: int | None = None) -> tuple[int, int]:
-        """Paged admission ALWAYS routes through the chunk-resumable
-        program (begin_insert + one uncapped chunk): there is no
-        slice-out monolithic prefill over a shared pool, and chunked
-        admission is the path whose writes go through
-        ``_ensure_writable`` (allocation + CoW)."""
-        slot, _ = self.begin_insert(prompt, slot)
-        try:
-            first = self.prefill_chunk(slot)
-        except BaseException:
-            if self.has_pending(slot):
-                self.abort_insert(slot)
-            elif self.active[slot]:
-                self.evict(slot)
-            raise
-        assert first is not None
-        return slot, first
-
-    def prefill_chunk(self, slot: int,
-                      max_tokens: int | None = None) -> int | None:
-        pend = self._pending.get(slot)
-        if pend is None:
-            raise RuntimeError(f"slot {slot} has no pending admission "
-                               f"(begin_insert first)")
-        filled, lp = pend["filled"], pend["lp"]
-        n = lp - filled
-        if max_tokens is not None:
-            if max_tokens < 1:
-                raise ValueError(
-                    f"max_tokens must be positive, got {max_tokens}")
-            n = min(n, int(max_tokens))
-        final = filled + n == lp
-        # allocation + CoW BEFORE the program runs: the scan's writes
-        # must only ever land in private (or scratch) blocks
-        self._ensure_writable(slot, filled, filled + n)
-        lpad = _bucket(n, 1, self.max_len)
-        padded = np.zeros(lpad, np.int32)
-        padded[:n] = pend["prompt"][filled:filled + n]
-        if lpad not in self._chunks:
-            self._chunks[lpad] = self._chunk(lpad)
-        bt_row = self._put_repl(
-            self.block_tables_np[slot:slot + 1].astype(np.int32))
-        t0 = time.perf_counter()
-        self.cache, first = self._chunks[lpad](
-            self.params, self.cache, bt_row,
-            self._put_repl(padded), jnp.int32(filled), jnp.int32(n),
-            self._next_rng())
-        if final:
-            first = int(first)   # inside the timed region, as above
-        self._phase_s["prefill_s"] += time.perf_counter() - t0
-        pend["filled"] = filled + n
-        self.lengths[slot] = filled + n
-        self.prefill_tokens_computed += n
-        self.prefill_tokens_padded += lpad
-        if not final:
-            return None
-        del self._pending[slot]
-        self.reserved[slot] = False
-        self.active[slot] = True
-        self.lengths[slot] = lp
-        self.tokens[slot] = first
-        self._pool_prefix(pend["prompt"], lp, slot)
-        return first
-
-    def abort_insert(self, slot: int) -> None:
-        super().abort_insert(slot)
-        self._release_slot_blocks(slot)
-
-    def evict(self, slot: int) -> None:
-        if not self.active[slot]:
-            raise RuntimeError(f"slot {slot} is not active")
-        self._release_slot_blocks(slot)
-        self.active[slot] = False
-        self.lengths[slot] = 0
-        self.tokens[slot] = 0
-        self._reset_multi_slot(slot)
-
-    # ------------------------------------------------------- KV handoff
-    def _handoff_block(self) -> int:
-        """Paged handoff granularity IS the physical block: the transfer
-        format serializes whole pool blocks by id, so block size and
-        table block size agree by construction."""
-        return self.paged_block
-
-    def _handoff_ops(self):
-        """Physical-block handoff programs (``_build_copy``'s slicing
-        aimed across tables instead of within one): ``read`` slices one
-        physical block out of every pool leaf, ``write`` scatters a
-        transferred block into a freshly-allocated block of the
-        receiving pool.  Block ids are traced — one compile each."""
-        def read(cache, bid):
-            return jax.tree.map(
-                lambda t: lax.dynamic_slice(
-                    t, (bid,) + (0,) * (t.ndim - 1),
-                    (1,) + t.shape[1:]), cache)
-
-        def write(cache, entry, bid):
-            return jax.tree.map(
-                lambda t, e: lax.dynamic_update_slice(
-                    t, e.astype(t.dtype), (bid,) + (0,) * (t.ndim - 1)),
-                cache, entry)
-
-        return (self._jit(read, "kv_handoff_read_block"),
-                self._jit(write, "kv_handoff_write_block",
-                          donate_argnums=0))
-
-    def extract_handoff(self, slot: int) -> dict:
-        """Paged extract: serialize the physical blocks backing the
-        slot's first ``ceil(length/block)`` table entries (aliased
-        prefix blocks serialize like private ones — the payload is
-        self-contained, the receiving pool shares nothing with ours)."""
-        if not self.active[slot]:
-            raise RuntimeError(f"slot {slot} is not active")
-        if self._handoff_read is None:
-            self._handoff_read, self._handoff_write = self._handoff_ops()
-        length = int(self.lengths[slot])
-        blk = self.paged_block
-        n = -(-length // blk)
-        sb = self._slot_blocks[slot]
-        if len(sb) < n:
-            raise RuntimeError(
-                f"slot {slot} block table covers {len(sb)} blocks but "
-                f"length {length} needs {n} — block bookkeeping bug")
-        blocks = []
-        for bid in sb[:n]:
-            entry = self._handoff_read(self.cache, jnp.int32(bid))
-            blocks.append(jax.device_get(entry))
-        return {"layout": "paged", "block": blk, "length": length,
-                "token": int(self.tokens[slot]),
-                "kv_dtype": self.kv_dtype, "max_len": self.max_len,
-                "blocks": blocks}
-
-    def restore_handoff(self, payload: dict,
-                        slot: int | None = None) -> tuple[int, int]:
-        """Paged restore: allocate the covering blocks, scatter the
-        payload in, point the slot's table at them.  Failure anywhere —
-        pool exhausted mid-allocation, a device error mid-write —
-        releases every block this restore claimed before re-raising, so
-        a failed handoff admission cannot leak pool blocks."""
-        blk = self.paged_block
-        length = self._check_handoff_payload(payload, blk)
-        n = -(-length // blk)
-        if len(payload["blocks"]) != n:
-            raise ValueError(
-                f"handoff payload carries {len(payload['blocks'])} blocks "
-                f"but length {length} needs {n}")
-        slot = self._claim_restore_slot(length, slot)
-        if self._handoff_write is None:
-            self._handoff_read, self._handoff_write = self._handoff_ops()
-        sb = self._slot_blocks[slot]
-        try:
-            for j, entry in enumerate(payload["blocks"]):
-                bid = self._alloc_block()
-                sb.append(bid)
-                self.block_tables_np[slot, j] = bid
-                entry = jax.tree.map(self._put_repl, entry)
-                self.cache = self._handoff_write(
-                    self.cache, entry, jnp.int32(bid))
-        except BaseException:
-            # slot is still inactive — releasing its blocks restores the
-            # pool exactly (refcounts were 1: nothing aliased a block
-            # that never finished arriving)
-            self._release_slot_blocks(slot)
-            raise
-        self.active[slot] = True
-        self.lengths[slot] = length
-        self.tokens[slot] = token = int(payload["token"])
-        return slot, token
-
-    # ------------------------------------------------------- prefix pool
-    def _restore_prefix(self, prompt: np.ndarray, lp: int,
-                        slot: int) -> int:
-        """The zero-copy hit: matched pool blocks are aliased into the
-        slot's block table (pointer writes + refcount bumps) — no device
-        traffic at all.  Reuse covers FULL blocks including the one
-        holding the prompt's final token (unlike the monolithic
-        ``(lp-1)//blk`` cap): the final token is still recomputed (reuse
-        is capped at ``lp - 1`` positions), and its write into the
-        shared last block is what exercises copy-on-write."""
-        if not self.prefix_cache_blocks:
-            return 0
-        blk = self.prefix_block
-        usable = lp // blk
-        keys = self._prefix_keys(prompt, usable)
-        matched = 0
-        for key in keys:
-            if key not in self._prefix_pool:
-                break
-            matched += 1
-        reused = min(matched * blk, lp - 1)
-        self.prefix_stats["hits"] += matched
-        self.prefix_stats["misses"] += usable - matched
-        self.prefix_stats["tokens_reused"] += reused
-        if not matched:
-            return 0
-        sb = self._slot_blocks[slot]
-        for b, key in enumerate(keys[:matched]):
-            self._prefix_pool.move_to_end(key)   # LRU touch
-            bid = self._prefix_pool[key]
-            self._block_refs[bid] += 1
-            sb.append(bid)
-            self.block_tables_np[slot, b] = bid
-        self._paged_counters["zero_copy_hits"] += 1
-        self._paged_counters["zero_copy_blocks"] += matched
-        self._paged_counters["zero_copy_tokens"] += reused
-        return reused
-
-    def _pool_prefix(self, prompt: np.ndarray, lp: int, slot: int) -> None:
-        """Pool = pin: every full prompt block not already pooled gets a
-        refcount pin on the slot's OWN physical block (no extraction, no
-        copy — the pool and the slot share the block until eviction
-        drops the slot's reference)."""
-        if not self.prefix_cache_blocks:
-            return
-        blk = self.prefix_block
-        sb = self._slot_blocks[slot]
-        for b, key in enumerate(self._prefix_keys(prompt, lp // blk)):
-            if key in self._prefix_pool:
-                self._prefix_pool.move_to_end(key)
-                continue
-            bid = sb[b]
-            self._block_refs[bid] += 1          # the pool's pin
-            self._prefix_pool[key] = bid
-            self.prefix_stats["inserted_blocks"] += 1
-        while len(self._prefix_pool) > self.prefix_cache_blocks:
-            _, bid = self._prefix_pool.popitem(last=False)
-            self._release_block(bid)
-            self.prefix_stats["evictions"] += 1
-
-    def reset_prefix_cache(self) -> None:
-        while self._prefix_pool:
-            _, bid = self._prefix_pool.popitem(last=False)
-            self._release_block(bid)
-        for k in self.prefix_stats:
-            self.prefix_stats[k] = 0
-        for k in self._paged_counters:
-            self._paged_counters[k] = 0
-
-    # ------------------------------------------------------------- decode
-    def advance(self, only=None) -> np.ndarray:
-        mask = self.active if only is None else np.asarray(only, np.bool_)
-        live = self.lengths[mask]
-        if live.size and int(live.max()) >= self.max_len:
-            raise SlotOverflow(
-                f"active slot at length {int(live.max())} would write past "
-                f"max_len={self.max_len}; the scheduler must bound "
-                f"prompt + max_new_tokens at admission")
-        if self._multi_pending:
-            raise RuntimeError(
-                "a fused multi-step round is in flight — drain it before "
-                "a single-step advance (host mirrors lag the device)")
-        for slot in np.flatnonzero(mask):
-            pos = int(self.lengths[slot])
-            self._ensure_writable(int(slot), pos, pos + 1)
-        t0 = time.perf_counter()
-        with self.tracer.span("step_dispatch"):
-            self.cache, d_nxt, d_len = self._step(
-                self.params, self.cache,
-                self._dev_cached("tokens", self.tokens),
-                self._dev_cached("lengths", self.lengths),
-                self._dev_cached("mask", mask), self._masked_bt(mask),
-                self._next_rng())
-        with self.tracer.span("token_fetch"):
-            nxt = np.asarray(d_nxt)
-        self._phase_s["decode_s"] += time.perf_counter() - t0
-        self.lengths[mask] += 1
-        self.tokens = nxt.astype(np.int32)
-        self._dev_learn("tokens", self.tokens, d_nxt)
-        self._dev_learn("lengths", self.lengths, d_len)
-        return nxt
-
-    # ------------------------------------------------- multi-step decode
-    def _multi(self, k: int):
-        """Paged fused k-iteration decode: the monolithic scan with the
-        masked block-table operand threaded through every step.  The
-        table is a DISPATCH-TIME snapshot: `_multi_prepare` pre-extends
-        each slot's coverage for all in-flight growth, and a slot the
-        device deactivates keeps scattering at its frozen length — into
-        its own covered block (overwritten before any read: validity is
-        length-driven) or past the snapshot's coverage, which routes to
-        the scratch block."""
-        dm = self.dm
-        max_len = self.max_len
-
-        def multi(params, cache, d_tok, d_len, d_act, d_bud,
-                  h_tok, h_len, h_act, h_bud, edited, eos, bt, rngs):
-            tokens = jnp.where(edited, h_tok, d_tok)
-            lengths = jnp.where(edited, h_len, d_len)
-            active = jnp.where(edited, h_act, d_act)
-            budget = jnp.where(edited, h_bud, d_bud)
-
-            def body(carry, rng):
-                cache, tokens, lengths, active, budget = carry
-                logits, upd = dm.apply(
-                    {"params": params, "cache": cache}, tokens[:, None],
-                    train=False, positions=lengths[:, None],
-                    block_tables=bt, mutable=["cache"])
-                nxt = self._sample(logits[:, -1],
-                                   rng).astype(tokens.dtype)
-                nxt = jnp.where(active, nxt, tokens)
-                nlen = jnp.where(active, lengths + 1, lengths)
-                nbud = jnp.where(active & (budget > 0),
-                                 budget - 1, budget)
-                done = active & ((nxt == eos)
-                                 | ((budget > 0) & (nbud <= 0))
-                                 | (nlen >= max_len))
-                return ((upd["cache"], nxt, nlen, active & ~done, nbud),
-                        (nxt, active, done))
-
-            carry, (toks, acts, dones) = lax.scan(
-                body, (cache, tokens, lengths, active, budget), rngs)
-            return carry, toks, acts, dones
-
-        return self._jit(multi, f"kv_paged_decode_multi_k{k}",
-                         donate_argnums=1)
-
-    def _multi_prepare(self, mask: np.ndarray, k: int) -> tuple:
-        """Cover every masked slot's worst-case in-flight growth —
-        already-dispatched undrained rounds (``_inflight``) plus this
-        round's k — so no fused write can land outside the slot's own
-        blocks, then snapshot the masked block table as the program's
-        extra operand."""
-        for slot in np.flatnonzero(mask):
-            start = int(self.lengths[slot])
-            end = min(start + int(self._inflight[slot]) + k, self.max_len)
-            self._ensure_writable(int(slot), start, end)
-        return (self._masked_bt(mask),)
-
-    def verify_block(self, block) -> np.ndarray:
-        if not self.greedy:
-            raise ValueError(
-                "verify_block requires greedy sampling: the exact "
-                "acceptance rule (accept while draft == target argmax) "
-                "only exists for greedy decode")
-        block = np.asarray(block, np.int32)
-        if block.ndim != 2 or block.shape[0] != self.slots:
-            raise ValueError(
-                f"block must be (slots, width) = ({self.slots}, k+1), "
-                f"got {block.shape}")
-        width = int(block.shape[1])
-        live = self.lengths[self.active]
-        if live.size and int(live.max()) + width > self.max_len:
-            raise SlotOverflow(
-                f"verify width {width} at length {int(live.max())} would "
-                f"write past max_len={self.max_len}; the scheduler must "
-                f"cap the draft k by remaining slot capacity")
-        for slot in np.flatnonzero(self.active):
-            pos = int(self.lengths[slot])
-            self._ensure_writable(int(slot), pos, pos + width)
-        if width not in self._verifies:
-            self._verifies[width] = self._verify(width)
-        blk = jnp.asarray(block)
-        if self._blk_sharding is not None:
-            blk = jax.device_put(blk, self._blk_sharding)
-        t0 = time.perf_counter()
-        self.cache, g = self._verifies[width](
-            self.params, self.cache, blk,
-            self._dev_cached("lengths", self.lengths),
-            self._masked_bt(self.active))
-        g = np.asarray(g).astype(np.int32)
-        self._phase_s["decode_s"] += time.perf_counter() - t0
-        return g
-
-    # --------------------------------------------------------- accounting
-    def kv_bytes_per_slot(self) -> int:
-        """HONEST paged capacity (the BASELINE stored-bytes rule): bytes
-        actually backing live sequences — allocated pool blocks (K/V
-        payload + int8 scales) plus the block tables — amortized over
-        live (active or reserved) slots.  With nothing live this is the
-        pool-warmth floor: whatever the prefix pool still pins, plus the
-        tables.  The monolithic ``slots × max_len`` formula would claim
-        capacity the pool never allocated."""
-        per_block = sum(
-            (int(leaf.size) // leaf.shape[0])
-            * jnp.dtype(leaf.dtype).itemsize
-            for leaf in jax.tree.leaves(self.cache))
-        table_bytes = self.block_tables_np.nbytes
-        live = int(self.active.sum()) + int(self.reserved.sum())
-        return (self.blocks_in_use * per_block
-                + table_bytes) // max(live, 1)
-
-    def paged_stats(self) -> dict:
-        """Pool utilization + the zero-copy/CoW ledger (cumulative; the
-        scheduler reads counter deltas per run)."""
-        return {"num_blocks": self.num_blocks,
-                "block": self.paged_block,
-                "blocks_in_use": self.blocks_in_use,
-                "utilization": self.blocks_in_use / self.num_blocks,
-                **dict(self._paged_counters)}
-
-    def compiled_programs(self) -> dict[str, int]:
-        """Paged program inventory: ONE decode step, one chunk program
-        per bucket (admission always chunks — there is no monolithic
-        slice-out prefill over a shared pool), no prefix block-copy
-        programs (hits are pointer writes), one verify program per
-        width, plus at most one CoW block copy."""
-        out = super().compiled_programs()
-        out["paged_block_copies"] = 0 if self._copy_block is None else 1
-        return out
-
-    def timeline_gauges(self) -> dict[str, float]:
-        """Paged gauge snapshot: the base slot-table gauges plus pool
-        occupancy/refcounts, all host numpy — no device syncs.  Under
-        paging ``kv_live_bytes`` is block-backed: allocated blocks ×
-        stored bytes per block (aliased blocks counted once, exactly the
-        zero-copy saving the pool exists for)."""
-        per_block = getattr(self, "_tl_block_bytes", None)
-        if per_block is None:
-            per_block = self._tl_block_bytes = sum(
-                (int(leaf.size) // leaf.shape[0])
-                * jnp.dtype(leaf.dtype).itemsize
-                for leaf in jax.tree.leaves(self.cache))
-        live_tokens = int(self.lengths.sum())
-        return {
-            "kv_active_slots": int(self.active.sum()),
-            "kv_reserved_slots": int(self.reserved.sum()),
-            "kv_live_tokens": live_tokens,
-            "kv_live_bytes": self.blocks_in_use * per_block,
-            "kv_prefix_pool_blocks": len(self._prefix_pool),
-            "kv_blocks_in_use": self.blocks_in_use,
-            "kv_pool_refcount_total": int(self._block_refs.sum()),
-            "kv_free_blocks": len(self._free_list),
         }

@@ -519,10 +519,7 @@ class ContinuousBatcher:
                 slot, first = kv.insert(req.prompt)
                 sp["padded_len"] = kv.prefill_tokens_padded - padded
                 sp["slot"] = slot
-                if kv.ring_leaves:      # rows written into each ring
-                    sp["ring_rows"] = min(lp, *kv.ring_leaves.values())
-                if kv.last_prefill_pairs is not None:   # an indexer's
-                    sp.update(kv.last_prefill_pairs)
+                sp.update(kv.last_prefill_attrs)    # what the model reports
             self.clock.on_prefill(kv.prefill_tokens_computed - before)
             if self._rf_cost is not None:
                 # credit only positions actually computed: a prefix-cache
@@ -533,10 +530,6 @@ class ContinuousBatcher:
                 self._rf_prefill_flops += (
                     self._rf_cost.prefill_chunk_flops(done, lp - done)
                     + self._rf_cost.lm_head_flops)
-        if hasattr(kv, "note_admission"):
-            # register the paged block budget (prompt + decode growth) so
-            # can_admit's outstanding ledger covers this slot's worst case
-            kv.note_admission(slot, lp + req.max_new_tokens)
         if self.handoff_out is not None:
             # prefill role: the finished slot's KV leaves for a decode
             # replica — no local decode, no local token delivery (the
@@ -570,8 +563,6 @@ class ContinuousBatcher:
         req_span = tracer.begin("request", rid=req.rid, prompt_len=lp,
                                 max_new_tokens=req.max_new_tokens)
         slot, reused = kv.begin_insert(req.prompt)
-        if hasattr(kv, "note_admission"):
-            kv.note_admission(slot, lp + req.max_new_tokens)
         pending[slot] = {"req": req, "span": req_span, "lp": lp,
                          "admitted_s": t_claim, "reused": reused,
                          "queue_wait_s": t_claim - req.arrival_s}
@@ -821,24 +812,6 @@ class ContinuousBatcher:
             req = queue.pop_ready(clock.now())
             if req is None:
                 break
-            # paged block-exhaustion gate: a free SLOT is not enough
-            # when the kv is a block pool — the request's worst-case
-            # block need (prompt + max_new_tokens, plus live slots'
-            # committed budgets) must fit the free list.  Deferral
-            # pushes the request back (FIFO by arrival is preserved:
-            # the queue re-sorts) until decode completions release
-            # blocks.  With NOTHING in flight the pool is as free as
-            # it will ever get, so deferring would busy-spin — admit
-            # and let BlockPoolExhausted surface the impossible
-            # configuration instead.
-            if (hasattr(kv, "can_admit") and (live or pending)
-                    and not kv.can_admit(
-                        int(np.asarray(req.prompt).reshape(-1)
-                            .shape[0]),
-                        req.max_new_tokens)):
-                queue.push(req)
-                self._block_deferrals += 1
-                break
             if self.prefill_chunk:
                 self._begin_admit(req, pending)
             else:
@@ -1065,15 +1038,8 @@ class ContinuousBatcher:
             # the decode step, and the gap to the next one is the stall
             with self.tracer.span("decode_step", active=len(live),
                                   slots=kv.slots) as sp:
-                if kv.ring_leaves:
-                    sp["past_window"] = kv.past_window()
                 toks = kv.advance()
-                if kv.last_routing is not None:     # a model with experts
-                    sp.update(kv.last_routing)
-                if kv.last_rows_read is not None:   # GPT-2's prefix
-                    sp["rows_read"] = kv.last_rows_read
-                if kv.last_index_rows is not None:  # an indexer's rows
-                    sp.update(kv.last_index_rows)
+                sp.update(kv.last_round_attrs)      # what the model reports
             return {slot: [int(toks[slot])] for slot in live}
         return self._spec_round(live, k_eff)
 
@@ -1224,7 +1190,6 @@ class ContinuousBatcher:
         self._registry = MetricsRegistry()
         self._shed_count = 0
         self._shed_rids: list[int] = []
-        self._block_deferrals = 0   # paged pool admission deferrals
         self._preempted: str | None = None
         # speculative-decode ledger (zeros when no draft is attached):
         # conservation is exact — accepted + rejected == proposed
@@ -1247,11 +1212,6 @@ class ContinuousBatcher:
         prefix_before = self.kv.prefix_cache_stats()
         prefill_before = self.kv.prefill_tokens_computed
         phases_before = self.kv.phase_times()
-        # paged-pool counter snapshot (zero-copy/CoW are cumulative on the
-        # kv — windows may share one pool — so the summary reports
-        # deltas over THIS run, like the prefix-pool ledger above)
-        paged_before = (self.kv.paged_stats()
-                        if hasattr(self.kv, "paged_stats") else None)
         # host-dispatch ledger (multi-step accounting): compiled-program
         # host calls as a delta over this run, and the REAL wall clock —
         # clock.now() may be virtual, but the host gap the multi-step
@@ -1312,27 +1272,6 @@ class ContinuousBatcher:
             prefix_sec["cached_blocks"] = prefix_after["cached_blocks"]
             asked = prefix_sec["hits"] + prefix_sec["misses"]
             hit_rate = prefix_sec["hits"] / asked if asked else 0.0
-        # paged-pool accounting: utilization is CURRENT pool state
-        # (blocks still backing live/pinned data), the zero-copy/CoW
-        # ledger is the delta over this run.  zero-copy hit rate = aliased
-        # blocks over blocks asked of the prefix pool — the fraction of
-        # reusable prefix KV shared by POINTER instead of copied.
-        paged_sec = zero_copy_rate = None
-        if paged_before is not None:
-            paged_after = self.kv.paged_stats()
-            paged_sec = {
-                k: paged_after[k] - paged_before.get(k, 0)
-                for k in ("zero_copy_hits", "zero_copy_blocks",
-                          "zero_copy_tokens", "cow_copies")}
-            paged_sec["num_blocks"] = paged_after["num_blocks"]
-            paged_sec["block"] = paged_after["block"]
-            paged_sec["blocks_in_use"] = paged_after["blocks_in_use"]
-            paged_sec["utilization"] = paged_after["utilization"]
-            paged_sec["block_deferrals"] = self._block_deferrals
-            if prefix_sec is not None:
-                asked = prefix_sec["hits"] + prefix_sec["misses"]
-                zero_copy_rate = (paged_sec["zero_copy_blocks"] / asked
-                                  if asked else 0.0)
         summary = {
             "mode": self.mode,
             "requests": len(results),
@@ -1347,19 +1286,6 @@ class ContinuousBatcher:
             # the served tree as the table holds it (each leaf in the
             # dtype the step uses it in: SlotKVCache._place_params)
             "serve_param_bytes": getattr(self.kv, "param_bytes", None),
-            # --serve-kv-layout: paged pool accounting (None/0 under
-            # monolithic — the keys are always present so `analyze diff`
-            # gates them when both runs page).  blocks_in_use is gated
-            # lower (fewer physical blocks for the same streams = the
-            # aliasing working), zero-copy rate higher.
-            "serve_kv_layout": getattr(self.kv, "kv_layout", "monolithic"),
-            "serve_kv_blocks_in_use": (paged_sec["blocks_in_use"]
-                                       if paged_sec else None),
-            "serve_kv_block_utilization": (paged_sec["utilization"]
-                                           if paged_sec else None),
-            "serve_prefix_zero_copy_hit_rate": zero_copy_rate,
-            "serve_kv_block_deferrals": self._block_deferrals,
-            "paged": paged_sec,
             # speculative decoding (draft-k → verify-1): accept rate over
             # THIS run's proposals (None: no draft attached — the key is
             # always present so `analyze diff` gates it when both runs
@@ -1460,8 +1386,6 @@ class ContinuousBatcher:
             tag = self.timeline_tag
             summary["queue_depth_auc"] = self.timeline.stat(
                 "queue_depth", "auc", replica=tag)
-            summary["kv_blocks_in_use_p95"] = self.timeline.stat(
-                "kv_blocks_in_use", "p95", replica=tag)
             summary["timeline_overhead_s"] = self.timeline.overhead_s
         if self.roofline is not None:
             # --roofline keys ride ONLY when a Roofline is attached: the

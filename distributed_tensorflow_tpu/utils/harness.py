@@ -351,33 +351,6 @@ class ExperimentConfig:
                                            # greedy tokens unchanged (the
                                            # swapped-in weights are the
                                            # same trained params)
-    serve_kv_layout: str = "monolithic"    # --serve-kv-layout paged: the KV
-                                           # table becomes a refcounted
-                                           # physical block pool + per-slot
-                                           # block tables (PagedSlotKVCache
-                                           # — vLLM PagedAttention): prefix
-                                           # hits alias blocks zero-copy,
-                                           # CoW isolates writers, decode
-                                           # reads fused through the Pallas
-                                           # paged kernel (tolerance-based
-                                           # token parity, the int8
-                                           # precedent).  'monolithic'
-                                           # keeps the per-slot rows and a
-                                           # byte-identical program set
-    serve_paged_block: int = 0             # tokens per physical KV block
-                                           # under paged (0: inherit
-                                           # serve_prefix_block — the two
-                                           # MUST agree when the prefix
-                                           # pool is on: hits alias
-                                           # physical blocks by pointer)
-    serve_paged_blocks: int = 0            # physical block-pool capacity
-                                           # under paged (0: auto-size so
-                                           # slots*max_len + prefix pool
-                                           # always fit — never exhausts);
-                                           # explicit smaller pools defer
-                                           # admissions when the free list
-                                           # cannot cover a request's
-                                           # worst-case block need
     serve_disaggregate: str | None = None  # 'P:D': disaggregated fleet —
                                            # P prefill replicas (admission
                                            # + chunked prefill, then a
@@ -2343,36 +2316,6 @@ def _validate_serving(config: ExperimentConfig, ex: _Experiment,
         parse_draft_config(config.serve_draft_config)
     if config.serve_kv_dtype:
         _resolve_serve_kv_dtype(config.serve_kv_dtype)
-    if config.serve_kv_layout not in ("monolithic", "paged"):
-        raise ValueError(
-            f"--serve-kv-layout must be 'monolithic' or 'paged', got "
-            f"{config.serve_kv_layout!r}")
-    if config.serve_paged_block < 0 or config.serve_paged_blocks < 0:
-        raise ValueError(
-            f"--serve-paged-block/--serve-paged-blocks must be >= 0, got "
-            f"{config.serve_paged_block}/{config.serve_paged_blocks}")
-    if config.serve_kv_layout != "paged" and (config.serve_paged_block
-                                              or config.serve_paged_blocks):
-        raise ValueError(
-            "--serve-paged-block/--serve-paged-blocks need "
-            "--serve-kv-layout paged")
-    if config.serve_kv_layout == "paged":
-        # the paged pool's fatal misconfigurations are all knowable
-        # pre-train: block granularity must tile max_len, and with the
-        # prefix pool on it must equal the prefix block (hits alias
-        # physical blocks by pointer)
-        block = config.serve_paged_block or config.serve_prefix_block
-        if model.max_len % block:
-            raise ValueError(
-                f"--serve-paged-block {block} must divide the model's "
-                f"max_len={model.max_len}")
-        if (config.serve_prefix_cache and config.serve_paged_block
-                and config.serve_paged_block != config.serve_prefix_block):
-            raise ValueError(
-                f"--serve-paged-block ({config.serve_paged_block}) must "
-                f"equal --serve-prefix-block "
-                f"({config.serve_prefix_block}) when the prefix pool is "
-                f"on: pool hits alias physical blocks")
     if config.serve_replicas < 1:
         raise ValueError(
             f"--serve-replicas must be >= 1, got {config.serve_replicas}")
@@ -2533,25 +2476,16 @@ def _serve_from_state(config: ExperimentConfig, ex: _Experiment, state,
         prefix_cache_blocks=config.serve_prefix_cache,
         prefix_block=config.serve_prefix_block)
     if ledger is not None:
-        # conditional-kwarg pattern (same as the paged block below): the
-        # flag-off construction stays byte-identical, and with the ledger
+        # conditional-kwarg pattern: the flag-off construction stays
+        # byte-identical, and with the ledger
         # on every kv jit site routes through ledger.jit — observed
         # compiles, memory_analysis captured, same executable dispatched
         kv_kwargs.update(ledger=ledger)
-    if config.serve_kv_layout == "paged":
-        # --serve-kv-layout paged: SlotKVCache's __new__ dispatches to
-        # PagedSlotKVCache — refcounted block pool, zero-copy prefix
-        # aliasing, fused Pallas decode attention.  The kwargs are only
-        # passed under paged so the monolithic construction stays
-        # byte-identical (program-set pin).
-        kv_kwargs.update(kv_layout="paged",
-                         paged_blocks=config.serve_paged_blocks,
-                         paged_block=config.serve_paged_block)
     kv = SlotKVCache(ex.engine.model, params, config.serve_slots,
                      **kv_kwargs)
     # --roofline serve half: rebuild the cost model FROM THE KV TABLE so
-    # the byte accounting reflects the layout actually serving (storage
-    # dtype, paged blocks, measured param bytes) — the train-side model
+    # the byte accounting reflects the table actually serving (storage
+    # dtype, measured param bytes) — the train-side model
     # knows none of that.  Device peaks / device count carry over.
     serve_roofline = None
     if roofline is not None:
@@ -2636,7 +2570,7 @@ def _serve_from_state(config: ExperimentConfig, ex: _Experiment, state,
                     if config.serve_fault_spec else None)
         fleet_kwargs: dict[str, Any] = {}
         if roles is not None:
-            # conditional-kwarg pattern (same as the paged block above):
+            # conditional-kwarg pattern (same as the ledger above):
             # the round-17 fleet construction stays byte-identical when
             # the round-18 flags are off
             fleet_kwargs.update(roles=roles)

@@ -856,9 +856,7 @@ def test_slot_prefill_block_is_the_training_forward_at_one_row(remat,
 @pytest.mark.parametrize("clone, match", [
     (dict(), "decode_slots"),
     (dict(decode=True), "decode_slots"),
-    (dict(decode=True, decode_slots=True, paged_blocks=5, paged_block=8),
-     "monolithic"),
-], ids=["training", "cursor_decode", "paged"])
+], ids=["training", "cursor_decode"])
 def test_prompt_len_is_refused_outside_the_monolithic_slot_mode(clone, match):
     import jax.numpy as jnp
 

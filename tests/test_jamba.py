@@ -318,7 +318,7 @@ def state_of(kv, slot):
     """The slot's per-slot state leaves, on the host."""
     return [np.asarray(leaf[slot]) for path, leaf in
             jax.tree_util.tree_leaves_with_path(kv.cache)
-            if path[-1].key in kv.state_leaves]
+            if path[-1].key in kv.contract.slot_state]
 
 
 def serve_alone(kv, prompt, new, slot):
@@ -532,7 +532,6 @@ def _live(model, params):
 
 
 @pytest.mark.parametrize("feature, call", [
-    ("paged layout", lambda m, p: SlotKVCache(m, p, 2, kv_layout="paged")),
     ("prefix pool", lambda m, p: SlotKVCache(m, p, 2, prefix_cache_blocks=4)),
     ("int8 storage", lambda m, p: SlotKVCache(m, p, 2, kv_dtype="int8")),
     ("chunked", lambda m, p: SlotKVCache(m, p, 2).begin_insert([1, 2, 3])),
@@ -543,11 +542,8 @@ def _live(model, params):
     ("rewind", lambda m, p: _live(m, p).rewind(0, 2, 5)),
     ("handoff", lambda m, p: _live(m, p).extract_handoff(0)),
     ("handoff", lambda m, p: SlotKVCache(m, p, 2).restore_handoff({})),
-    ("tensor-parallel", lambda m, p: m.slot_decode_clone(
-        partition_model=True)),
-], ids=["paged", "prefix_pool", "int8", "chunk_resume", "multi_step",
-        "verify", "commit_block", "rewind", "handoff_out", "handoff_in",
-        "tensor_parallel"])
+], ids=["prefix_pool", "int8", "chunk_resume", "multi_step", "verify",
+        "commit_block", "rewind", "handoff_out", "handoff_in"])
 def test_what_is_not_built_for_per_slot_state_says_so(model, weights,
                                                       feature, call):
     """By the feature's name, and by this model's: the refusal names the

@@ -249,12 +249,10 @@ def test_the_counters_rules():
                                         "attended_pairs": 10 * LAYERS}
 
 
-@pytest.mark.parametrize("feature", ["prefix", "int8", "paged"])
+@pytest.mark.parametrize("feature", ["prefix", "int8"])
 def test_what_is_not_built_says_so(model, weights, feature):
     kw = {"prefix": dict(prefix_cache_blocks=4),
-          "int8": dict(kv_dtype=jnp.int8),
-          "paged": dict(kv_layout="paged", paged_blocks=8,
-                        paged_block=8)}[feature]
+          "int8": dict(kv_dtype=jnp.int8)}[feature]
     with pytest.raises(NotImplementedError):
         SlotKVCache(model, to_flax(weights), 2, **kw)
 

@@ -308,7 +308,7 @@ def test_a_gpt_window_reports_a_batched_prefill_and_no_routing():
             if r["name"] == "prefill"] == ["batched"]
     assert all("experts_touched" not in r["attrs"] for r in window)
     assert window[0]["attrs"]["expert_assignments"] == 0
-    assert kv.last_routing is None
+    assert "experts_touched" not in kv.last_round_attrs
 
 
 # ------------------------------------------------------- the expert layer
@@ -396,7 +396,6 @@ def test_tokens_marked_invalid_reach_no_expert(weights):
 # ------------------------------------- what is not built for this model
 
 @pytest.mark.parametrize("feature, call", [
-    ("paged layout", lambda m, p: SlotKVCache(m, p, 2, kv_layout="paged")),
     ("prefix pool", lambda m, p: SlotKVCache(m, p, 2, prefix_cache_blocks=4)),
     ("int8 storage", lambda m, p: SlotKVCache(m, p, 2, kv_dtype="int8")),
     ("chunked", lambda m, p: SlotKVCache(m, p, 2).begin_insert([1, 2, 3])),
@@ -405,8 +404,8 @@ def test_tokens_marked_invalid_reach_no_expert(weights):
         np.zeros((2, 2), np.int32))),
     ("handoff", lambda m, p: SlotKVCache(m, p, 2).extract_handoff(0)),
     ("handoff", lambda m, p: SlotKVCache(m, p, 2).restore_handoff({})),
-], ids=["paged", "prefix_pool", "int8", "chunk_resume", "multi_step",
-        "verify", "handoff_out", "handoff_in"])
+], ids=["prefix_pool", "int8", "chunk_resume", "multi_step", "verify",
+        "handoff_out", "handoff_in"])
 def test_what_is_not_built_for_a_batched_prefill_says_so(model, weights,
                                                          feature, call):
     with pytest.raises(NotImplementedError, match=feature):
@@ -623,5 +622,3 @@ def test_modes_that_do_not_exist_are_refused(model, weights, tokens):
         model.apply(params, tokens[None], positions=jnp.arange(40)[None])
     with pytest.raises(ValueError, match="max_len"):
         model.apply(params, jnp.zeros((1, 65), jnp.int32))
-    with pytest.raises(NotImplementedError, match="tensor-parallel"):
-        model.slot_decode_clone(partition_model=True)

@@ -1,6 +1,6 @@
 """The inside of a decode round: ``SlotKVCache.advance`` leaves a
 ``step_dispatch`` and a ``token_fetch`` record under the scheduler's
-``decode_step``, for every served model family and for the paged table, and
+``decode_step``, for every served model family, and
 leaves nothing (and serves the same tokens) under ``NULL_TRACER``."""
 
 import numpy as np
@@ -22,7 +22,6 @@ FAMILIES = {
     "mla_moe": ("mla_moe", TINY, {}),
     "hybrid_ssm": ("hybrid_ssm", TINY, {}),
     "window_moe": ("window_moe", TINY, {}),
-    "gpt-paged": ("gpt", GPT, {"kv_layout": "paged", "paged_block": 8}),
 }
 
 

@@ -1449,7 +1449,7 @@ def test_the_step_reads_the_prefix_and_matches_the_full_table(
         np.testing.assert_array_equal(kv.tokens, plain.tokens)
         np.testing.assert_array_equal(kv.lengths, plain.lengths)
         # the slot of 40 stands aside: 21 + 1 of the next, rounded to 24
-        assert kv.last_rows_read == prefix_rows(
+        assert kv.last_round_attrs["rows_read"] == prefix_rows(
             plain.lengths - only, only, max_len) == 24
         for g, w in zip(*map(jax.tree.leaves, (kv.cache, plain.cache))):
             np.testing.assert_array_equal(np.asarray(g)[:, :24],

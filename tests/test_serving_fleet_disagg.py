@@ -120,39 +120,8 @@ def test_handoff_roundtrip_int8_scales_ride_along(model_params):
     assert got == ref
 
 
-def test_handoff_roundtrip_paged(model_params):
-    """Paged layout: physical blocks serialize (aliased prefix blocks
-    included — the payload is self-contained) and restore allocates into
-    the receiving pool; eviction returns every block."""
-    model, params = model_params
-    ref, got = _roundtrip(model, params, kv_layout="paged", paged_block=8)
-    assert got == ref
-
-
-def test_handoff_paged_restore_failure_leaks_no_blocks(model_params):
-    """A restore that dies mid-allocation (pool exhausted) releases every
-    block it claimed — the no-leak guard on the receiving side."""
-    model, params = model_params
-    src = SlotKVCache(model, params, 2, kv_layout="paged", paged_block=8)
-    slot, _ = src.insert(np.arange(1, 20, dtype=np.int32))  # 3 blocks
-    payload = src.extract_handoff(slot)
-    # 8-block pool with 6 already pinned by a resident slot: the restore
-    # needs 3, claims 2, fails on the third — and must give both back
-    dst = SlotKVCache(model, params, 2, kv_layout="paged", paged_block=8,
-                      paged_blocks=8)
-    resident, _ = dst.insert(np.arange(1, 45, dtype=np.int32))  # 6 blocks
-    held = dst.blocks_in_use
-    assert held == 6
-    with pytest.raises(Exception):
-        dst.restore_handoff(payload)
-    assert dst.blocks_in_use == held
-    assert int(dst.active.sum()) == 1
-    dst.evict(resident)
-    assert dst.blocks_in_use == 0
-
-
-# round 20 fast-lane repair: mesh variant — the monolithic and paged
-# handoff roundtrips keep the fast representatives
+# round 20 fast-lane repair: mesh variant — the handoff roundtrips above
+# keep the fast representatives
 @pytest.mark.slow
 def test_handoff_roundtrip_mesh8_slot_sharded(model_params, mesh8):
     """The handoff works across slot-sharded tables: extract gathers
@@ -390,12 +359,11 @@ def test_handoff_crash_requeues_no_leak_no_duplicates(model_params,
     """A prefill replica killed between prefill completion and decode
     admission (the handoff site): its request requeues to the surviving
     prefill replica, streams stay bitwise, conservation holds per role,
-    and — on paged tables — no pool block leaks anywhere."""
+    and no slot leaks anywhere."""
     model, params = model_params
     reqs = _requests()
     oracle = default_oracle
-    kvs = build_replica_kvs(model, params, 3, 2, kv_layout="paged",
-                            paged_block=8)
+    kvs = build_replica_kvs(model, params, 3, 2)
     inj = FaultInjector("crash:replica=0,handoff=1", seed=0)
     rs = ReplicaSet(kvs, clock=VirtualClock(),
                     roles=["prefill", "prefill", "decode"],
@@ -409,7 +377,7 @@ def test_handoff_crash_requeues_no_leak_no_duplicates(model_params,
     per = summary["serve_disagg"]["per_role"]
     assert sum(per[r]["done"] for r in per) == summary["completed"] == 6
     for kv in kvs:
-        assert kv.blocks_in_use == 0, kv.blocks_in_use
+        assert not (kv.active.any() or kv.reserved.any()), kv.active
 
 
 def test_handoff_with_no_decode_survivor_is_accounted(model_params):

@@ -252,11 +252,10 @@ SHARED = np.arange(16, dtype=np.int32) % 64
 
 
 @pytest.mark.slow
-@pytest.mark.parametrize("case", ["chunk", "prefix", "paged", "int8",
-                                  "paged_int8"])
+@pytest.mark.parametrize("case", ["chunk", "prefix", "int8"])
 def test_parity_composes_with_serving_features(model_params, case):
-    """Multi-step under chunked prefill, the prefix pool, the paged
-    table, and int8 KV storage: same bitwise-parity + fewer-dispatches
+    """Multi-step under chunked prefill, the prefix pool and int8 KV
+    storage: same bitwise-parity + fewer-dispatches
     pin — the fused scan runs the SAME per-iteration step the feature
     already compiled, so composition is free by construction."""
     model, params = model_params
@@ -264,12 +263,7 @@ def test_parity_composes_with_serving_features(model_params, case):
         "chunk": (dict(), dict(prefill_chunk=4), False),
         "prefix": (dict(prefix_cache_blocks=8, prefix_block=8), dict(),
                    True),
-        "paged": (dict(kv_layout="paged", paged_blocks=48, paged_block=4),
-                  dict(prefill_chunk=4), False),
         "int8": (dict(kv_dtype="int8"), dict(), False),
-        "paged_int8": (dict(kv_layout="paged", paged_blocks=48,
-                            paged_block=4, kv_dtype="int8"),
-                       dict(prefill_chunk=4), False),
     }[case]
     kv_kw, b_kw, prefix = cfg
 

@@ -1,6 +1,6 @@
 """Roofline efficiency ledger (ISSUE 19): the analytic GPT cost model's
 exact-pinned FLOPs/bytes figures (grad-accum invariance, prefill chunk
-telescoping, int8/paged KV byte accounting, speculative verify widths),
+telescoping, int8 KV byte accounting, speculative verify widths),
 the device peak table's honesty contract (unknown kind → None, never an
 invented peak), the MFU/MBU wiring through trainer fit results, the
 continuous batcher, the replica fleet and the run report (all flag-off
@@ -141,15 +141,12 @@ def test_prefill_chunks_telescope():
 
 
 def test_kv_bytes_layout_pins():
-    """Must-read KV bytes under every storage layout, pinned: f32 is
+    """Must-read KV bytes under every storage dtype, pinned: f32 is
     2 (k,v) · kv_hidden · 4 B = 32 B/pos; int8 is payload 8 + one f32
-    scale per (pos, kv_head) · 2 tensors = 24 B/pos; paged rounds the
-    read up to whole blocks (the block-granular gather)."""
+    scale per (pos, kv_head) · 2 tensors = 24 B/pos."""
     assert _cost().kv_read_bytes(5) == 160                       # 32·5
     assert _cost(kv_dtype="int8").kv_read_bytes(5) == 120        # 24·5
-    assert _cost(kv_layout="paged", paged_block=4).kv_read_bytes(5) \
-        == 256                                                   # 32·8
-    # monolithic credits exactly L — the max_len scan the compiled
+    # the table credits exactly L — the max_len scan the compiled
     # program actually does is the inefficiency MBU exposes, not credit
     assert _cost().kv_read_bytes(32) == 32 * 32
 

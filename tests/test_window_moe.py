@@ -428,7 +428,7 @@ def test_counters_tell_the_three_kinds_of_leaf_apart(name):
         "ssm_scan_positions", "ssm_scan_tokens"}
     assert counts["ssm_scan_positions"] == counts["ssm_scan_tokens"] == 0
     assert counts["window_bytes_per_slot"] == ring_bytes
-    assert bool(kv.ring_leaves) == bool(ring_bytes)
+    assert bool(kv.contract.slot_rings) == bool(ring_bytes)
     if row_bytes is not None:
         assert counts["cache_bytes_per_token"] == row_bytes
     if state_bytes is not None:
@@ -438,14 +438,15 @@ def test_counters_tell_the_three_kinds_of_leaf_apart(name):
     assert kv.kv_bytes_per_slot() == (rows + state + rings) // 3
     full = [leaf for path, leaf in
             jax.tree_util.tree_leaves_with_path(kv.cache)
-            if path[-1].key not in kv.state_leaves
-            and path[-1].key not in kv.ring_leaves]
+            if path[-1].key not in kv.contract.slot_state
+            and path[-1].key not in kv.contract.slot_rings]
     assert full and all(leaf.shape[1] == 64 for leaf in full)
 
 
 def test_the_table_holds_rings_beside_full_length_rows(model, weights):
     kv = SlotKVCache(model, to_flax(weights), 4)
-    assert kv.ring_leaves == {"ring_key": WINDOW, "ring_value": WINDOW}
+    assert kv.contract.slot_rings == {"ring_key": WINDOW,
+                                      "ring_value": WINDOW}
     assert {leaf.shape for leaf in jax.tree.leaves(kv.cache)} == {
         (4, WINDOW, KVH, HD), (4, MAX_LEN, KVH, HD)}
     assert kv.kv_bytes_per_slot() == ROW_BYTES * MAX_LEN + RING_BYTES
@@ -483,7 +484,6 @@ def _live(model, params):
 
 
 @pytest.mark.parametrize("feature, call", [
-    ("paged layout", lambda m, p: SlotKVCache(m, p, 2, kv_layout="paged")),
     ("prefix pool", lambda m, p: SlotKVCache(m, p, 2, prefix_cache_blocks=4)),
     ("int8 storage", lambda m, p: SlotKVCache(m, p, 2, kv_dtype="int8")),
     ("chunked", lambda m, p: SlotKVCache(m, p, 2).begin_insert([1, 2, 3])),
@@ -494,15 +494,12 @@ def _live(model, params):
     ("rewind", lambda m, p: _live(m, p).rewind(0, 2, 5)),
     ("handoff", lambda m, p: _live(m, p).extract_handoff(0)),
     ("handoff", lambda m, p: SlotKVCache(m, p, 2).restore_handoff({})),
-    ("tensor-parallel", lambda m, p: m.slot_decode_clone(
-        partition_model=True)),
-], ids=["paged", "prefix_pool", "int8", "chunk_resume", "multi_step",
-        "verify", "commit_block", "rewind", "handoff_out", "handoff_in",
-        "tensor_parallel"])
+], ids=["prefix_pool", "int8", "chunk_resume", "multi_step", "verify",
+        "commit_block", "rewind", "handoff_out", "handoff_in"])
 def test_what_cannot_hold_over_a_ring_says_so(model, weights, feature, call):
     with pytest.raises(NotImplementedError, match=feature) as err:
         call(model, to_flax(weights))
-    if feature not in ("int8 storage", "tensor-parallel"):
+    if feature != "int8 storage":
         assert "rings" in str(err.value)
 
 
